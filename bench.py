@@ -952,13 +952,12 @@ def _roofline_dict(per_iter: float, cells: int, flops_per_cell: float,
                    bytes_per_cell: float,
                    compiler: Optional[dict] = None) -> dict:
     """Roofline placement against the LIVE device's ceilings — shared by
-    the uniform and AMR microbenches.  Round 19: the peaks come from the
-    ``obs/costs.py`` device-kind table (``device_peaks()``) instead of
-    hand-typed v5e constants, so MFU/HBM fractions stop silently lying
-    on non-v5e hardware (lint JX017 keeps new literals out); on CPU the
-    table's documented nominal-v5e fallback keeps the trendline
-    comparable, flagged ``peaks.nominal``.  When a compiler-counted
-    cost row rides along (``compiler``, from ``xla.cost_analysis`` via
+    the uniform and AMR microbenches.  The peaks come from the
+    ``obs/costs.py`` device-kind table (``device_peaks()``; lint JX017
+    keeps hand-typed literals out).  Off the TPU there is no ceiling:
+    the MFU/HBM shares are ``None`` ("not measured"), never a share of
+    some other chip's peak.  When a compiler-counted cost row rides
+    along (``compiler``, from ``xla.cost_analysis`` via
     ``_compiler_per_iter``) the dict reports the compiler-grounded
     MFU/HBM placement NEXT TO the analytic model — and the history
     gate tracks the compiler bytes, so a compile that doubles HBM
@@ -968,27 +967,28 @@ def _roofline_dict(per_iter: float, cells: int, flops_per_cell: float,
     peaks = obs_costs.device_peaks()
     flops = flops_per_cell * cells
     bytes_ = bytes_per_cell * cells
+
+    def share(amount, peak_attr, digits):
+        if peaks is None or not amount:
+            return None
+        return round(amount / per_iter / getattr(peaks, peak_attr), digits)
+
     out = {
         "bicgstab_iter_device_ms": round(per_iter * 1e3, 3),
         "cell_iters_per_s": round(cells / per_iter / 1e6, 1),
         "est_gflops": round(flops / per_iter / 1e9, 1),
-        "mfu_vs_bf16_peak": round(flops / per_iter / peaks.bf16_flops, 5),
+        "mfu_vs_bf16_peak": share(flops, "bf16_flops", 5),
         "est_hbm_gbs": round(bytes_ / per_iter / 1e9, 1),
-        "hbm_fraction": round(
-            bytes_ / per_iter / peaks.hbm_bytes_per_s, 4),
-        "peaks": peaks.as_dict(),
+        "hbm_fraction": share(bytes_, "hbm_bytes_per_s", 4),
+        "peaks": None if peaks is None else peaks.as_dict(),
     }
     if compiler is not None:
         out["compiler"] = compiler
         if compiler.get("available"):
-            cf, cb = compiler.get("flops_per_iter"), compiler.get(
-                "bytes_per_iter")
-            if cf:
-                out["mfu_vs_bf16_peak_compiler"] = round(
-                    cf / per_iter / peaks.bf16_flops, 5)
-            if cb:
-                out["hbm_fraction_compiler"] = round(
-                    cb / per_iter / peaks.hbm_bytes_per_s, 4)
+            out["mfu_vs_bf16_peak_compiler"] = share(
+                compiler.get("flops_per_iter"), "bf16_flops", 5)
+            out["hbm_fraction_compiler"] = share(
+                compiler.get("bytes_per_iter"), "hbm_bytes_per_s", 4)
     return out
 
 
@@ -1334,7 +1334,7 @@ def _amr_roofline(sim):
     the MXU, 2 HBM passes each), ~10 BiCGSTAB vector ops at 1 flop +
     2 passes -> ~2100 flop and ~110 B of HBM traffic per cell-iteration.
     Ceilings come from the live device's entry in the obs/costs.py peak
-    table (nominal v5e reference on CPU); the stencil part runs f32 VPU
+    table (no ceiling off the TPU); the stencil part runs f32 VPU
     but MFU is reported against the bf16 peak for comparability."""
     import time
 
@@ -2162,6 +2162,9 @@ def bench_durability():
 
 
 def main():
+    from cup3d_tpu.utils import compile_cache
+
+    compile_cache.enable()
     which = os.environ.get("CUP3D_BENCH_CONFIG", "all")
     if which not in ("fish", "fish256", "tgv", "spectral", "amr",
                      "channel", "amr_tgv", "fleet", "fleet_slo",

@@ -72,7 +72,10 @@ def _log_config(driver) -> None:
             f.write(f"{field.name} {getattr(cfg, field.name)!r}\n")
 
 
-def main(argv: Optional[List[str]] = None) -> None:
+def main(argv: Optional[List[str]] = None):
+    """Run one case to its end and return the finished driver (in-process
+    callers such as ``chip_smoke.py`` inspect its state; the command
+    line ignores it)."""
     args = sys.argv[1:] if argv is None else argv
     if args and args[0] == "fleet":
         # many-simulation serving mode: `python -m cup3d_tpu fleet
@@ -88,10 +91,17 @@ def main(argv: Optional[List[str]] = None) -> None:
         from cup3d_tpu.aot.cli import main as aot_main
 
         raise SystemExit(aot_main(args[1:]))
+    # the solver path keeps XLA's persistent compile cache at a fixed
+    # place (the fleet/aot subcommands above manage their own
+    # executable store, aot/store.py)
+    from cup3d_tpu.utils import compile_cache
+
+    compile_cache.enable()
     driver = build_driver(args)
     _log_config(driver)
     driver.init()
     driver.simulate()
+    return driver
 
 
 if __name__ == "__main__":

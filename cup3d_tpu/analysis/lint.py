@@ -280,7 +280,7 @@ JX017_PATH_RE = re.compile(r"(^|/)bench[^/]*\.py$")
 JX017_FUNC_RE = re.compile(r"roofline|peak", re.IGNORECASE)
 
 #: the one sanctioned home for hardware peak literals: the device-kind
-#: table in obs/costs.py (provenance-annotated, nominal-flagged)
+#: table in obs/costs.py (provenance-annotated)
 JX017_EXEMPT_RE = re.compile(r"cup3d_tpu/obs/costs\.py$")
 
 #: spec-sheet magnitudes start at ~1e9 (GB/s bandwidths); exact powers

@@ -388,8 +388,9 @@ RULES: Dict[str, Rule] = {
             "device — the round-19 bug class where bench.py divided by "
             "v5e ceilings regardless of hardware.  Hardware peaks live "
             "in the provenance-annotated device-kind table in "
-            "obs/costs.py (the one path-exempt module, nominal-flagged "
-            "CPU fallback included); consumers resolve the live "
+            "obs/costs.py (the one path-exempt module; no entry, no "
+            "ceiling — an unknown TPU kind raises, a CPU has none); "
+            "consumers resolve the live "
             "backend with obs.costs.device_peaks().  Exact powers of "
             "ten (1e9, 1e12) are unit conversions and never fire.",
         ),

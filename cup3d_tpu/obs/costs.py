@@ -10,9 +10,9 @@ typed by hand:
    silently lied on anything that wasn't a v5e.  :func:`device_peaks`
    resolves the live backend's ``device_kind`` against
    :data:`PEAK_TABLE` (public spec-sheet numbers, provenance in the
-   table) and falls back to the **documented nominal v5e entry** on CPU
-   and unknown kinds — flagged ``nominal=True`` so consumers (and the
-   bench summary) can tell a real ceiling from a reference one.  Lint
+   table).  A TPU kind the table does not know raises, and off the TPU
+   there is no ceiling at all (``None``): a CPU run reports its
+   roofline shares as not measured, never against v5e numbers.  Lint
    rule JX017 keeps new hand-typed peaks out of roofline/bench paths;
    this module is the one sanctioned home for the literals.
 
@@ -53,23 +53,17 @@ from cup3d_tpu.obs import metrics as _metrics
 
 @dataclass(frozen=True)
 class DevicePeaks:
-    """One device kind's advertised ceilings (the roofline denominators).
-
-    ``nominal`` marks a reference entry (CPU / unknown kinds): the
-    numbers are the documented v5e ceilings so trend lines stay
-    comparable across backends, NOT a claim about the local machine.
-    """
+    """One device kind's advertised ceilings (the roofline denominators)."""
 
     kind: str
     bf16_flops: float        # dense bf16 peak, FLOP/s per chip
     hbm_bytes_per_s: float   # HBM bandwidth, B/s per chip
-    nominal: bool = False
     note: str = ""
 
     def as_dict(self) -> dict:
         return {"kind": self.kind, "bf16_flops": self.bf16_flops,
                 "hbm_bytes_per_s": self.hbm_bytes_per_s,
-                "nominal": self.nominal, "note": self.note}
+                "note": self.note}
 
 
 #: public spec-sheet peaks per ``device_kind`` substring (cloud.google
@@ -87,17 +81,6 @@ PEAK_TABLE = (
                 note="275 TFLOP/s bf16, 1228 GB/s HBM"),
 )
 
-#: the documented fallback: rooflines on CPU (and unknown kinds) are
-#: reported against the v5e ceilings so the history trajectory stays
-#: one series, with ``nominal=True`` recording that the ceiling is a
-#: reference, not the local hardware.
-NOMINAL_FALLBACK = DevicePeaks(
-    "nominal-v5e", 197e12, 819e9, nominal=True,
-    note="reference ceiling (v5e numbers): backend has no entry in "
-         "PEAK_TABLE — MFU/HBM fractions are vs this documented "
-         "reference, not the local machine",
-)
-
 _KIND_ALIASES = {
     "tpu v5 lite": "TPU v5e",
     "tpu v5litepod": "TPU v5e",
@@ -107,29 +90,32 @@ _KIND_ALIASES = {
 
 def peaks_for_kind(kind: str) -> DevicePeaks:
     """Resolve a ``device_kind`` string against :data:`PEAK_TABLE`
-    (normalized substring match, v5-lite aliases folded in); unknown
-    kinds get :data:`NOMINAL_FALLBACK`."""
+    (normalized substring match, v5-lite aliases folded in).  A kind
+    the table does not know is an error, not a default: a roofline
+    against another chip's ceilings is a wrong number."""
     norm = str(kind).strip().lower()
     norm = _KIND_ALIASES.get(norm, norm).lower()
     for peaks in PEAK_TABLE:
         if peaks.kind.lower() in norm or norm in peaks.kind.lower():
             return peaks
-    return NOMINAL_FALLBACK
+    raise KeyError(
+        f"device_kind {kind!r} has no entry in obs.costs.PEAK_TABLE: add "
+        f"its published peaks (with their source) before reporting a "
+        f"roofline on it")
 
 
-def device_peaks(device=None) -> DevicePeaks:
+def device_peaks(device=None) -> Optional[DevicePeaks]:
     """The live backend's peaks (``jax.devices()[0]`` unless a device
-    is passed).  Never raises: a jax-less / backend-less environment is
-    counted and returns the nominal fallback."""
-    try:
-        if device is None:
-            import jax
+    is passed).  None off the TPU — a CPU run has no device ceiling,
+    and its roofline fields read "not measured"; an unknown TPU
+    ``device_kind`` raises (:func:`peaks_for_kind`)."""
+    if device is None:
+        import jax
 
-            device = jax.devices()[0]
-        return peaks_for_kind(device.device_kind)
-    except Exception:
-        _metrics.counter("costs.unavailable", what="device_kind").inc()
-        return NOMINAL_FALLBACK
+        device = jax.devices()[0]
+    if device.platform != "tpu":
+        return None
+    return peaks_for_kind(device.device_kind)
 
 
 # -- per-executable harvest --------------------------------------------------

@@ -410,6 +410,10 @@ def build_amr_poisson_solver_dynamic(
     # tests rebuild the solver to flip paths, production builds once
     fused_on = (_precision.use_fused() and mean_constraint == 2
                 and krylov.use_exact_getz())
+    # a knob combination this build cannot honor raises here (bf16
+    # without the fused driver; the fused driver where its kernels
+    # would compile natively) — never a quiet downgrade
+    _precision.check_policy(mean_constraint, forest_fused=fused_on)
 
     def solve(rhs, x0=None, tab_arg=None, flux_arg=None, rnorm_ref=None,
               geom=None, vol=None, pmask=None, graph=None, slot0=None,

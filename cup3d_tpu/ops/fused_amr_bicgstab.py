@@ -57,6 +57,18 @@ this driver under ``CUP3D_FUSED`` (precision.use_fused) for the
 mean-removal constraint (mode 2) with the exact getZ — the production
 pressure configuration; pinned-row modes and the CUP3D_GETZ=cg ladder
 keep the legacy composition.
+
+NOT RUNNABLE NATIVELY YET.  The stages keep the block-major
+``(capacity, 8, 8, 8)`` layout, which puts an 8-wide axis on the
+128-wide lane dimension: the v5e compiler aborts the process (SIGABRT
+in ``VectorLayoutInferer::inferReshape``, not a Python exception) on
+every stage — the per-block reductions of ``_blocksum``, the
+``(n, 512)`` views of ``_getz_math`` and the lab stencil of
+``_lap_math`` alike; handing the element-wise stages 2-D ``(C, 512)``
+views does not get ``_k_lap`` through.  Until the stages are re-laid
+out, asking for the native kernels raises
+(:func:`refuse_native_kernels`); the jnp twins and the interpreter run
+the same math for the parity tests.
 """
 
 from __future__ import annotations
@@ -79,6 +91,20 @@ _F32 = jnp.float32
 #: budget (the heaviest stage, getz, holds ~5 chunk-sized f32 arrays
 #: plus the 512x512 basis: ~7.5 MB).
 BLOCK_CHUNK = 64
+
+
+def refuse_native_kernels() -> None:
+    """The fused forest stages do not compile for the TPU (module
+    docstring).  Raising beats the alternative on both sides: compiling
+    them aborts the interpreter, and dropping to the jnp twins or the
+    unfused solver would hide that the requested path did not run."""
+    raise NotImplementedError(
+        "the fused forest BiCGSTAB kernels (ops/fused_amr_bicgstab.py) "
+        "do not compile for the TPU: their block-major (capacity, 8, 8, "
+        "8) stages abort the chip's compiler.  Run the forest with the "
+        "stock solver (unset CUP3D_FUSED and CUP3D_KRYLOV_DTYPE); bf16 "
+        "Krylov storage on a forest needs the stage re-layout first"
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -441,6 +467,8 @@ def fused_amr_bicgstab(
         kernels = use_pallas()
     if interpret:
         kernels = True
+    elif kernels:
+        refuse_native_kernels()
     two_level = graph is not None
     if tab.width != 1:
         raise ValueError("fused AMR Laplacian needs width-1 lab tables")

@@ -86,12 +86,22 @@ def use_fused() -> bool:
     return krylov_dtype() == jnp.bfloat16
 
 
-def check_policy(mean_constraint: int = 2) -> None:
+def check_policy(mean_constraint: int = 2,
+                 forest_fused: bool = False) -> None:
     """Build-time validation of the knob combination: a bf16 request the
-    configuration cannot honor raises instead of silently downgrading."""
+    configuration cannot honor raises instead of silently downgrading,
+    and so does a forest solve routed to the fused driver
+    (``forest_fused``) where its kernels would be compiled natively —
+    they do not compile for the TPU yet (ops/fused_amr_bicgstab.py), and
+    neither the jnp twins nor the unfused solver may stand in unasked."""
     if krylov_dtype() == jnp.bfloat16 and not use_fused():
         raise ValueError(
             "CUP3D_KRYLOV_DTYPE=bf16 requires the fused iteration driver "
             "(its cast discipline keeps accumulations f32); unset "
             "CUP3D_FUSED=0 or use f32 storage"
         )
+    if forest_fused:
+        from cup3d_tpu.ops import fused_amr_bicgstab, getz_pallas
+
+        if getz_pallas.use_pallas():
+            fused_amr_bicgstab.refuse_native_kernels()

@@ -1,11 +1,8 @@
-"""jax version compatibility for the sharded-forest layer.
+"""One call surface for ``jax.shard_map`` in the sharded layers.
 
-``jax.shard_map`` (with ``check_vma``) became a top-level API after the
-experimental ``jax.experimental.shard_map.shard_map`` (with
-``check_rep``) stabilized.  The TPU image runs the new API; CPU test
-environments may carry an older jax where only the experimental path
-exists.  The wrapper keeps one call surface (the new API's) for the
-forest/faces kernels and maps the replication-check flag across.
+The forest/faces/ring kernels and the sharded megaloop pass the mesh
+positionally and default the replication check off; this thin wrapper
+keeps that spelling in one place.
 """
 
 from __future__ import annotations
@@ -13,26 +10,8 @@ from __future__ import annotations
 import jax
 
 
-def _has_new_api() -> bool:
-    try:
-        return callable(jax.shard_map)
-    except AttributeError:
-        return False
-
-
-if _has_new_api():
-
-    def shard_map(f, mesh, in_specs, out_specs, check_vma=False):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-
-else:
-    from jax.experimental.shard_map import shard_map as _legacy_shard_map
-
-    def shard_map(f, mesh, in_specs, out_specs, check_vma=False):
-        return _legacy_shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=check_vma,
-        )
+def shard_map(f, mesh, in_specs, out_specs, check_vma=False):
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=check_vma,
+    )
