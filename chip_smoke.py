@@ -396,9 +396,10 @@ def main() -> int:
     from cup3d_tpu.utils import compile_cache
 
     cache_dir = compile_cache.enable()
+    cached_before = compile_cache.entries(cache_dir)
     emit(cache_dir=cache_dir,
          cache_dir_from_env=bool(os.environ.get("JAX_COMPILATION_CACHE_DIR")),
-         cache_entries_before=compile_cache.count_entries(cache_dir))
+         cache_entries_before=len(cached_before))
     n = 32 if args.rehearse else 128
     with tempfile.TemporaryDirectory(prefix="cup3d-chip-smoke-") as workdir:
         if args.chips == 4:
@@ -408,8 +409,9 @@ def main() -> int:
                 phase_a(n, workdir, on_tpu, device)
             if args.phase in (None, "B"):
                 phase_b(3 if args.rehearse else 12, workdir, device)
-    emit(cache_dir=cache_dir,
-         cache_entries_after=compile_cache.count_entries(cache_dir))
+    cached_after = compile_cache.entries(cache_dir)
+    emit(cache_dir=cache_dir, cache_entries_after=len(cached_after),
+         cache_entries_new=sorted(cached_after - cached_before))
     emit(ok=on_tpu and not args.rehearse,
          device={"platform": device.platform, "kind": device.device_kind,
                  "count": len(devices)})

@@ -94,7 +94,9 @@ def _ring_shift_pallas(x: jnp.ndarray, axis_name: str, shift: int,
             dst_ref=out_ref,
             send_sem=send_sem,
             recv_sem=recv_sem,
-            device_id=(dst,),
+            # by axis name: the other axes of a 2-D (lanes, x) mesh keep
+            # this shard's own coordinates
+            device_id={axis_name: dst},
             device_id_type=pltpu.DeviceIdType.MESH,
         )
         copy.start()

@@ -36,7 +36,6 @@ conftest) — the mesh factory does not care what backs the devices.
 from __future__ import annotations
 
 import os
-import warnings
 from typing import List, Optional, Sequence, Tuple
 
 import jax
@@ -264,10 +263,10 @@ def fleet_mesh2d() -> Optional[Mesh]:
 
 def megaloop_mesh() -> Optional[Mesh]:
     """The solo megaloop's slab mesh: ``CUP3D_MESH_X=D`` asks for a
-    ``(1, D)`` mesh (unit lane axis, D x-slabs).  None when unset,
-    <2, or more slabs than devices are requested — the caller falls
-    back to the unsharded megaloop, loudly
-    (``topology.megaloop_mesh_fallbacks``)."""
+    ``(1, D)`` mesh (unit lane axis, D x-slabs).  None when unset or
+    <2.  A mesh that was asked for and cannot be had — more slabs than
+    visible devices — raises: a run that was meant to span D chips
+    must not quietly put everything on the first one."""
     v = os.environ.get("CUP3D_MESH_X", "").strip()
     if not v:
         return None
@@ -281,11 +280,10 @@ def megaloop_mesh() -> Optional[Mesh]:
         return None
     dist_init()
     if len(jax.devices()) < want:
-        warnings.warn(
+        raise RuntimeError(
             f"CUP3D_MESH_X={want} exceeds the {len(jax.devices())} "
-            f"visible devices: megaloop runs unsharded", stacklevel=2)
-        M.counter("topology.megaloop_mesh_fallbacks").inc()
-        return None
+            f"visible devices: unset it or run where {want} devices are "
+            f"attached")
     return make_mesh2d(lanes=1, x=want,
                        devices=device_order()[:want])
 

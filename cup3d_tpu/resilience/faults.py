@@ -20,7 +20,7 @@ ckpt.write_fail      ``io.checkpoint.write_payload``: the checkpoint
                      write raises (every retry re-fires while armed)
 dump.write_fail      ``stream.dump.AsyncDumper._write``: the dump write
                      raises (retried, then dropped + counted)
-stream.stall         ``stream.qoi.QoIStream.emit``: a simulated tunnel
+stream.stall         ``stream.qoi.QoIStream.emit``: a simulated transfer
                      stall (sleep) before the pack is queued
 ==================== ======================================================
 
@@ -79,7 +79,7 @@ SITES = (
 
 ENV_VAR = "CUP3D_FAULT"
 
-#: simulated tunnel stall for the stream.stall site (seconds)
+#: simulated transfer stall for the stream.stall site (seconds)
 STALL_S = 0.02
 
 

@@ -350,6 +350,27 @@ def _slab_specs(keys, axis):
     return {k: (P(axis) if k in FIELD_KEYS else P()) for k in keys}
 
 
+def _require_slabbable(s, mesh, axis) -> None:
+    """``CUP3D_MESH_X`` asked for the x-slab scan body: raise where it
+    cannot be built, instead of running the solo loop under a mesh's
+    name.  The scan body solves Poisson replicated, which the spectral
+    solver supports; the iterative front-ends advertise [residual,
+    iterations] telemetry that has no replicated form yet."""
+    from cup3d_tpu.parallel import topology as topo
+
+    if getattr(s.poisson_solver, "supports_stats", False):
+        raise NotImplementedError(
+            "CUP3D_MESH_X: the x-slab megaloop needs the spectral Poisson "
+            "solver (-poissonSolver spectral); the iterative solver has "
+            "no sharded scan body yet")
+    D = topo.mesh_axis_size(mesh, axis)
+    nx = s.grid.shape[0]
+    if nx % D or nx // D < GHOSTS:
+        raise ValueError(
+            f"CUP3D_MESH_X: {D} x-shards cannot slab nx={nx} (need even "
+            f"slabs of >= {GHOSTS} planes for the one-hop ring halo)")
+
+
 def make_tgv_step_sharded(s, axis="x"):
     """The obstacle-free scan body on one x-slab, to run INSIDE
     shard_map over mesh axis ``axis``.  Same carry keys and row layout
@@ -401,27 +422,14 @@ def make_tgv_step_sharded(s, axis="x"):
 def build_tgv_megaloop_sharded(s, mesh, axis="x"):
     """jitted (carry, cfl_eff (K,)) -> (carry', rows (K, TGV_ROW)) with
     the scan body shard_mapped over the mesh's ``axis`` slabs.  Global
-    shapes in and out match the solo megaloop exactly.  Returns None
-    when unbuildable: an iterative (stats-advertising) solver keeps the
-    solo path, and a mesh axis that does not divide nx cannot slab."""
-    import warnings
-
+    shapes in and out match the solo megaloop exactly.  A run that
+    cannot slab raises (:func:`_require_slabbable`) — the mesh was
+    asked for, so the solo loop is not a stand-in."""
     from jax.sharding import PartitionSpec as P
 
-    from cup3d_tpu.obs import metrics as M
-    from cup3d_tpu.parallel import topology as topo
     from cup3d_tpu.parallel.compat import shard_map
 
-    if getattr(s.poisson_solver, "supports_stats", False):
-        return None
-    D = topo.mesh_axis_size(mesh, axis)
-    if s.grid.shape[0] % D or s.grid.shape[0] // D < GHOSTS:
-        warnings.warn(
-            f"{D} x-shards cannot slab nx={s.grid.shape[0]} (need even "
-            f"slabs of >= {GHOSTS} planes for the one-hop ring halo): "
-            f"megaloop runs unsharded", stacklevel=2)
-        M.counter("topology.megaloop_mesh_fallbacks").inc()
-        return None
+    _require_slabbable(s, mesh, axis)
     one_step = make_tgv_step_sharded(s, axis)
 
     def megaloop(carry, cfl_eff):
@@ -556,31 +564,18 @@ def make_fish_step_sharded(s, ob, axis="x"):
 def build_fish_megaloop_sharded(s, ob, mesh, axis="x"):
     """jitted (carry, cfl_eff (K,)) -> (carry', rows (K, FISH_ROW)) with
     the fish scan body shard_mapped over ``axis`` slabs.  Returns None
-    when the gait is not freezable, the solver advertises stats (the
-    iterative front-ends keep the solo path — their [residual, iter]
-    telemetry has no replicated form yet), or nx does not slab."""
-    import warnings
-
+    when the gait is not freezable (no megaloop at all, exactly like
+    :func:`build_fish_megaloop`); a run that cannot slab raises
+    (:func:`_require_slabbable`)."""
     from jax.sharding import PartitionSpec as P
 
     from cup3d_tpu.models.fish.device_midline import freeze_gait
-    from cup3d_tpu.obs import metrics as M
-    from cup3d_tpu.parallel import topology as topo
     from cup3d_tpu.parallel.compat import shard_map
 
     gait = freeze_gait(ob, s.time, s.dtype)
     if gait is None:
         return None
-    if getattr(s.poisson_solver, "supports_stats", False):
-        return None
-    D = topo.mesh_axis_size(mesh, axis)
-    if s.grid.shape[0] % D or s.grid.shape[0] // D < GHOSTS:
-        warnings.warn(
-            f"{D} x-shards cannot slab nx={s.grid.shape[0]} (need even "
-            f"slabs of >= {GHOSTS} planes for the one-hop ring halo): "
-            f"megaloop runs unsharded", stacklevel=2)
-        M.counter("topology.megaloop_mesh_fallbacks").inc()
-        return None
+    _require_slabbable(s, mesh, axis)
     one_step = make_fish_step_sharded(s, ob, axis)
 
     def megaloop(carry, cfl_eff):

@@ -37,12 +37,12 @@ def enable() -> str:
     return DEFAULT_DIR
 
 
-def count_entries(directory: str) -> int:
-    """Executables in ``directory`` (JAX writes one ``<key>-cache`` file
-    per entry, next to an access-time sidecar); 0 when it does not
-    exist yet."""
+def entries(directory: str) -> set:
+    """Keys of the executables in ``directory`` (JAX writes one
+    ``<key>-cache`` file per entry, next to an access-time sidecar);
+    empty when it does not exist yet."""
     try:
-        return sum(1 for name in os.listdir(directory)
-                   if name.endswith("-cache"))
+        return {name[:-len("-cache")] for name in os.listdir(directory)
+                if name.endswith("-cache")}
     except FileNotFoundError:
-        return 0
+        return set()

@@ -23,9 +23,8 @@ import os
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 import pytest
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from cup3d_tpu.grid.uniform import BC, UniformGrid
@@ -98,12 +97,15 @@ def test_fused_bicgstab_kernels_compile_128(one_chip, store):
 
 
 def test_ring_remote_copy_compiles_on_four_chips(topo):
-    """``_ring_shift_pallas`` under shard_map on the 2x2 host: one
-    x-slab halo message of the 128^3 megaloop (3 ghost planes)."""
+    """``_ring_shift_pallas`` under shard_map on the 2x2 host, on the
+    ``(lanes=1, x=4)`` mesh the megaloop builds (``CUP3D_MESH_X=4``):
+    one x-slab halo message of the 128^3 case (3 ghost planes)."""
     from cup3d_tpu.parallel import ring
+    from cup3d_tpu.parallel import topology as topology_layer
     from cup3d_tpu.parallel.compat import shard_map
 
-    mesh = Mesh(np.asarray(topo.devices).reshape(4), ("x",))
+    mesh = topology_layer.make_mesh2d(lanes=1, x=4,
+                                      devices=list(topo.devices))
 
     def shift(x):
         return ring._ring_shift_pallas(x, "x", 1, 4)

@@ -391,3 +391,38 @@ def test_dt_collapse_triggers_postmortem(tmp_path):
     assert F.load_postmortem(
         os.path.join(tmp_path, files[0])
     )["reason"] == "dt-collapse"
+
+
+# -- device peak table (obs/costs.py) ----------------------------------------
+
+
+class _FakeDevice:
+    def __init__(self, platform, device_kind):
+        self.platform = platform
+        self.device_kind = device_kind
+
+
+def test_unknown_tpu_device_kind_raises():
+    """A TPU the peak table does not know is an error, never another
+    chip's ceilings; the kinds it does know resolve (v5 lite = v5e)."""
+    from cup3d_tpu.obs import costs
+
+    v5e = costs.device_peaks(_FakeDevice("tpu", "TPU v5 lite"))
+    assert v5e.kind == "TPU v5e" and v5e.hbm_bytes_per_s == 819e9
+    with pytest.raises(KeyError, match="PEAK_TABLE"):
+        costs.device_peaks(_FakeDevice("tpu", "TPU v99 imaginary"))
+
+
+def test_cpu_device_has_no_ceiling():
+    """Off the TPU there is no roofline denominator: the live (CPU)
+    backend and any CPU device resolve to None, and the bench's roofline
+    shares read None ("not measured") instead of a share of v5e peaks."""
+    import bench
+    from cup3d_tpu.obs import costs
+
+    assert costs.device_peaks() is None  # conftest pins the CPU backend
+    assert costs.device_peaks(_FakeDevice("cpu", "cpu")) is None
+    r = bench._roofline_dict(1e-3, 4096, 100.0, 50.0)
+    assert r["peaks"] is None
+    assert r["mfu_vs_bf16_peak"] is None and r["hbm_fraction"] is None
+    assert r["cell_iters_per_s"] > 0  # counts and rates still reported

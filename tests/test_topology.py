@@ -19,9 +19,9 @@ fleet 2-D wiring, per-slice elastic recovery; VALIDATION.md "Round 18"):
   /health mesh section record what happened.
 - Zero steady-state retraces: the sharded megaloop serves every
   dispatch from one trace (RecompileCounter budget 1).
-- Loud fallbacks: an unshardable request degrades to the unsharded
-  path with a warning and a counter (fleet.mesh_fallbacks /
-  topology.megaloop_mesh_fallbacks), never silently.
+- No quiet solo runs: an unshardable fleet request degrades with a
+  warning and a counter (fleet.mesh_fallbacks); a megaloop mesh that
+  was asked for (CUP3D_MESH_X) and cannot be had raises.
 """
 
 import os
@@ -144,25 +144,29 @@ def test_shard_carry_places_fields_on_x():
 # -- loud fallbacks --------------------------------------------------------
 
 
-def test_megaloop_mesh_gate_and_loud_fallback(monkeypatch):
+def test_megaloop_mesh_gate_raises_when_unavailable(monkeypatch, tmp_path):
     monkeypatch.delenv("CUP3D_MESH_X", raising=False)
     assert topo.megaloop_mesh() is None
     monkeypatch.setenv("CUP3D_MESH_X", "4")
     m = topo.megaloop_mesh()
     assert m is not None and m.devices.shape == (1, 4)
-    # silent no-mesh cases: off, malformed, <2 — no counter traffic
-    before = M.counter("topology.megaloop_mesh_fallbacks").value
+    # no mesh was asked for: off, malformed, <2
     monkeypatch.setenv("CUP3D_MESH_X", "bogus")
     assert topo.megaloop_mesh() is None
     monkeypatch.setenv("CUP3D_MESH_X", "1")
     assert topo.megaloop_mesh() is None
-    assert M.counter("topology.megaloop_mesh_fallbacks").value == before
-    # more slabs than devices: unsharded fallback, LOUDLY
+    # more slabs than devices: the mesh cannot be had, so it raises —
+    # never an unsharded run under the mesh's name
     monkeypatch.setenv("CUP3D_MESH_X", "16")
-    with pytest.warns(UserWarning, match="unsharded"):
-        assert topo.megaloop_mesh() is None
-    assert (M.counter("topology.megaloop_mesh_fallbacks").value
-            == before + 1)
+    with pytest.raises(RuntimeError, match="CUP3D_MESH_X=16"):
+        topo.megaloop_mesh()
+    # a solver with no slab form raises at the sharded build as well
+    monkeypatch.setenv("CUP3D_MESH_X", "4")
+    sim = Simulation(_tgv_cfg(tmp_path, scan_k=8,
+                              poissonSolver="iterative"))
+    sim.init()
+    with pytest.raises(NotImplementedError, match="spectral"):
+        sim._scan_ready()
 
 
 def test_fleet_mesh_gate_and_loud_fallback(monkeypatch):
