@@ -70,6 +70,17 @@ def check(ok: bool, what: str) -> None:
         raise SystemExit(f"chip_smoke: CHECK FAILED: {what}")
 
 
+def timed(fn, live_state) -> float:
+    """Seconds ``fn()`` takes with its device work done: ``live_state()``
+    is fetched after the call (donated buffers rebind every step)."""
+    import jax
+
+    t0 = time.perf_counter()
+    fn()
+    jax.block_until_ready(live_state())
+    return time.perf_counter() - t0
+
+
 def peak_bytes(device):
     stats = device.memory_stats()
     return None if not stats else stats.get("peak_bytes_in_use")
@@ -181,36 +192,26 @@ def phase_a(n: int, workdir: str, on_tpu: bool, device) -> None:
                    nsteps=1 + per_step + SCAN_K * scan_dispatches)
     sim = Simulation(cfg)
     sim.init()
-    state = sim.sim.state
 
-    def sync():
-        jax.block_until_ready(sim.sim.state["vel"])
+    def vel():
+        return sim.sim.state["vel"]
+
+    def advance(steps):
+        for _ in range(steps):
+            sim.advance(sim.calc_max_timestep())
 
     m0 = obs_metrics.snapshot()
-    t0 = time.perf_counter()
-    sim.advance(sim.calc_max_timestep())
-    sync()
-    first_step_s = time.perf_counter() - t0
-    t0 = time.perf_counter()
-    for _ in range(per_step):
-        sim.advance(sim.calc_max_timestep())
-    sync()
-    step_s = (time.perf_counter() - t0) / per_step
+    first_step_s = timed(lambda: advance(1), vel)
+    step_s = timed(lambda: advance(per_step), vel) / per_step
 
     # simulate(): the remaining budget is whole K-step scan dispatches;
     # the first call compiles the scan, the second (budget extended by
     # the same amount) is steady
-    t0 = time.perf_counter()
-    sim.simulate()
-    sync()
-    scan_first_s = time.perf_counter() - t0
+    scan_first_s = timed(sim.simulate, vel)
     check(sim._scan_k == SCAN_K and sim._scan_carry is not None,
           "simulate() did not take the scan megaloop")
     cfg.nsteps += SCAN_K * scan_dispatches
-    t0 = time.perf_counter()
-    sim.simulate()
-    sync()
-    scan_step_s = (time.perf_counter() - t0) / (SCAN_K * scan_dispatches)
+    scan_step_s = timed(sim.simulate, vel) / (SCAN_K * scan_dispatches)
     check(sim.sim.step == cfg.nsteps, f"stopped at step {sim.sim.step}")
     delta = obs_metrics.delta(m0)
 
@@ -261,10 +262,10 @@ def phase_b(nsteps: int, workdir: str, device) -> None:
         "-path4serialization", os.path.join(workdir, "B"),
     ]
     m0 = obs_metrics.snapshot()
-    t0 = time.perf_counter()
-    sim = cli_main(argv)
-    jax.block_until_ready(sim.state["vel"])
-    main_s = time.perf_counter() - t0
+    ran = []
+    main_s = timed(lambda: ran.append(cli_main(argv)),
+                   lambda: ran[0].state["vel"])
+    sim = ran[0]
     regrids = obs_metrics.delta(m0).get("amr.regrids", 0)
     check(sim.step_idx == nsteps, f"CLI stopped at step {sim.step_idx}")
     check(regrids >= 1, "the run crossed no adaptation")
@@ -276,10 +277,7 @@ def phase_b(nsteps: int, workdir: str, device) -> None:
     sim.cfg.verbose = False
     m1 = obs_metrics.snapshot()
     with RecompileCounter() as rc:
-        t0 = time.perf_counter()
-        sim.simulate()
-        jax.block_until_ready(sim.state["vel"])
-        step_s = (time.perf_counter() - t0) / more
+        step_s = timed(sim.simulate, lambda: sim.state["vel"]) / more
     iters = iters_per_solve(obs_metrics.delta(m1), "amr")
 
     blocks = int(sim.grid.nb)
@@ -330,10 +328,7 @@ def phase_mesh(n: int, workdir: str, on_tpu: bool) -> None:
                                   nsteps=SCAN_K * dispatches,
                                   solver="spectral"))
         sim.init()
-        t0 = time.perf_counter()
-        sim.simulate()
-        jax.block_until_ready(sim.sim.state["vel"])
-        wall = time.perf_counter() - t0
+        wall = timed(sim.simulate, lambda: sim.sim.state["vel"])
         os.environ.pop("CUP3D_MESH_X", None)
         check(sim.sim.step == SCAN_K * dispatches and sim._scan_carry
               is not None, f"{tag}: the scan megaloop did not run")

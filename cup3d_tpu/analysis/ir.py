@@ -97,7 +97,7 @@ def _sub_jaxprs(params: Dict[str, Any]) -> Iterable[Any]:
     ``call_jaxpr`` / ``cond_jaxpr`` / ``body_jaxpr`` / ``branches`` /
     ... — discovered structurally (isinstance on Jaxpr/ClosedJaxpr)
     so new higher-order primitives keep walking without a catalog."""
-    import jax.core as jcore
+    import jax.extend.core as jcore
 
     kinds = (jcore.Jaxpr, jcore.ClosedJaxpr)
     for v in params.values():

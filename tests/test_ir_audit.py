@@ -228,7 +228,7 @@ def test_jp004_bf16_storage_without_accumulation_is_clean():
 
 
 def test_jp004_f64_fires():
-    from jax.experimental import enable_x64
+    from jax import enable_x64
 
     with enable_x64():
         fn = jax.jit(lambda x: x * 2.0)
