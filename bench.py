@@ -966,30 +966,32 @@ def _roofline_dict(per_iter: float, cells: int, flops_per_cell: float,
     from cup3d_tpu.obs import costs as obs_costs
 
     peaks = obs_costs.device_peaks()
+    peak_flops = peaks.bf16_flops if peaks else None
+    peak_bw = peaks.hbm_bytes_per_s if peaks else None
     flops = flops_per_cell * cells
     bytes_ = bytes_per_cell * cells
 
-    def share(amount, peak_attr, digits):
-        if peaks is None or not amount:
+    def share(amount, peak, digits):
+        if not peak or not amount:
             return None
-        return round(amount / per_iter / getattr(peaks, peak_attr), digits)
+        return round(amount / per_iter / peak, digits)
 
     out = {
         "bicgstab_iter_device_ms": round(per_iter * 1e3, 3),
         "cell_iters_per_s": round(cells / per_iter / 1e6, 1),
         "est_gflops": round(flops / per_iter / 1e9, 1),
-        "mfu_vs_bf16_peak": share(flops, "bf16_flops", 5),
+        "mfu_vs_bf16_peak": share(flops, peak_flops, 5),
         "est_hbm_gbs": round(bytes_ / per_iter / 1e9, 1),
-        "hbm_fraction": share(bytes_, "hbm_bytes_per_s", 4),
-        "peaks": None if peaks is None else peaks.as_dict(),
+        "hbm_fraction": share(bytes_, peak_bw, 4),
+        "peaks": peaks.as_dict() if peaks else None,
     }
     if compiler is not None:
         out["compiler"] = compiler
         if compiler.get("available"):
             out["mfu_vs_bf16_peak_compiler"] = share(
-                compiler.get("flops_per_iter"), "bf16_flops", 5)
+                compiler.get("flops_per_iter"), peak_flops, 5)
             out["hbm_fraction_compiler"] = share(
-                compiler.get("bytes_per_iter"), "hbm_bytes_per_s", 4)
+                compiler.get("bytes_per_iter"), peak_bw, 4)
     return out
 
 
@@ -1235,7 +1237,8 @@ def bench_amr_tgv():
     # crosses adaptation boundaries — with capacity bucketing the
     # within-bucket regrids reuse compiled executables, so `recompiles`
     # counts only genuine bucket changes and p95/max stay near the
-    # steady wall (the BENCH_r05 5.50 s max-step bug class; round-5 chip run, record removed)
+    # steady wall (the BENCH_r05 5.50 s max-step bug class; round-5
+    # chip run, record removed)
     sim.adapt_enabled = True
     compiles_before = rc.total_compiles
     m0 = obs_metrics.snapshot()
@@ -2083,12 +2086,12 @@ def bench_cold_start():
     }
 
 
-def bench_durability():
+def bench_durability(drill: Optional[dict] = None):
     """Round-23 durable-serving config: the crash-restart drill
-    (:func:`_durability_drill`, three child processes) plus the
-    in-process journal-overhead gate (adjacent on/off drain pairs,
-    ``_journal_overhead``, <= 3%)."""
-    out = _durability_drill()
+    (:func:`_durability_drill`, three child processes; ``drill`` hands
+    in one that already ran) plus the in-process journal-overhead gate
+    (adjacent on/off drain pairs, ``_journal_overhead``, <= 3%)."""
+    out = _durability_drill() if drill is None else dict(drill)
     out.update(_journal_overhead(lanes=4, n=out["n"]))
     return out
 
@@ -2237,8 +2240,8 @@ def main() -> int:
         # run here, before this process's first device call, because a
         # parent that has touched JAX holds the chip and its children
         # then fail or hang
-        early = {"cold_start": _isolated(bench_cold_start),
-                 "durability": _isolated(_durability_drill)}
+        secondary["cold_start"] = _isolated(bench_cold_start)
+        drill = _isolated(_durability_drill)
     fish = None
     if which in ("fish", "fish256", "all"):
         fish = _isolated(
@@ -2257,17 +2260,13 @@ def main() -> int:
     if which == "all":
         secondary["fish256"] = _isolated(lambda: bench_fish_uniform(256))
     for sel, (key, fn) in SECONDARY.items():
-        if which not in ("all", sel):
+        if which not in ("all", sel) or key in secondary:
             continue
-        if which == "all" and key == "cold_start":
-            secondary[key] = early[key]
-        elif which == "all" and key == "durability":
-            drill = early[key]
-            secondary[key] = drill if "error" in drill else _isolated(
-                lambda: {**drill, **_journal_overhead(lanes=4,
-                                                      n=drill["n"])})
-        else:
-            secondary[key] = _isolated(fn)
+        if which == "all" and key == "durability":
+            # the drill ran first; its in-process half runs here
+            fn = ((lambda: drill) if "error" in drill
+                  else (lambda: bench_durability(drill)))
+        secondary[key] = _isolated(fn)
 
     if fish is None:  # single-config run: promote one result to headline
         key, data = next(

@@ -70,15 +70,15 @@ def check(ok: bool, what: str) -> None:
         raise SystemExit(f"chip_smoke: CHECK FAILED: {what}")
 
 
-def timed(fn, live_state) -> float:
-    """Seconds ``fn()`` takes with its device work done: ``live_state()``
+def timed(fn, live_state):
+    """``(seconds, fn())`` with the device work done: ``live_state(result)``
     is fetched after the call (donated buffers rebind every step)."""
     import jax
 
     t0 = time.perf_counter()
-    fn()
-    jax.block_until_ready(live_state())
-    return time.perf_counter() - t0
+    result = fn()
+    jax.block_until_ready(live_state(result))
+    return time.perf_counter() - t0, result
 
 
 def peak_bytes(device):
@@ -193,7 +193,7 @@ def phase_a(n: int, workdir: str, on_tpu: bool, device) -> None:
     sim = Simulation(cfg)
     sim.init()
 
-    def vel():
+    def vel(_):
         return sim.sim.state["vel"]
 
     def advance(steps):
@@ -201,17 +201,17 @@ def phase_a(n: int, workdir: str, on_tpu: bool, device) -> None:
             sim.advance(sim.calc_max_timestep())
 
     m0 = obs_metrics.snapshot()
-    first_step_s = timed(lambda: advance(1), vel)
-    step_s = timed(lambda: advance(per_step), vel) / per_step
+    first_step_s, _ = timed(lambda: advance(1), vel)
+    step_s = timed(lambda: advance(per_step), vel)[0] / per_step
 
     # simulate(): the remaining budget is whole K-step scan dispatches;
     # the first call compiles the scan, the second (budget extended by
     # the same amount) is steady
-    scan_first_s = timed(sim.simulate, vel)
+    scan_first_s, _ = timed(sim.simulate, vel)
     check(sim._scan_k == SCAN_K and sim._scan_carry is not None,
           "simulate() did not take the scan megaloop")
     cfg.nsteps += SCAN_K * scan_dispatches
-    scan_step_s = timed(sim.simulate, vel) / (SCAN_K * scan_dispatches)
+    scan_step_s = timed(sim.simulate, vel)[0] / (SCAN_K * scan_dispatches)
     check(sim.sim.step == cfg.nsteps, f"stopped at step {sim.sim.step}")
     delta = obs_metrics.delta(m0)
 
@@ -248,7 +248,6 @@ def phase_a(n: int, workdir: str, on_tpu: bool, device) -> None:
 
 
 def phase_b(nsteps: int, workdir: str, device) -> None:
-    import jax
 
     from bench import _div_gate
     from cup3d_tpu import native
@@ -262,10 +261,7 @@ def phase_b(nsteps: int, workdir: str, device) -> None:
         "-path4serialization", os.path.join(workdir, "B"),
     ]
     m0 = obs_metrics.snapshot()
-    ran = []
-    main_s = timed(lambda: ran.append(cli_main(argv)),
-                   lambda: ran[0].state["vel"])
-    sim = ran[0]
+    main_s, sim = timed(lambda: cli_main(argv), lambda s: s.state["vel"])
     regrids = obs_metrics.delta(m0).get("amr.regrids", 0)
     check(sim.step_idx == nsteps, f"CLI stopped at step {sim.step_idx}")
     check(regrids >= 1, "the run crossed no adaptation")
@@ -277,7 +273,7 @@ def phase_b(nsteps: int, workdir: str, device) -> None:
     sim.cfg.verbose = False
     m1 = obs_metrics.snapshot()
     with RecompileCounter() as rc:
-        step_s = timed(sim.simulate, lambda: sim.state["vel"]) / more
+        step_s = timed(sim.simulate, lambda _: sim.state["vel"])[0] / more
     iters = iters_per_solve(obs_metrics.delta(m1), "amr")
 
     blocks = int(sim.grid.nb)
@@ -311,7 +307,6 @@ def phase_mesh(n: int, workdir: str, on_tpu: bool) -> None:
     sharded scan body solves Poisson replicated with the spectral solver
     (the iterative front-ends have no slab form; asking for them under a
     mesh raises), so both legs use it."""
-    import jax
     import jax.numpy as jnp
     import numpy as np
 
@@ -328,7 +323,7 @@ def phase_mesh(n: int, workdir: str, on_tpu: bool) -> None:
                                   nsteps=SCAN_K * dispatches,
                                   solver="spectral"))
         sim.init()
-        wall = timed(sim.simulate, lambda: sim.sim.state["vel"])
+        wall, _ = timed(sim.simulate, lambda _: sim.sim.state["vel"])
         os.environ.pop("CUP3D_MESH_X", None)
         check(sim.sim.step == SCAN_K * dispatches and sim._scan_carry
               is not None, f"{tag}: the scan megaloop did not run")
