@@ -197,6 +197,29 @@ def test_fused_matches_legacy_dynamic_solver(monkeypatch, two_level):
     assert rel <= 1e-4, rel
 
 
+@pytest.mark.parametrize("knob", [("CUP3D_FUSED", "1"),
+                                  ("CUP3D_KRYLOV_DTYPE", "bf16")])
+def test_fused_forest_refused_where_kernels_would_compile(monkeypatch,
+                                                          knob):
+    """The fused forest stages abort the TPU's compiler (module
+    docstring), so where they would be compiled natively — a backend on
+    which ``use_pallas()`` is true — selecting the fused forest solver
+    raises at BUILD time; the stock solver builds as ever, and nothing
+    stands in for the refused one."""
+    from cup3d_tpu.ops import getz_pallas
+
+    monkeypatch.delenv("CUP3D_FUSED", raising=False)
+    monkeypatch.delenv("CUP3D_KRYLOV_DTYPE", raising=False)
+    monkeypatch.setattr(getz_pallas, "use_pallas", lambda: True)
+    amr_ops.build_amr_poisson_solver_dynamic(BS)  # stock f32: fine
+    monkeypatch.setenv(*knob)
+    with pytest.raises(NotImplementedError, match="do not compile"):
+        amr_ops.build_amr_poisson_solver_dynamic(BS)
+    # pinned-row modes keep the legacy loop and are not refused
+    if knob[0] == "CUP3D_FUSED":
+        amr_ops.build_amr_poisson_solver_dynamic(BS, mean_constraint=1)
+
+
 def test_padding_rows_contribute_nothing(monkeypatch):
     """Garbage in the padding rows of the INPUT rhs is masked out by the
     dynamic solver's pmask and never reaches the real solution; the
