@@ -1,0 +1,13 @@
+"""Host wall of the CreateObstacles section of the program's profiler,
+over steps (per-step paths only: a scan has no such section)."""
+
+META = {"name": "operators.create_obstacles_host_ms", "layer": "operators", "unit": "ms", "moves": "step_ms",
+        "source": "program_span", "better": "lower"}
+
+
+def read(ctx):
+    t = ctx["profiler"].get("CreateObstacles")
+    w = ctx["window"]
+    if not t or not w["steps"]:
+        return None
+    return 1e3 * t / w["steps"]
