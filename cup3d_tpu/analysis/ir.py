@@ -44,6 +44,7 @@ caller (audit.py) brings the jaxpr / Lowered / Compiled objects.
 
 from __future__ import annotations
 
+import re
 from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
 
 from cup3d_tpu.analysis.rules import Violation
@@ -281,14 +282,20 @@ def donated_leaf_indices(args: Sequence[Any],
 def aliased_params_from_lowered(mlir_text: str) -> List[int]:
     """``@main`` argument indices whose donation survived lowering:
     ``tf.aliasing_output`` when jax resolved the alias itself, or
-    ``jax.buffer_donor`` when the module carries shardings and the
-    aliasing decision is deferred to the XLA SPMD partitioner (the
-    compiled header is then the ground truth — sharded entries keep
-    ``compile=True``).  An unaliasable donated arg gets NEITHER mark,
-    plus a UserWarning at lowering time."""
+    ``jax.buffer_donor`` when the module is partitioned
+    (``mhlo.num_partitions`` > 1) and the aliasing decision is deferred
+    to the XLA SPMD partitioner (the compiled header is then the ground
+    truth — sharded entries keep ``compile=True``).  In an unpartitioned
+    module ``jax.buffer_donor`` is no alias: jax (0.9) hands XLA a
+    donated arg that matched no output in shape and dtype, only in
+    element count, and XLA cannot alias buffers of different bytes.  A
+    donated arg that matches nothing gets NEITHER mark, plus a
+    UserWarning at lowering time."""
     start = mlir_text.find("@main(")
     if start < 0:
         return []
+    parts = re.search(r"mhlo\.num_partitions = (\d+)", mlir_text)
+    deferred = parts is not None and int(parts.group(1)) > 1
     i = start + len("@main(")
     depth = 1
     j = i
@@ -311,7 +318,8 @@ def aliased_params_from_lowered(mlir_text: str) -> List[int]:
         # JP001 missing-alias finding, never silently)
         except ValueError:
             continue
-        if "tf.aliasing_output" in chunk or "jax.buffer_donor" in chunk:
+        if "tf.aliasing_output" in chunk or (
+                deferred and "jax.buffer_donor" in chunk):
             out.append(idx)
     return sorted(out)
 
@@ -320,8 +328,6 @@ def aliased_params_from_compiled(hlo_text: str) -> List[int]:
     """Input parameter numbers in the scheduled HLO header's
     ``input_output_alias={ {out}: (param, {}, may-alias), ... }`` map —
     what the compiled executable actually aliases."""
-    import re
-
     start = hlo_text.find("input_output_alias={")
     if start < 0:
         return []

@@ -161,22 +161,28 @@ class CompileService:
                     return False
                 self._cv.wait(min(remaining, 0.25))
 
+    @staticmethod
+    def _orphaned(task: dict) -> bool:
+        """A task is orphaned when the thread that took it is dead."""
+        return task["status"] == RUNNING and not task["owner"].is_alive()
+
     def fail_orphans(self) -> int:
-        """Death-path recovery (round 23): when the worker thread died
+        """Death-path recovery (round 23): when a worker thread died
         (``compile.service_die``, or any uncatchable thread death), its
         popped-but-unfinished build is stuck RUNNING forever — nothing
         requeues it, so ``depth()`` never reaches zero and every waiter
         parks.  Mark such orphans FAILED (the schedulers' existing
         failed-build path then compiles inline, a transparent
         degradation counted ``aot.service_fallbacks``) and restart the
-        worker for any still-PENDING queue entries.  Returns the number
-        of orphans failed; 0 while the worker is alive."""
+        worker for any still-PENDING queue entries.  Orphans are judged
+        by the thread that took them, not by the service's current
+        thread: a later ``submit`` may already have started a new
+        worker, alive and idle beside the dead one's task.  Returns the
+        number of orphans failed."""
         with self._cv:
-            if self._thread is not None and self._thread.is_alive():
-                return 0
             n = 0
             for task in self._tasks.values():
-                if task["status"] == RUNNING:
+                if self._orphaned(task):
                     task["status"] = FAILED
                     task["build"] = None
                     n += 1
@@ -190,11 +196,14 @@ class CompileService:
         return n
 
     def state(self) -> dict:
-        """The /health payload."""
+        """The /health payload.  A RUNNING task whose owner died counts
+        under ``orphaned``, not ``running``: the state that parks every
+        waiter shows before ``fail_orphans`` reaps it."""
         with self._cv:
             counts: Dict[str, int] = {}
             for t in self._tasks.values():
-                counts[t["status"]] = counts.get(t["status"], 0) + 1
+                kind = "orphaned" if self._orphaned(t) else t["status"]
+                counts[kind] = counts.get(kind, 0) + 1
             return {"queue_depth": self.depth_locked(),
                     "tasks": counts,
                     "worker_alive": bool(
@@ -222,6 +231,7 @@ class CompileService:
                     self._cv.wait()
                 task = self._tasks[key]
                 task["status"] = RUNNING
+                task["owner"] = threading.current_thread()
                 build, name = task["build"], task["name"]
             # the chaos seam: the worker dies mid-task, leaving this
             # build orphaned RUNNING — exactly the state fail_orphans()

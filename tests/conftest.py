@@ -30,3 +30,50 @@ if "xla_force_host_platform_device_count" not in prev:
 import jax
 
 jax.config.update("jax_platforms", "cpu")
+
+import faulthandler
+import signal
+import tempfile
+
+import pytest
+
+#: Seconds one test's setup or call may take: 3.6 x the slowest phase
+#: measured (83.6 s).  A parked test then costs its run five minutes,
+#: not the files queued behind it on the same xdist worker.
+TEST_LIMIT_S = 300
+
+
+@pytest.hookimpl(wrapper=True)
+def pytest_runtest_call(item):
+    """Fail ``item`` by name, with every thread's stack, when the phase
+    outlasts TEST_LIMIT_S.  The handler raises in the main thread (pytest
+    and xdist run the tests there) as soon as it is back in Python."""
+
+    def on_alarm(signum, frame):
+        with tempfile.TemporaryFile("w+") as f:
+            faulthandler.dump_traceback(file=f, all_threads=True)
+            f.seek(0)
+            pytest.fail(f"{item.nodeid} exceeded its limit of "
+                        f"{TEST_LIMIT_S} s\n{f.read()}", pytrace=False)
+
+    old = signal.signal(signal.SIGALRM, on_alarm)
+    signal.setitimer(signal.ITIMER_REAL, TEST_LIMIT_S)
+    try:
+        return (yield)
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, old)
+
+
+pytest_runtest_setup = pytest_runtest_call  # fixtures get the same limit
+
+
+@pytest.fixture
+def clean_faults():
+    """No armed fault reaches a test or outlives it (the files that arm
+    faults ask for it with ``pytestmark = usefixtures``)."""
+    from cup3d_tpu.resilience import faults
+
+    faults.clear()
+    yield
+    faults.clear()

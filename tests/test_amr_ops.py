@@ -11,19 +11,13 @@ from cup3d_tpu.grid.flux import build_flux_tables
 from cup3d_tpu.grid.octree import Octree, TreeConfig
 from cup3d_tpu.grid.uniform import BC, UniformGrid
 from cup3d_tpu.ops import amr_ops
+from tests._grids import two_level_grid
 from tests.test_blocks import BS, blocks_from_dense
 
 
 def _uniform_block_grid(n_blocks=2):
     t = Octree(TreeConfig((n_blocks,) * 3, 1, (True,) * 3), 0)
     return BlockGrid(t, (float(n_blocks),) * 3, (BC.periodic,) * 3, bs=BS)
-
-
-def _two_level_grid():
-    t = Octree(TreeConfig((2, 2, 2), 2, (True,) * 3), 0)
-    t.refine((0, 0, 0, 0))
-    t.assert_balanced()
-    return BlockGrid(t, (2.0, 2.0, 2.0), (BC.periodic,) * 3, bs=BS)
 
 
 def test_laplacian_uniform_topology_matches_dense():
@@ -47,7 +41,7 @@ def test_refluxed_laplacian_is_conservative():
     """sum over the domain of lap(f) h^3 must vanish on a periodic 2-level
     grid — the defining property of conservative refluxing (reference
     FillBlockCases, main.cpp:729-801)."""
-    g = _two_level_grid()
+    g = two_level_grid(2.0)
     rng = np.random.default_rng(1)
     f = jnp.asarray(rng.standard_normal((g.nb, BS, BS, BS)).astype(np.float32))
     vol = (g.h**3).reshape(g.nb, 1, 1, 1)
@@ -67,7 +61,7 @@ def test_refluxed_laplacian_is_conservative():
 def test_laplacian_two_level_linear_exact():
     """lap of a linear field is zero everywhere, including at coarse-fine
     interfaces (ghosts and refluxing are exact for linears)."""
-    g = _two_level_grid()
+    g = two_level_grid(2.0)
     xc = g.cell_centers(np.float64)
     f = jnp.asarray(
         (1.0 + 0.5 * xc[..., 0] - 0.25 * xc[..., 1]).astype(np.float32)
@@ -116,7 +110,7 @@ def test_advdiff_uniform_topology_matches_dense():
 
 
 def test_amr_poisson_solver_converges():
-    g = _two_level_grid()
+    g = two_level_grid(2.0)
     xc = g.cell_centers(np.float64)
     rhs = np.sin(np.pi * xc[..., 0]) * np.cos(np.pi * xc[..., 1]) * np.cos(
         2 * np.pi * xc[..., 2]

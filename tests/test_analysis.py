@@ -28,6 +28,14 @@ def _rules(vs):
     return {v.rule for v in vs}
 
 
+def _cli(*args):
+    """``python -m cup3d_tpu.analysis`` in a process of its own (the
+    whole package lints in ~5 s; the limit is for a hung child)."""
+    return subprocess.run(
+        [sys.executable, "-m", "cup3d_tpu.analysis", *args],
+        capture_output=True, text=True, timeout=120)
+
+
 # -- per-rule fixtures: firing and suppressed ------------------------------
 
 
@@ -795,11 +803,7 @@ def test_jx017_in_tree_roofline_paths_are_clean():
     """The burn-down stays burned down: bench.py and the obs/tools
     trees carry no unannotated hardware-peak literal (the peak table in
     obs/costs.py is path-exempt by design)."""
-    out = subprocess.run(
-        [sys.executable, "-m", "cup3d_tpu.analysis", "--rules", "JX017",
-         "bench.py", "cup3d_tpu/", "tools/", "-q"],
-        capture_output=True, text=True,
-    )
+    out = _cli("--rules", "JX017", "bench.py", "cup3d_tpu/", "tools/", "-q")
     assert out.returncode == 0, out.stdout + out.stderr
 
 
@@ -852,18 +856,6 @@ def test_jx018_raw_collective_fires_suppresses_and_scopes():
         v.rule == "JX018" and v.suppressed and
         v.suppression_reason == "staging for parallel/ migration"
         for v in all_vs)
-
-
-def test_jx018_package_is_clean():
-    """The burn-down stays burned down: after rerouting the sharded
-    megaloop through parallel/collectives.py, no raw collective call
-    site survives outside the seam (baseline EMPTY for this rule)."""
-    out = subprocess.run(
-        [sys.executable, "-m", "cup3d_tpu.analysis", "--rules", "JX018",
-         "--no-baseline", "cup3d_tpu/", "-q"],
-        capture_output=True, text=True,
-    )
-    assert out.returncode == 0, out.stdout + out.stderr
 
 
 def test_jx019_aot_seam_fires_suppresses_and_scopes():
@@ -927,18 +919,6 @@ def test_jx019_aot_seam_fires_suppresses_and_scopes():
         v.rule == "JX019" and v.suppressed and
         v.suppression_reason == "one-shot debug harness"
         for v in all_vs)
-
-
-def test_jx019_package_is_clean():
-    """The burn-down stays burned down: every compile-producing call
-    site routes through cup3d_tpu/aot/ (or the exempt obs/costs.py
-    harvest) — baseline EMPTY for this rule."""
-    out = subprocess.run(
-        [sys.executable, "-m", "cup3d_tpu.analysis", "--rules", "JX019",
-         "--no-baseline", "cup3d_tpu/", "-q"],
-        capture_output=True, text=True,
-    )
-    assert out.returncode == 0, out.stdout + out.stderr
 
 
 def test_jx020_raw_clock_fires_suppresses_and_scopes():
@@ -1013,18 +993,6 @@ def test_jx020_raw_clock_fires_suppresses_and_scopes():
         for v in all_vs)
 
 
-def test_jx020_package_is_clean():
-    """The burn-down stays burned down: every clock read in the
-    package routes through obs.trace.now()/wall() — baseline EMPTY
-    for this rule."""
-    out = subprocess.run(
-        [sys.executable, "-m", "cup3d_tpu.analysis", "--rules", "JX020",
-         "--no-baseline", "cup3d_tpu/", "-q"],
-        capture_output=True, text=True,
-    )
-    assert out.returncode == 0, out.stdout + out.stderr
-
-
 def test_jx021_status_mutation_fires_suppresses_and_scopes():
     """Fleet job status mutated outside the journal-logging seam
     (round 23): a transition the write-ahead journal never records is
@@ -1088,14 +1056,15 @@ def test_jx021_status_mutation_fires_suppresses_and_scopes():
         for v in all_vs)
 
 
-def test_jx021_package_is_clean():
-    """EMPTY baseline: every fleet status transition routes through a
-    sanctioned journal-logging seam."""
-    out = subprocess.run(
-        [sys.executable, "-m", "cup3d_tpu.analysis", "--rules", "JX021",
-         "--no-baseline", "cup3d_tpu/", "-q"],
-        capture_output=True, text=True,
-    )
+@pytest.mark.parametrize("rule", ["JX018", "JX019", "JX020", "JX021"])
+def test_package_is_clean_with_empty_baseline(rule):
+    """The burn-downs stay burned down, with the baseline EMPTY for
+    each rule: no raw collective call site outside
+    parallel/collectives.py (JX018); every compile-producing call site
+    through cup3d_tpu/aot/ or the exempt obs/costs.py harvest (JX019);
+    every clock read through obs.trace.now()/wall() (JX020); every
+    fleet status transition through a journal-logging seam (JX021)."""
+    out = _cli("--rules", rule, "--no-baseline", "cup3d_tpu/", "-q")
     assert out.returncode == 0, out.stdout + out.stderr
 
 
@@ -1265,19 +1234,12 @@ def test_package_lints_clean_with_reasons():
 
 
 def test_cli_exits_zero_on_package():
-    proc = subprocess.run(
-        [sys.executable, "-m", "cup3d_tpu.analysis", _package_root(),
-         "-q"],
-        capture_output=True, text=True, timeout=300,
-    )
+    proc = _cli(_package_root(), "-q")
     assert proc.returncode == 0, proc.stdout + proc.stderr
 
 
 def test_cli_lists_rules():
-    proc = subprocess.run(
-        [sys.executable, "-m", "cup3d_tpu.analysis", "--list-rules"],
-        capture_output=True, text=True, timeout=300,
-    )
+    proc = _cli("--list-rules")
     assert proc.returncode == 0
     for rid in RULES:
         assert rid in proc.stdout

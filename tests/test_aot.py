@@ -35,10 +35,7 @@ from cup3d_tpu.aot import store as aot_store
 from cup3d_tpu.aot.compiler import CompileService
 from cup3d_tpu.aot.store import ExecutableStore, StoreBackedExecutable
 from cup3d_tpu.obs import metrics as M
-
-
-def _delta(before, key):
-    return M.snapshot().get(key, 0) - before.get(key, 0)
+from tests._cases import delta, tgv_spec
 
 
 def _f(x):
@@ -47,12 +44,6 @@ def _f(x):
 
 def _wrapper(store, sig=("test", 1), name="test-exec"):
     return StoreBackedExecutable(jax.jit(_f), sig, name=name, store=store)
-
-
-def _tgv_spec(**kw):
-    spec = dict(kind="tgv", n=16, nsteps=8, cfl=0.3)
-    spec.update(kw)
-    return spec
 
 
 # -- store round trip -------------------------------------------------------
@@ -67,12 +58,12 @@ def test_store_roundtrip_bitwise(tmp_path):
 
     w1 = _wrapper(store)
     y1 = np.asarray(w1(x))
-    assert _delta(before, "aot.store_writes") == 1
+    assert delta(before, "aot.store_writes") == 1
     assert store.contains(("test", 1))
 
     w2 = _wrapper(store)  # fresh wrapper, same sig: loads, no compile
     y2 = np.asarray(w2(x))
-    assert _delta(before, "aot.store_hits") == 1
+    assert delta(before, "aot.store_hits") == 1
     assert y1.tobytes() == y2.tobytes()
 
     y_fresh = np.asarray(jax.jit(_f)(x))
@@ -116,7 +107,7 @@ def test_fingerprint_mismatch_rejected(tmp_path):
     _tamper_record(path, wrong_world)
     before = M.snapshot()
     y1 = np.asarray(_wrapper(store)(x))  # transparent recompile
-    assert _delta(before, "aot.store_rejects{reason=fingerprint}") == 1
+    assert delta(before, "aot.store_rejects{reason=fingerprint}") == 1
     assert y0.tobytes() == y1.tobytes()
     assert not os.path.exists(path) or store.contains(("test", 1))
 
@@ -128,7 +119,7 @@ def test_sig_collision_rejected(tmp_path):
     _tamper_record(path, lambda rec: rec.update(sig="('other', 99)"))
     before = M.snapshot()
     assert store.get(("test", 1)) is None
-    assert _delta(before, "aot.store_rejects{reason=sig-collision}") == 1
+    assert delta(before, "aot.store_rejects{reason=sig-collision}") == 1
 
 
 @pytest.mark.parametrize("damage,reason", [
@@ -151,7 +142,7 @@ def test_corrupt_artifact_rejected(tmp_path, damage, reason):
     before = M.snapshot()
     y1 = np.asarray(_wrapper(store)(x))
     key = "aot.store_rejects{reason=%s}" % reason
-    assert _delta(before, key) == 1
+    assert delta(before, key) == 1
     assert y0.tobytes() == y1.tobytes()
 
 
@@ -181,7 +172,7 @@ def test_warm_boot_zero_advance_compiles(tmp_path, monkeypatch):
     monkeypatch.setenv("CUP3D_AOT_STORE", str(tmp_path / "store"))
     srv1 = FleetServer(workdir=str(tmp_path / "wd1"))
     for i in range(2):
-        srv1.submit(f"t{i}", _tgv_spec())
+        srv1.submit(f"t{i}", tgv_spec())
     srv1.drain()
     store = aot_store.active_store()
     assert store.state()["files"] >= 1
@@ -189,18 +180,18 @@ def test_warm_boot_zero_advance_compiles(tmp_path, monkeypatch):
     before = M.snapshot()
     with RecompileCounter() as rc:
         srv2 = FleetServer(workdir=str(tmp_path / "wd2"))
-        ids = [srv2.submit(f"t{i}", _tgv_spec()) for i in range(2)]
+        ids = [srv2.submit(f"t{i}", tgv_spec()) for i in range(2)]
         srv2.drain()
     assert all(srv2._jobs[j].status == "done" for j in ids)
     advance = {k: v for k, v in rc.compiles.items() if "advance" in k}
     assert not advance, advance
-    assert _delta(before, "aot.store_hits") >= 1
+    assert delta(before, "aot.store_hits") >= 1
 
     # control: the same boot WITHOUT a store recompiles the advance
     monkeypatch.delenv("CUP3D_AOT_STORE")
     with RecompileCounter() as rc_cold:
         srv3 = FleetServer(workdir=str(tmp_path / "wd3"))
-        ids = [srv3.submit(f"t{i}", _tgv_spec()) for i in range(2)]
+        ids = [srv3.submit(f"t{i}", tgv_spec()) for i in range(2)]
         srv3.drain()
     assert all(srv3._jobs[j].status == "done" for j in ids)
     assert any("advance" in k for k in rc_cold.compiles), rc_cold.compiles
@@ -220,7 +211,7 @@ def test_compile_wait_phase_cold_then_warm(tmp_path, monkeypatch):
     OT.TRACE.configure(enabled=True, directory=td)
     try:
         srv1 = FleetServer(workdir=str(tmp_path / "wd1"))
-        ids = [srv1.submit(f"t{i}", _tgv_spec()) for i in range(2)]
+        ids = [srv1.submit(f"t{i}", tgv_spec()) for i in range(2)]
         srv1.drain()
         OT.TRACE.close()
     finally:
@@ -254,7 +245,7 @@ def test_compile_wait_phase_cold_then_warm(tmp_path, monkeypatch):
 
     # warm boot: the signature deserializes — nobody waits on a compile
     srv2 = FleetServer(workdir=str(tmp_path / "wd2"))
-    ids2 = [srv2.submit(f"t{i}", _tgv_spec()) for i in range(2)]
+    ids2 = [srv2.submit(f"t{i}", tgv_spec()) for i in range(2)]
     srv2.drain()
     assert all(srv2._jobs[j].status == "done" for j in ids2)
     for j in ids2:
@@ -268,7 +259,7 @@ def test_health_reports_aot_state(tmp_path, monkeypatch):
 
     monkeypatch.setenv("CUP3D_AOT_STORE", str(tmp_path / "store"))
     srv = FleetServer(workdir=str(tmp_path / "wd"))
-    srv.submit("t", _tgv_spec())
+    srv.submit("t", tgv_spec())
     srv.drain()
     aot = srv.health()["aot"]
     assert aot["store"]["files"] >= 1
@@ -295,7 +286,7 @@ def test_cross_process_store_reuse(tmp_path):
              "--scenarios", str(spec_path),
              "--store", str(tmp_path / "store"),
              "--workdir", str(tmp_path / f"wd-{tag}")],
-            capture_output=True, text=True, env=env, timeout=600)
+            capture_output=True, text=True, env=env, timeout=120)
         assert out.returncode == 0, out.stderr[-500:]
         return json.loads(out.stdout)
 
@@ -343,13 +334,13 @@ def test_background_miss_queue_serve(tmp_path, monkeypatch):
     monkeypatch.setenv("CUP3D_AOT_STORE", str(tmp_path / "store"))
     before = M.snapshot()
     srv = FleetServer(workdir=str(tmp_path / "wd"))
-    ids = [srv.submit(f"t{i}", _tgv_spec()) for i in range(2)]
+    ids = [srv.submit(f"t{i}", tgv_spec()) for i in range(2)]
     srv.drain()
     assert all(srv._jobs[j].status == "done" for j in ids)
-    assert _delta(before, "aot.compile_submits{kind=demand}") >= 1
-    assert _delta(before, "aot.background_compiles") >= 1
-    assert _delta(before, "aot.background_installs") >= 1
-    assert _delta(before, "aot.store_writes") >= 1
+    assert delta(before, "aot.compile_submits{kind=demand}") >= 1
+    assert delta(before, "aot.background_compiles") >= 1
+    assert delta(before, "aot.background_installs") >= 1
+    assert delta(before, "aot.store_writes") >= 1
 
 
 @pytest.mark.slow
@@ -363,11 +354,11 @@ def test_speculative_rung_precompile(tmp_path, monkeypatch):
     monkeypatch.setenv("CUP3D_AOT_SPECULATE", "1")
     before = M.snapshot()
     srv = FleetServer(workdir=str(tmp_path / "wd"))
-    ids = [srv.submit(f"t{i}", _tgv_spec()) for i in range(2)]
+    ids = [srv.submit(f"t{i}", tgv_spec()) for i in range(2)]
     srv.drain()
     assert all(srv._jobs[j].status == "done" for j in ids)
-    assert _delta(before, "aot.compile_submits{kind=speculative}") >= 1
-    assert _delta(before, "aot.speculative_compiles") >= 1
+    assert delta(before, "aot.compile_submits{kind=speculative}") >= 1
+    assert delta(before, "aot.speculative_compiles") >= 1
     # the speculative executable landed on disk for the next boot
     store = aot_store.active_store()
     assert store.state()["files"] >= 2
@@ -380,10 +371,10 @@ def test_speculation_disabled_by_env(tmp_path, monkeypatch):
     monkeypatch.setenv("CUP3D_AOT_SPECULATE", "0")
     before = M.snapshot()
     srv = FleetServer(workdir=str(tmp_path / "wd"))
-    ids = [srv.submit(f"t{i}", _tgv_spec()) for i in range(2)]
+    ids = [srv.submit(f"t{i}", tgv_spec()) for i in range(2)]
     srv.drain()
     assert all(srv._jobs[j].status == "done" for j in ids)
-    assert _delta(before, "aot.compile_submits{kind=speculative}") == 0
+    assert delta(before, "aot.compile_submits{kind=speculative}") == 0
 
 
 # -- GC bound ---------------------------------------------------------------
@@ -408,7 +399,7 @@ def test_gc_keeps_store_under_bound(tmp_path):
     store.max_bytes = 2 * one + one // 2  # room for two entries
     store.gc()
     assert store.total_bytes() <= store.max_bytes
-    assert _delta(before, "aot.store_gc_evictions") >= 1
+    assert delta(before, "aot.store_gc_evictions") >= 1
     assert not store.contains(sigs[0])  # oldest went first
     assert store.contains(sigs[2])
     assert store.get(sigs[2], name="gc-2") is not None

@@ -13,6 +13,7 @@ from cup3d_tpu.grid.blocks import (
 from cup3d_tpu.grid.octree import Octree, TreeConfig
 from cup3d_tpu.grid.sfc import hilbert_index
 from cup3d_tpu.grid.uniform import BC, UniformGrid
+from tests._grids import two_level_grid
 
 BS = 8
 
@@ -174,13 +175,6 @@ def test_uniform_topology_vector_lab_matches_dense_pad(bc):
 # -- two-level interpolation -----------------------------------------------
 
 
-def _two_level_grid():
-    t = _tree(bpd=(2, 2, 2), level_max=2)
-    t.refine((0, 0, 0, 0))
-    t.assert_balanced()
-    return _grid(t)
-
-
 def _fill_quadratic(g: BlockGrid):
     """f(x) = a + bx + cy + dz + exy + ... full quadratic in cell centers."""
     xc = g.cell_centers(np.float64)
@@ -211,7 +205,7 @@ def test_two_level_ghosts_exact_for_quadratics():
     """Quadratic Lagrange interpolation must reproduce quadratics exactly;
     fine->coarse averaging is exact for linears, 2nd-order for quadratics
     (cell average vs center value differs by h^2/24 * lap f)."""
-    g = _two_level_grid()
+    g = two_level_grid(2.0)
     f, fexact = _fill_quadratic(g)
     tab = g.lab_tables(1)
     labs = np.asarray(assemble_scalar_lab(jnp.asarray(f), tab, BS))
@@ -245,7 +239,7 @@ def test_two_level_ghosts_exact_for_quadratics():
 def test_two_level_ghosts_exact_for_linears():
     """Linear fields: every path (copy, 2:1 average, quadratic interp) is
     exact to roundoff."""
-    g = _two_level_grid()
+    g = two_level_grid(2.0)
     xc = g.cell_centers(np.float64)
     f = (0.5 + 2.0 * xc[..., 0] - 1.0 * xc[..., 1] + 0.25 * xc[..., 2]).astype(
         np.float32
@@ -280,7 +274,7 @@ def test_two_level_ghosts_exact_for_linears():
 def test_lab_assembly_is_jittable_and_stable():
     import jax
 
-    g = _two_level_grid()
+    g = two_level_grid(2.0)
     f, _ = _fill_quadratic(g)
     tab = g.lab_tables(1)
     fn = jax.jit(lambda x: assemble_scalar_lab(x, tab, BS))

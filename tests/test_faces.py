@@ -12,8 +12,7 @@ from cup3d_tpu.grid.flux import build_flux_tables
 from cup3d_tpu.grid.octree import Octree, TreeConfig
 from cup3d_tpu.grid.uniform import BC
 from cup3d_tpu.ops import amr_ops
-
-BS = 8
+from tests._grids import BS, THREE_LEVEL
 
 
 def _grid(levels=2, bc=(BC.periodic,) * 3, refine=((0, 0, 0, 0),),
@@ -76,16 +75,9 @@ def test_two_level_periodic(w):
     _check_vector(_grid(), w)
 
 
-_THREE_LEVEL = (
-    (0, 0, 0, 0), (0, 1, 0, 0), (0, 0, 1, 0), (0, 0, 0, 1),
-    (0, 1, 1, 0), (0, 1, 0, 1), (0, 0, 1, 1), (0, 1, 1, 1),
-    (1, 1, 1, 1),
-)
-
-
 @pytest.mark.parametrize("w", [1, 3])
 def test_three_level_periodic(w):
-    g = _grid(levels=3, refine=_THREE_LEVEL)
+    g = _grid(levels=3, refine=THREE_LEVEL)
     _check_scalar(g, w)
     _check_vector(g, w)
 
@@ -136,7 +128,7 @@ def test_two_fish_style_tree():
 
 
 def test_laplacian_parity():
-    g = _grid(levels=3, refine=_THREE_LEVEL)
+    g = _grid(levels=3, refine=THREE_LEVEL)
     rng = np.random.default_rng(2)
     f = jnp.asarray(rng.standard_normal((g.nb, BS, BS, BS)).astype(np.float32))
     ft = build_flux_tables(g)

@@ -38,19 +38,10 @@ from cup3d_tpu.fleet.server import (
 from cup3d_tpu.obs import metrics as M
 from cup3d_tpu.resilience import faults
 from cup3d_tpu.sim.simulation import Simulation
+from tests._cases import mean_ke, tgv_spec
 
 
-@pytest.fixture(autouse=True)
-def _clean_faults():
-    faults.clear()
-    yield
-    faults.clear()
-
-
-def _tgv_spec(**kw):
-    spec = dict(kind="tgv", n=16, nsteps=8, cfl=0.3)
-    spec.update(kw)
-    return spec
+pytestmark = pytest.mark.usefixtures("clean_faults")
 
 
 def _fish_spec(**kw):
@@ -93,11 +84,6 @@ def _solo_fish(tmp, spec):
     return sim
 
 
-def _ke(vel):
-    v = np.asarray(vel, np.float64)
-    return float(np.mean(np.sum(v * v, axis=-1)))
-
-
 def _drain(tmp, specs, **srv_kw):
     """Fresh server, one tenant per spec; returns (server, job_ids)."""
     srv = FleetServer(workdir=str(tmp), **srv_kw)
@@ -112,14 +98,14 @@ def _drain(tmp, specs, **srv_kw):
 def test_tgv_lanes_match_solo_scan(tmp_path):
     """Two TGV lanes with different CFL each reproduce their solo
     scan-path run; the only divergence allowed is vmap lowering."""
-    specs = [_tgv_spec(cfl=0.3), _tgv_spec(cfl=0.25)]
+    specs = [tgv_spec(cfl=0.3), tgv_spec(cfl=0.25)]
     srv, ids = _drain(tmp_path / "fleet", specs)
     for i, (job_id, spec) in enumerate(zip(ids, specs)):
         assert srv.poll(job_id)["status"] == DONE
         solo = _solo_tgv(tmp_path / f"solo{i}", spec)
         lane = srv.lane_state(job_id)
         vel_f, vel_s = lane["vel"], np.asarray(solo.sim.state["vel"])
-        ke_f, ke_s = _ke(vel_f), _ke(vel_s)
+        ke_f, ke_s = mean_ke(vel_f), mean_ke(vel_s)
         assert abs(ke_f - ke_s) <= 1e-4 * max(abs(ke_s), 1e-12)
         np.testing.assert_allclose(vel_f, vel_s, rtol=0, atol=1e-4)
         assert np.isclose(float(lane["time"]), solo.sim.time, rtol=1e-4)
@@ -141,7 +127,7 @@ def test_fish_lanes_match_solo_scan(tmp_path):
         assert srv.poll(job_id)["status"] == DONE
         solo = _solo_fish(tmp_path / f"solo{i}", spec)
         lane = srv.lane_state(job_id)
-        ke_f, ke_s = _ke(lane["vel"]), _ke(solo.sim.state["vel"])
+        ke_f, ke_s = mean_ke(lane["vel"]), mean_ke(solo.sim.state["vel"])
         assert abs(ke_f - ke_s) <= 1e-4 * max(abs(ke_s), 1e-12)
         pos_f = np.asarray(lane["rigid"][6:9], np.float64)
         pos_s = np.asarray(solo.sim.obstacles[0].position, np.float64)
@@ -158,8 +144,8 @@ def test_lane_nan_isolated_bitwise_and_recovers(tmp_path):
     """The Round-14 acceptance criterion: a NaN injected into lane 1
     leaves lanes 0 and 2 BITWISE identical to the unfaulted batch,
     while lane 1 rolls back to its snapshot, halves dt, and completes."""
-    specs = [_tgv_spec(cfl=0.3, nsteps=12), _tgv_spec(cfl=0.28, nsteps=12),
-             _tgv_spec(cfl=0.25, nsteps=12)]
+    specs = [tgv_spec(cfl=0.3, nsteps=12), tgv_spec(cfl=0.28, nsteps=12),
+             tgv_spec(cfl=0.25, nsteps=12)]
     ref, ref_ids = _drain(tmp_path / "ref", specs, snap_every=4)
     ref_lanes = [ref.lane_state(j) for j in ref_ids]
 
@@ -188,7 +174,7 @@ def test_step_nan_fault_recovers_without_collateral(tmp_path):
     """The solo seam (step.nan_velocity) fires inside the fleet
     consumer too: the lane that consumes the armed step first rolls
     back; every job still completes."""
-    specs = [_tgv_spec(cfl=0.3, nsteps=8), _tgv_spec(cfl=0.25, nsteps=8)]
+    specs = [tgv_spec(cfl=0.3, nsteps=8), tgv_spec(cfl=0.25, nsteps=8)]
     faults.arm("step.nan_velocity", 2, 1)
     s0 = M.snapshot()
     srv, ids = _drain(tmp_path, specs, snap_every=4)
@@ -202,7 +188,7 @@ def test_step_nan_fault_recovers_without_collateral(tmp_path):
 def test_exhausted_lane_fails_alone(tmp_path):
     """A lane that faults past its retry budget is retired FAILED; the
     other tenants finish untouched."""
-    specs = [_tgv_spec(cfl=0.3), _tgv_spec(cfl=0.25)]
+    specs = [tgv_spec(cfl=0.3), tgv_spec(cfl=0.25)]
     # the seam fires at lane >= armed, so poison the LAST lane to keep
     # the injection single-lane; every consumed row of lane 1 faults
     faults.arm("fleet.lane_nan", 1, 99)
@@ -230,8 +216,8 @@ def test_bucketed_assembly_bounds_compiles(tmp_path):
     from cup3d_tpu.analysis import runtime as R
 
     srv = FleetServer(workdir=str(tmp_path))
-    for spec in (_tgv_spec(n=16, cfl=0.3), _tgv_spec(n=16, cfl=0.25),
-                 _tgv_spec(n=24, cfl=0.3), _tgv_spec(n=24, cfl=0.25)):
+    for spec in (tgv_spec(n=16, cfl=0.3), tgv_spec(n=16, cfl=0.25),
+                 tgv_spec(n=24, cfl=0.3), tgv_spec(n=24, cfl=0.25)):
         srv.submit("t", spec)
     s0 = M.snapshot()
     with R.RecompileCounter() as rc:
@@ -243,8 +229,8 @@ def test_bucketed_assembly_bounds_compiles(tmp_path):
     assert srv.jobs_by_status() == {DONE: 4}
 
     # same signature again: the cache serves the jit, nothing recompiles
-    srv.submit("t", _tgv_spec(n=16, cfl=0.28))
-    srv.submit("t", _tgv_spec(n=16, cfl=0.27))
+    srv.submit("t", tgv_spec(n=16, cfl=0.28))
+    srv.submit("t", tgv_spec(n=16, cfl=0.27))
     s0 = M.snapshot()
     with R.RecompileCounter() as rc2:
         srv.drain()
@@ -265,7 +251,7 @@ def test_lifecycle_submit_poll_cancel_and_padding(tmp_path):
         srv.submit("t", dict(kind="warp-drive", nsteps=4))
     with pytest.raises(ValueError):
         srv.submit("t", dict(kind="tgv"))  # no step budget
-    ids = [srv.submit(f"t{i}", _tgv_spec(cfl=0.3 - 0.01 * i))
+    ids = [srv.submit(f"t{i}", tgv_spec(cfl=0.3 - 0.01 * i))
            for i in range(7)]
     assert srv.poll(ids[0])["status"] == QUEUED
     assert srv.cancel(ids[3]) is True
@@ -292,7 +278,7 @@ def test_lifecycle_submit_poll_cancel_and_padding(tmp_path):
 def test_qoi_fanout_is_byte_stable(tmp_path):
     """Two identical drains produce bitwise-identical per-tenant QoI
     buffers: the fan-out ordering is deterministic, keyed by step."""
-    specs = [_tgv_spec(cfl=0.3), _tgv_spec(cfl=0.25)]
+    specs = [tgv_spec(cfl=0.3), tgv_spec(cfl=0.25)]
     a_srv, a_ids = _drain(tmp_path / "a", specs)
     b_srv, b_ids = _drain(tmp_path / "b", specs)
     for a_id, b_id in zip(a_ids, b_ids):
@@ -318,8 +304,8 @@ def test_fleet_cli_and_health_payload(tmp_path, capsys):
 
     spec_path = tmp_path / "spec.json"
     spec_path.write_text(json.dumps({
-        "scenarios": [dict(_tgv_spec(cfl=0.3), tenant="acme"),
-                      dict(_tgv_spec(cfl=0.25))],
+        "scenarios": [dict(tgv_spec(cfl=0.3), tenant="acme"),
+                      dict(tgv_spec(cfl=0.25))],
         "lanes": 8,
     }))
     with pytest.raises(SystemExit) as exc:
@@ -345,8 +331,8 @@ def test_legacy_drain_matches_continuous_no_arrivals(tmp_path):
     observationally identical to the legacy generation-drain: same
     statuses, byte-identical per-tenant QoI, zero reseeds — the
     CUP3D_FLEET_CONTINUOUS=0 baseline stays bitwise-unchanged."""
-    specs = [_tgv_spec(cfl=0.3), _tgv_spec(cfl=0.25),
-             _tgv_spec(cfl=0.28, nsteps=16)]
+    specs = [tgv_spec(cfl=0.3), tgv_spec(cfl=0.25),
+             tgv_spec(cfl=0.28, nsteps=16)]
     legacy, lid = _drain(tmp_path / "legacy", specs, continuous=False)
     cont, cid = _drain(tmp_path / "cont", specs, continuous=True)
     assert cont.reseeds == 0
@@ -362,8 +348,8 @@ def test_reseed_bitwise_non_interference(tmp_path):
     completes on the reused lane."""
     # one bucket (nsteps 8 and 9 share the ×1.25 step rung): lane 0
     # retires after a single dispatch while lanes 1-2 still run
-    specs = [_tgv_spec(nsteps=8, cfl=0.3), _tgv_spec(nsteps=9, cfl=0.25),
-             _tgv_spec(nsteps=9, cfl=0.28)]
+    specs = [tgv_spec(nsteps=8, cfl=0.3), tgv_spec(nsteps=9, cfl=0.25),
+             tgv_spec(nsteps=9, cfl=0.28)]
     ref, rid = _drain(tmp_path / "ref", specs)
 
     srv = FleetServer(workdir=str(tmp_path / "srv"))
@@ -373,7 +359,7 @@ def test_reseed_bitwise_non_interference(tmp_path):
     def feed(server, tick):
         if "id" not in late and server.poll(ids[0])["status"] == DONE:
             late["id"] = server.submit(
-                "late", _tgv_spec(nsteps=8, cfl=0.2))
+                "late", tgv_spec(nsteps=8, cfl=0.2))
         return "id" not in late
 
     srv.serve(feed)
@@ -395,9 +381,9 @@ def test_submit_during_serve_admission(tmp_path):
     lanes of the live batch (cross-rung, so no new batch and no new
     executable) and the occupancy window closes into the gauge."""
     srv = FleetServer(workdir=str(tmp_path))
-    srv.submit("t0", _tgv_spec(nsteps=8))
-    srv.submit("t0", _tgv_spec(nsteps=32))
-    stream = [_tgv_spec(nsteps=8), _tgv_spec(nsteps=8)]
+    srv.submit("t0", tgv_spec(nsteps=8))
+    srv.submit("t0", tgv_spec(nsteps=32))
+    stream = [tgv_spec(nsteps=8), tgv_spec(nsteps=8)]
 
     def feed(server, tick):
         if stream and server.queue_depth() == 0:
@@ -428,9 +414,9 @@ def test_reseed_zero_recompile(tmp_path):
     from cup3d_tpu.analysis import runtime as R
 
     srv = FleetServer(workdir=str(tmp_path))
-    srv.submit("t", _tgv_spec(nsteps=8))
-    srv.submit("t", _tgv_spec(nsteps=32))
-    stream = [_tgv_spec(nsteps=8, cfl=0.3 - 0.01 * i) for i in range(3)]
+    srv.submit("t", tgv_spec(nsteps=8))
+    srv.submit("t", tgv_spec(nsteps=32))
+    stream = [tgv_spec(nsteps=8, cfl=0.3 - 0.01 * i) for i in range(3)]
 
     def feed(server, tick):
         if stream and server.queue_depth() == 0:
@@ -454,15 +440,15 @@ def test_lane_nan_fault_then_reseed_same_lane(tmp_path):
     srv = FleetServer(workdir=str(tmp_path), max_retries=0)
     # one bucket (8 and 9 share the step rung): the batch stays live
     # on lane 1 while lane 0 fails and is reseeded
-    doomed = srv.submit("t", _tgv_spec(nsteps=8, cfl=0.3))
-    other = srv.submit("t", _tgv_spec(nsteps=9, cfl=0.25))
+    doomed = srv.submit("t", tgv_spec(nsteps=8, cfl=0.3))
+    other = srv.submit("t", tgv_spec(nsteps=9, cfl=0.25))
     faults.arm("fleet.lane_nan", 0, 1)
     late = {}
 
     def feed(server, tick):
         if "id" not in late and server.poll(doomed)["status"] == FAILED:
             late["id"] = server.submit(
-                "late", _tgv_spec(nsteps=8, cfl=0.2))
+                "late", tgv_spec(nsteps=8, cfl=0.2))
         return "id" not in late
 
     s0 = M.snapshot()
@@ -488,22 +474,22 @@ def test_admission_quota_and_backpressure(tmp_path):
     from cup3d_tpu.fleet.server import FleetAdmissionError
 
     srv = FleetServer(workdir=str(tmp_path), tenant_quota=2)
-    srv.submit("a", _tgv_spec())
-    srv.submit("a", _tgv_spec())
+    srv.submit("a", tgv_spec())
+    srv.submit("a", tgv_spec())
     s0 = M.snapshot()
     with pytest.raises(FleetAdmissionError) as exc:
-        srv.submit("a", _tgv_spec())
+        srv.submit("a", tgv_spec())
     assert exc.value.reason == "quota"
-    srv.submit("b", _tgv_spec())  # other tenants unaffected
+    srv.submit("b", tgv_spec())  # other tenants unaffected
     assert M.delta(s0)["fleet.admission_rejects{reason=quota}"] == 1
 
     srv2 = FleetServer(workdir=str(tmp_path), max_queue_depth=2)
-    srv2.submit("a", _tgv_spec())
-    srv2.submit("b", _tgv_spec())
+    srv2.submit("a", tgv_spec())
+    srv2.submit("b", tgv_spec())
     assert srv2.health()["admission"]["backpressure"] is True
     s0 = M.snapshot()
     with pytest.raises(FleetAdmissionError) as exc:
-        srv2.submit("c", _tgv_spec())
+        srv2.submit("c", tgv_spec())
     assert exc.value.reason == "queue-full"
     assert M.delta(s0)["fleet.admission_rejects{reason=queue-full}"] == 1
 
@@ -513,7 +499,7 @@ def test_cancel_running_verifies_lane_state(tmp_path):
     changed lane state: a lane that no longer holds the job returns
     False instead of the old unconditional True."""
     srv = FleetServer(workdir=str(tmp_path), continuous=False)
-    jid = srv.submit("t", _tgv_spec(nsteps=64))
+    jid = srv.submit("t", tgv_spec(nsteps=64))
     srv.assemble()
     assert srv.poll(jid)["status"] == "running"
     assert srv.cancel(jid) is True
@@ -522,7 +508,7 @@ def test_cancel_running_verifies_lane_state(tmp_path):
 
     # a stale handle: the batch lane no longer holds the job (as after
     # a swap), so the guarded retire is a no-op and cancel must say so
-    jid2 = srv.submit("t", _tgv_spec(nsteps=64))
+    jid2 = srv.submit("t", tgv_spec(nsteps=64))
     srv.assemble()
     job2 = srv._jobs[jid2]
     job2.batch.jobs[job2.lane] = None

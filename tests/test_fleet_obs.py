@@ -27,19 +27,10 @@ from cup3d_tpu.obs import export as E
 from cup3d_tpu.obs import metrics as M
 from cup3d_tpu.obs import trace as OT
 from cup3d_tpu.resilience import faults
+from tests._cases import tgv_spec
 
 
-@pytest.fixture(autouse=True)
-def _clean_faults():
-    faults.clear()
-    yield
-    faults.clear()
-
-
-def _tgv_spec(**kw):
-    spec = dict(kind="tgv", n=16, nsteps=8, cfl=0.3)
-    spec.update(kw)
-    return spec
+pytestmark = pytest.mark.usefixtures("clean_faults")
 
 
 def _job_records(trace_dir):
@@ -59,9 +50,9 @@ def drained():
     OT.TRACE.configure(enabled=True, directory=td)
     try:
         srv = FleetServer(workdir=os.path.join(td, "wd"))
-        done_ids = [srv.submit("acme", _tgv_spec(cfl=0.3)),
-                    srv.submit("zeta", _tgv_spec(cfl=0.25))]
-        cancel_id = srv.submit("acme", _tgv_spec(cfl=0.28))
+        done_ids = [srv.submit("acme", tgv_spec(cfl=0.3)),
+                    srv.submit("zeta", tgv_spec(cfl=0.25))]
+        cancel_id = srv.submit("acme", tgv_spec(cfl=0.28))
         assert srv.cancel(cancel_id) is True
         srv.drain()
         OT.TRACE.close()  # flush trace.jsonl + write trace.pfto.json
@@ -119,7 +110,7 @@ def test_lane_occupancy_tracks_in_perfetto_export(drained):
     proc = subprocess.run(
         [sys.executable, os.path.join(repo, "tools", "trace_check.py"),
          os.path.join(td, "trace.jsonl")],
-        capture_output=True, text=True)
+        capture_output=True, text=True, timeout=60)
     assert proc.returncode == 0, proc.stdout + proc.stderr
     assert "job-lifecycle records" in proc.stdout
 
@@ -132,8 +123,8 @@ def test_faulted_lane_rolls_back_alone(tmp_path):
     try:
         faults.arm("fleet.lane_nan", 1, 1)
         srv = FleetServer(workdir=os.path.join(td, "wd"), snap_every=4)
-        ids = [srv.submit("t0", _tgv_spec(cfl=0.3, nsteps=12)),
-               srv.submit("t1", _tgv_spec(cfl=0.28, nsteps=12))]
+        ids = [srv.submit("t0", tgv_spec(cfl=0.3, nsteps=12)),
+               srv.submit("t1", tgv_spec(cfl=0.28, nsteps=12))]
         srv.drain()
         OT.TRACE.close()
     finally:
@@ -210,7 +201,7 @@ def test_burn_rate_fires_when_latency_exceeds_slo(tmp_path):
     s0 = M.snapshot()
     srv = FleetServer(workdir=str(tmp_path), slo_p99_s=1e-6,
                       slo_window=10)
-    srv.submit("burny", _tgv_spec(cfl=0.3))
+    srv.submit("burny", tgv_spec(cfl=0.3))
     srv.drain()
     d = M.delta(s0)
     assert d.get("fleet.slo_breaches{tenant=burny}", 0) == 1
@@ -290,8 +281,8 @@ def test_failed_job_partitions_with_rollback_mass(tmp_path):
         faults.arm("fleet.lane_nan", 1, 99)
         srv = FleetServer(workdir=os.path.join(td, "wd"),
                           max_retries=2, snap_every=4)
-        ids = [srv.submit("t0", _tgv_spec(cfl=0.3, nsteps=12)),
-               srv.submit("t1", _tgv_spec(cfl=0.28, nsteps=12))]
+        ids = [srv.submit("t0", tgv_spec(cfl=0.3, nsteps=12)),
+               srv.submit("t1", tgv_spec(cfl=0.28, nsteps=12))]
         srv.drain()
         OT.TRACE.close()
     finally:
@@ -313,9 +304,12 @@ def test_burn_attribution_names_dominant_phase(tmp_path):
     # warm the signature under a throwaway tenant so the measured
     # job's assembly phase is a cache hit — otherwise the XLA compile
     # lands in assembly and can out-weigh dispatch on a loaded machine
-    srv.submit("warmup", _tgv_spec(cfl=0.3))
+    srv.submit("warmup", tgv_spec(cfl=0.3))
     srv.drain()
-    srv.submit("burny", _tgv_spec(cfl=0.3))
+    # 64 steps, not 8: with a few ms of dispatch a hiccup between submit
+    # and the scheduling pass (capacity_wait) won a third of 48 runs
+    # under load, on this tree and its parent alike; at 64, none
+    srv.submit("burny", tgv_spec(cfl=0.3, nsteps=64))
     srv.drain()
     attr = srv.slo_status()["tenants"]["burny"]["attribution"]
     assert attr["dominant_phase"] in OT.JOB_PHASES
@@ -346,7 +340,7 @@ def test_provenance_knob_disables_phase_records(tmp_path):
     on demand via job.phases()."""
     s0 = M.snapshot()
     srv = FleetServer(workdir=str(tmp_path), provenance=False)
-    jid = srv.submit("quiet", _tgv_spec())
+    jid = srv.submit("quiet", tgv_spec())
     srv.drain()
     d = M.delta(s0)
     assert not any(v for k, v in d.items()

@@ -41,8 +41,7 @@ from cup3d_tpu.grid.uniform import BC
 from cup3d_tpu.ops import amr_ops, krylov
 from cup3d_tpu.ops import fused_amr_bicgstab as fa
 from cup3d_tpu.sim.amr import AMRSimulation
-
-BS = 8
+from tests._grids import BS, randn
 
 
 class _Geom:
@@ -93,10 +92,6 @@ def _stage_pair(npad):
     return mk(False), mk(True)
 
 
-def _rand(rng, *shape):
-    return jnp.asarray(rng.standard_normal(shape), jnp.float32)
-
-
 def _close(a, b, tol=2e-6):
     a, b = jnp.asarray(a), jnp.asarray(b)
     sc = max(float(jnp.max(jnp.abs(a))), 1.0)
@@ -115,7 +110,7 @@ def test_stage_parity_on_padded_forest():
     tw, kn = _stage_pair(npad)
     rng = np.random.default_rng(3)
     mask4 = np.asarray(mask).reshape(npad, 1, 1, 1)
-    r, p, v, rhat = (_rand(rng, npad, BS, BS, BS) * mask4
+    r, p, v, rhat = (randn(rng, npad, BS, BS, BS) * mask4
                      for _ in range(4))
     h_col = jnp.reshape(geom.h, (npad, 1, 1, 1))
     h2, inv_h2 = h_col * h_col, 1.0 / (h_col * h_col)
@@ -126,21 +121,21 @@ def test_stage_parity_on_padded_forest():
     for a, b in zip(tw.update(r, p, v, rhat, vol, sc),
                     kn.update(r, p, v, rhat, vol, sc)):
         _close(a, b)
-    zc = _rand(rng, npad, 1, 1, 1)
-    azf = _rand(rng, npad, BS, BS, BS) * mask4
+    zc = randn(rng, npad, 1, 1, 1)
+    azf = randn(rng, npad, BS, BS, BS) * mask4
     _close(tw.getz(p, azf, zc, h2, S3, lam),
            kn.getz(p, azf, zc, h2, S3, lam), tol=1e-5)
     _close(tw.getz(p, None, None, h2, S3, lam),
            kn.getz(p, None, None, h2, S3, lam), tol=1e-5)
     lab = jnp.asarray(tab.assemble_scalar(p, BS))
-    corr = _rand(rng, npad, BS, BS, BS) * mask4
+    corr = randn(rng, npad, BS, BS, BS) * mask4
     for a, b in zip(tw.lap(lab, corr, rhat, inv_h2),
                     kn.lap(lab, corr, rhat, inv_h2)):
         _close(a, b)
     for a, b in zip(tw.axpy(r, v, vol, _scalars(0.3)),
                     kn.axpy(r, v, vol, _scalars(0.3))):
         _close(a, b)
-    x = _rand(rng, npad, BS, BS, BS) * mask4
+    x = randn(rng, npad, BS, BS, BS) * mask4
     for a, b in zip(tw.finish(x, p, v, r, rhat, rhat, _scalars(0.3, 0.8)),
                     kn.finish(x, p, v, r, rhat, rhat, _scalars(0.3, 0.8))):
         _close(a, b)

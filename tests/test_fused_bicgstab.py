@@ -18,12 +18,7 @@ import pytest
 from cup3d_tpu.grid.uniform import BC, UniformGrid
 from cup3d_tpu.ops import fused_bicgstab as fb
 from cup3d_tpu.ops import krylov, precision, tilesolve
-
-BS = 8
-
-
-def _grid(bc, n=32):
-    return UniformGrid((n, n, n), (1.0, 1.0, 1.0), (bc,) * 3)
+from tests._grids import BS, randn, unit_cube
 
 
 def _stages(T, store=jnp.float32, kernels=False, h=0.25):
@@ -31,10 +26,6 @@ def _stages(T, store=jnp.float32, kernels=False, h=0.25):
     C = min(fb.TILE_T, T)
     return fb._Stages(bs=BS, Tpad=T, C=C, store=store, h2=h2,
                       inv_h2=1.0 / h2, kernels=kernels, interpret=kernels)
-
-
-def _rand(rng, *shape):
-    return jnp.asarray(rng.standard_normal(shape), jnp.float32)
 
 
 # -- per-stage interpret-mode kernel parity vs the jnp twins -----------------
@@ -50,7 +41,7 @@ def _stage_pair(T=512, store=jnp.float32):
 def test_update_stage_interpret_parity():
     tw, kn = _stage_pair()
     rng = np.random.default_rng(0)
-    r, p, v, rhat = (_rand(rng, BS, BS, BS, 512) for _ in range(4))
+    r, p, v, rhat = (randn(rng, BS, BS, BS, 512) for _ in range(4))
     scal = fb._scalars(0.7, 1.3, 0.0)
     for a, b in zip(tw.update(r, p, v, rhat, scal),
                     kn.update(r, p, v, rhat, scal)):
@@ -70,8 +61,8 @@ def test_update_stage_interpret_parity():
 def test_getz_stage_interpret_parity(two_level):
     tw, kn = _stage_pair()
     rng = np.random.default_rng(1)
-    w = _rand(rng, BS, BS, BS, 512)
-    aux = _rand(rng, 8, 512) if two_level else None
+    w = randn(rng, BS, BS, BS, 512)
+    aux = randn(rng, 8, 512) if two_level else None
     S3, lam3, _ = tilesolve._basis(BS, "float32")
     lam = lam3.reshape(BS ** 3, 1)
     a = tw.getz(w, aux, S3, lam)
@@ -85,7 +76,7 @@ def test_getz_stage_matches_tilesolve():
     """Tile-only getz IS the exact DST tile solve of -h2*w."""
     tw = _stages(128)
     rng = np.random.default_rng(2)
-    w = _rand(rng, BS, BS, BS, 128)
+    w = randn(rng, BS, BS, BS, 128)
     S3, lam3, _ = tilesolve._basis(BS, "float32")
     y = tw.getz(w, None, S3, lam3.reshape(BS ** 3, 1))
     want = tilesolve.tile_solve_lanes(-tw.h2 * w)
@@ -97,9 +88,9 @@ def test_lap_axpy_finish_stage_interpret_parity():
     tw, kn = _stage_pair()
     rng = np.random.default_rng(3)
     w, a, r, v, y, z, s, t, rhat = (
-        _rand(rng, BS, BS, BS, 512) for _ in range(9))
-    x = _rand(rng, BS, BS, BS, 512)
-    planes = _rand(rng, 6, BS, BS, 512)
+        randn(rng, BS, BS, BS, 512) for _ in range(9))
+    x = randn(rng, BS, BS, BS, 512)
+    planes = randn(rng, 6, BS, BS, 512)
     for got, want in zip(kn.lap(w, planes, a), tw.lap(w, planes, a)):
         sc = max(float(jnp.max(jnp.abs(want))), 1.0)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -142,7 +133,7 @@ def test_lane_planes_laplacian_matches_legacy(bc):
 def test_face_deltas_reconstruct_tileconst_laplacian(bc):
     """aux rows (make_face_deltas + zc) -> _azc_from_aux must equal the
     full Laplacian of the broadcast tile-constant coarse field."""
-    g = _grid(bc)
+    g = unit_cube(bc)
     A = krylov.make_laplacian_lanes(g)
     deltas_fn = krylov.make_face_deltas(g)
     T = 64
@@ -180,7 +171,7 @@ def test_fused_matches_legacy_bicgstab_f32(two_level):
     """Fused f32 vs the legacy composition on the identical system:
     same residual quality, equivalent solution (the documented fused-vs-
     unfused equivalence bound, VALIDATION.md round 12)."""
-    g = _grid(BC.periodic)
+    g = unit_cube(BC.periodic)
     A = krylov.make_laplacian_lanes(g)
     h2 = g.h * g.h
     if two_level:
@@ -215,7 +206,7 @@ def test_fused_bf16_storage_meets_residual_quality():
     """bf16 Krylov storage with f32 accumulation still reaches the f32
     stopping target on the production tolerances, and the solution stays
     within the mixed-precision ladder's bound of the f32 solve."""
-    g = _grid(BC.periodic)
+    g = unit_cube(BC.periodic)
     rng = np.random.default_rng(8)
     rhs = jnp.asarray(rng.standard_normal(g.shape), jnp.float32)
     bt = krylov.to_lanes(rhs - jnp.mean(rhs))
@@ -234,7 +225,7 @@ def test_fused_bf16_storage_meets_residual_quality():
 def test_fused_warm_start_and_maxiter_escalation():
     """x0 warm starts work and the maxiter knob (the recovery ladder's
     escalation parameter) caps the iteration count exactly."""
-    g = _grid(BC.periodic, n=16)
+    g = unit_cube(BC.periodic, n=16)
     rng = np.random.default_rng(9)
     rhs = jnp.asarray(rng.standard_normal(g.shape), jnp.float32)
     bt = krylov.to_lanes(rhs - jnp.mean(rhs))
@@ -271,7 +262,7 @@ def test_solver_dispatch_fused_and_stats(monkeypatch):
     """CUP3D_FUSED=1 routes build_iterative_solver through the fused
     driver with the with_stats/maxiter contract intact, and the result
     matches the legacy solver."""
-    g = _grid(BC.periodic)
+    g = unit_cube(BC.periodic)
     p_true, rhs = _manufactured(g)
     legacy = krylov.build_iterative_solver(g, tol_abs=1e-6, tol_rel=1e-5)
     p_leg = legacy(rhs)
@@ -291,7 +282,7 @@ def test_solver_dispatch_fused_and_stats(monkeypatch):
 
 
 def test_solver_dispatch_bf16_solves_and_policy_raises(monkeypatch):
-    g = _grid(BC.periodic)
+    g = unit_cube(BC.periodic)
     p_true, rhs = _manufactured(g)
     # bf16 + default CUP3D_FUSED (auto) -> fused driver, converged solve
     monkeypatch.setenv("CUP3D_KRYLOV_DTYPE", "bf16")
@@ -316,7 +307,7 @@ def test_default_f32_config_uses_legacy_path(monkeypatch):
     monkeypatch.delenv("CUP3D_FUSED", raising=False)
     assert precision.krylov_dtype() == jnp.float32
     assert not precision.use_fused()
-    g = _grid(BC.periodic, n=16)
+    g = unit_cube(BC.periodic, n=16)
     import inspect
 
     solve = krylov.build_iterative_solver(g)
@@ -332,7 +323,7 @@ def test_fused_solver_steady_state_retrace_budget(monkeypatch):
     from cup3d_tpu.analysis.runtime import RecompileCounter
 
     monkeypatch.setenv("CUP3D_FUSED", "1")
-    g = _grid(BC.periodic, n=16)
+    g = unit_cube(BC.periodic, n=16)
     rng = np.random.default_rng(10)
     with RecompileCounter() as rc:
         solve = jax.jit(krylov.build_iterative_solver(

@@ -9,14 +9,11 @@ import pytest
 from cup3d_tpu.grid.uniform import BC, UniformGrid
 from cup3d_tpu.ops import krylov
 from cup3d_tpu.ops.poisson import build_spectral_solver
-
-
-def _grid(bc, n=32):
-    return UniformGrid((n, n, n), (1.0, 1.0, 1.0), (bc,) * 3)
+from tests._grids import unit_cube
 
 
 def test_block_precond_reduces_residual():
-    g = _grid(BC.periodic)
+    g = unit_cube(BC.periodic)
     A = krylov.make_laplacian(g)
     M = krylov.make_block_cg_preconditioner(bs=8, iters=12, h=g.h)
     key = jax.random.PRNGKey(0)
@@ -34,7 +31,7 @@ def test_block_precond_reduces_residual():
 
 @pytest.mark.parametrize("bc", [BC.periodic, BC.wall])
 def test_bicgstab_solves_discrete_poisson(bc):
-    g = _grid(bc)
+    g = unit_cube(bc)
     A = krylov.make_laplacian(g)
     x = np.asarray(g.cell_centers())
     # manufactured pressure compatible with both wrap and zero-gradient BCs
@@ -53,7 +50,7 @@ def test_bicgstab_solves_discrete_poisson(bc):
 
 
 def test_bicgstab_matches_spectral_on_periodic():
-    g = _grid(BC.periodic, n=16)
+    g = unit_cube(BC.periodic, n=16)
     A = krylov.make_laplacian(g)
     key = jax.random.PRNGKey(1)
     rhs = jax.random.normal(key, g.shape, jnp.float32)
@@ -66,7 +63,7 @@ def test_bicgstab_matches_spectral_on_periodic():
 
 
 def test_bicgstab_reports_iterations_and_converges_fast():
-    g = _grid(BC.periodic)
+    g = unit_cube(BC.periodic)
     A = krylov.make_laplacian(g)
     M = krylov.make_block_cg_preconditioner(8, 12, h=g.h)
     key = jax.random.PRNGKey(2)
@@ -138,7 +135,7 @@ def test_lanes_laplacian_mixed_bcs():
 
 
 def test_lanes_solver_matches_dense_path():
-    g = _grid(BC.periodic, n=32)
+    g = unit_cube(BC.periodic, n=32)
     rng = np.random.default_rng(3)
     rhs = jnp.asarray(rng.standard_normal(g.shape).astype(np.float32))
     rhs = rhs - jnp.mean(rhs)
@@ -156,7 +153,7 @@ def test_tileconst_laplacian_matches_full_operator(bc):
     """The analytic tile-face form of A@(P zc) used by the two-level
     preconditioner must equal the full lane Laplacian on the broadcast
     coarse field, for both BC families."""
-    g = _grid(bc, n=32)
+    g = unit_cube(bc, n=32)
     A = krylov.make_laplacian_lanes(g)
     M = krylov.make_twolevel_preconditioner_lanes(g, g.h * g.h)
     key = jax.random.PRNGKey(1)
@@ -185,7 +182,7 @@ def test_twolevel_cuts_iterations(bc):
     """Two-level preconditioner: resolution-independent iteration count,
     well below tile-only (measured 12 vs 51 at 128^3; here 48^3 keeps the
     test fast)."""
-    g = _grid(bc, n=48)
+    g = unit_cube(bc, n=48)
     A = krylov.make_laplacian_lanes(g)
     h2 = g.h * g.h
     rng = np.random.default_rng(3)
@@ -266,7 +263,7 @@ def test_mean_constraint_pinned_paths(mc, monkeypatch):
     magnitude: unscaled, float32 BiCGSTAB stalls (1000 iterations, NaN
     breakdowns) on what should be a ~30-iteration solve."""
     monkeypatch.setenv("CUP3D_COARSE", "1")  # exercise the mc-1/3 fallback
-    g = _grid(BC.periodic)
+    g = unit_cube(BC.periodic)
     A = krylov.make_laplacian(g)
     x = np.asarray(g.cell_centers())
     p_true = (

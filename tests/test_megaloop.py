@@ -21,52 +21,13 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from cup3d_tpu.config import SimulationConfig
 from cup3d_tpu.obs import metrics as M
 from cup3d_tpu.resilience import faults
 from cup3d_tpu.sim.simulation import Simulation
+from tests._cases import fish_cfg, mean_ke, simulate, tgv_cfg
 
 
-@pytest.fixture(autouse=True)
-def _clean_faults():
-    faults.clear()
-    yield
-    faults.clear()
-
-
-def _tgv_cfg(tmp, **kw):
-    base = dict(
-        bpdx=2, bpdy=2, bpdz=2, levelMax=1, levelStart=0,
-        extent=2 * np.pi, CFL=0.3, nu=0.02, nsteps=16, tend=0.0,
-        rampup=0, initCond="taylorGreen", pipelined=True, verbose=False,
-        freqDiagnostics=0, path4serialization=str(tmp),
-    )
-    base.update(kw)
-    return SimulationConfig(**base)
-
-
-def _fish_cfg(tmp, **kw):
-    base = dict(
-        bpdx=1, bpdy=1, bpdz=1, levelMax=1, levelStart=0, block_size=32,
-        extent=1.0, CFL=0.3, nu=1e-4, nsteps=8, tend=0.0, rampup=0,
-        factory_content="stefanfish L=0.3 T=1.0 xpos=0.5",
-        dtype="float32", pipelined=True, verbose=False,
-        freqDiagnostics=0, path4serialization=str(tmp),
-    )
-    base.update(kw)
-    return SimulationConfig(**base)
-
-
-def _run(cfg):
-    sim = Simulation(cfg)
-    sim.init()
-    sim.simulate()
-    return sim
-
-
-def _ke(vel):
-    v = np.asarray(vel, np.float64)
-    return float(np.mean(np.sum(v * v, axis=-1)))
+pytestmark = pytest.mark.usefixtures("clean_faults")
 
 
 # -- K-equivalence ---------------------------------------------------------
@@ -75,8 +36,8 @@ def _ke(vel):
 def test_tgv_scan_k1_vs_k8_bitwise(tmp_path):
     """One compiled one_step body serves both: only the scan length
     differs, so the trajectories must agree BITWISE."""
-    a = _run(_tgv_cfg(tmp_path / "k1", scan_k=1))
-    b = _run(_tgv_cfg(tmp_path / "k8", scan_k=8))
+    a = simulate(tgv_cfg(tmp_path / "k1", scan_k=1))
+    b = simulate(tgv_cfg(tmp_path / "k8", scan_k=8))
     assert a._scan_k == 1 and b._scan_k == 8
     assert a.sim.step == b.sim.step == 16
     np.testing.assert_array_equal(
@@ -90,11 +51,11 @@ def test_tgv_scan_k1_vs_k8_bitwise(tmp_path):
 def test_fish_scan_k1_vs_k8_ke(tmp_path):
     """Fish carry adds rigid/qint/chi/udef; K must still not change the
     physics (<= 1e-6 relative KE, the ISSUE tolerance)."""
-    a = _run(_fish_cfg(tmp_path / "k1", scan_k=1))
-    b = _run(_fish_cfg(tmp_path / "k8", scan_k=8))
+    a = simulate(fish_cfg(tmp_path / "k1", scan_k=1))
+    b = simulate(fish_cfg(tmp_path / "k8", scan_k=8))
     assert a._scan_k == 1 and b._scan_k == 8
     assert a.sim.step == b.sim.step == 8
-    ke_a, ke_b = _ke(a.sim.state["vel"]), _ke(b.sim.state["vel"])
+    ke_a, ke_b = mean_ke(a.sim.state["vel"]), mean_ke(b.sim.state["vel"])
     assert abs(ke_a - ke_b) <= 1e-6 * max(abs(ke_a), 1e-12)
     np.testing.assert_allclose(
         a.sim.obstacles[0].position, b.sim.obstacles[0].position,
@@ -122,7 +83,7 @@ def test_device_midline_chi_udef_matches_host(tmp_path):
 
     # y/z offset by h/2: centers the (sub-cell-thin) body on cell
     # centers so the resting fish still owns interior cells at 32^3
-    sim = Simulation(_fish_cfg(tmp_path, factory_content=(
+    sim = Simulation(fish_cfg(tmp_path, factory_content=(
         "stefanfish L=0.3 T=1.0 xpos=0.5 ypos=0.515625 zpos=0.515625")))
     sim.init()
     s = sim.sim
@@ -198,19 +159,19 @@ def test_scan_fault_mid_megaloop_rolls_back_and_completes(tmp_path,
     rollback lands on the K-aligned cadence snapshot, the run completes
     with a clean decaying field."""
     monkeypatch.setenv("CUP3D_SNAP_EVERY", "4")
-    ref = _run(_tgv_cfg(tmp_path / "ref", scan_k=4))
-    ke_ref = _ke(ref.sim.state["vel"])
+    ref = simulate(tgv_cfg(tmp_path / "ref", scan_k=4))
+    ke_ref = mean_ke(ref.sim.state["vel"])
 
     faults.arm("step.nan_velocity", 6, 1)
     s0 = M.snapshot()
-    sim = _run(_tgv_cfg(tmp_path / "flt", scan_k=4))
+    sim = simulate(tgv_cfg(tmp_path / "flt", scan_k=4))
     d = M.delta(s0)
     assert sim.sim.step == 16
     assert d["resilience.rollbacks"] == 1
     assert d.get("resilience.giveups", 0) == 0
     vel = np.asarray(sim.sim.state["vel"], np.float64)
     assert np.isfinite(vel).all()
-    ke = _ke(vel)
+    ke = mean_ke(vel)
     # the retreat shrinks dt for the retried steps, so the faulted run
     # reaches step 16 at an earlier physical time than the reference:
     # demand a sane decaying-TGV energy, not a matched trajectory
@@ -226,9 +187,9 @@ def test_scan_recover_armed_idle_is_bitwise_vs_legacy(tmp_path,
                                                       monkeypatch):
     """Recovery armed + no faults must not perturb the scan trajectory:
     bitwise vs the CUP3D_RECOVER=0 legacy loop at the same K."""
-    armed = _run(_tgv_cfg(tmp_path / "armed", scan_k=4))
+    armed = simulate(tgv_cfg(tmp_path / "armed", scan_k=4))
     monkeypatch.setenv("CUP3D_RECOVER", "0")
-    legacy = _run(_tgv_cfg(tmp_path / "legacy", scan_k=4))
+    legacy = simulate(tgv_cfg(tmp_path / "legacy", scan_k=4))
     assert armed._scan_k == legacy._scan_k == 4
     np.testing.assert_array_equal(
         np.asarray(armed.sim.state["vel"]),
@@ -245,7 +206,7 @@ def test_scan_zero_steady_state_retraces(tmp_path):
     from cup3d_tpu.analysis import runtime as R
 
     with R.RecompileCounter() as rc:
-        sim = _run(_tgv_cfg(tmp_path, scan_k=4))
+        sim = simulate(tgv_cfg(tmp_path, scan_k=4))
     assert sim._scan_k == 4
     assert "megaloop" in rc.compiles
     rc.assert_steady_state(budget=1)
@@ -264,23 +225,23 @@ def test_scan_k_resolution_and_eligibility(tmp_path, monkeypatch):
 
     # env knob overrides config; malformed env falls back to config
     monkeypatch.setenv("CUP3D_SCAN_K", "5")
-    assert scan_k_of(_tgv_cfg(tmp_path / "env", scan_k=2)) == 5
+    assert scan_k_of(tgv_cfg(tmp_path / "env", scan_k=2)) == 5
     monkeypatch.setenv("CUP3D_SCAN_K", "bogus")
-    assert scan_k_of(_tgv_cfg(tmp_path / "bad", scan_k=2)) == 2
+    assert scan_k_of(tgv_cfg(tmp_path / "bad", scan_k=2)) == 2
     monkeypatch.delenv("CUP3D_SCAN_K")
     # static gates: pipelined only, step-budget runs only
-    assert scan_k_of(_tgv_cfg(tmp_path / "np", scan_k=4,
+    assert scan_k_of(tgv_cfg(tmp_path / "np", scan_k=4,
                               pipelined=False)) == 0
-    assert scan_k_of(_tgv_cfg(tmp_path / "tend", scan_k=4, tend=0.5,
+    assert scan_k_of(tgv_cfg(tmp_path / "tend", scan_k=4, tend=0.5,
                               nsteps=0)) == 0
-    assert scan_k_of(_tgv_cfg(tmp_path / "fixed", scan_k=4,
+    assert scan_k_of(tgv_cfg(tmp_path / "fixed", scan_k=4,
                               dt=1e-3)) == 0
 
 
 def test_scan_tail_steps_fall_back_to_host(tmp_path):
     """nsteps not divisible by K: the tail runs per-step so the step
     budget stays exact; flight records flag the scan steps."""
-    sim = _run(_tgv_cfg(tmp_path, scan_k=4, nsteps=10))
+    sim = simulate(tgv_cfg(tmp_path, scan_k=4, nsteps=10))
     assert sim.sim.step == 10
     recs = list(sim.flight.steps)
     # scan rows cover steps 0..7; the host tail covers 8..9 (megaloop

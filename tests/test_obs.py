@@ -13,6 +13,7 @@ ISSUE 4 acceptance paths on live drivers:
   VALIDATION.md round 9).
 """
 
+import functools
 import itertools
 import json
 import os
@@ -24,6 +25,7 @@ import pytest
 from cup3d_tpu.obs import flight as F
 from cup3d_tpu.obs import metrics as M
 from cup3d_tpu.obs import trace as T
+from tests._cases import flight_files, iterative_tgv_cfg
 
 
 # -- metrics registry ------------------------------------------------------
@@ -239,23 +241,7 @@ def test_flight_recorder_itercap_triggers(tmp_path):
 # -- live drivers ----------------------------------------------------------
 
 
-def _uniform_cfg(tmp_path, **kw):
-    from cup3d_tpu.config import SimulationConfig
-
-    base = dict(
-        bpdx=2, bpdy=2, bpdz=2, levelMax=1, levelStart=0,
-        extent=2 * np.pi, CFL=0.3, nu=0.02, nsteps=3, rampup=0,
-        initCond="taylorGreen", poissonSolver="iterative",
-        poissonTol=1e-6, poissonTolRel=1e-4,
-        verbose=False, freqDiagnostics=0,
-        path4serialization=str(tmp_path),
-    )
-    base.update(kw)
-    return SimulationConfig(**base)
-
-
-def _flight_files(tmp_path):
-    return [f for f in os.listdir(tmp_path) if f.startswith("flight_")]
+_uniform_cfg = functools.partial(iterative_tgv_cfg, nsteps=3)
 
 
 def test_uniform_traced_run_and_clean_flight(tmp_path):
@@ -282,7 +268,7 @@ def test_uniform_traced_run_and_clean_flight(tmp_path):
     pf = json.load(open(tmp_path / "trace.pfto.json"))
     steps = [e for e in pf["traceEvents"] if e["name"] == "step"]
     assert steps and "solver" in steps[-1]["args"]
-    assert _flight_files(tmp_path) == []  # clean run: no postmortem
+    assert flight_files(tmp_path) == []  # clean run: no postmortem
     # solver gauges reached the process-global registry
     assert M.snapshot()["poisson.iters{driver=uniform}"] >= 1
 
@@ -302,7 +288,7 @@ def test_uniform_nan_injection_dumps_postmortem(tmp_path):
         # the next dt's NaN-umax abort — both are flight triggers
         for _ in range(2):
             sim.advance(sim.calc_max_timestep())
-    files = _flight_files(tmp_path)
+    files = flight_files(tmp_path)
     assert len(files) == 1, files
     pm = F.load_postmortem(os.path.join(tmp_path, files[0]))
     assert pm["reason"] in ("nan-velocity", "poisson-nan-residual")
@@ -362,7 +348,7 @@ def test_amr_nan_injection_dumps_postmortem(tmp_path):
     with pytest.raises(RuntimeError):
         for _ in range(2):
             sim.advance(sim.calc_max_timestep())
-    files = _flight_files(tmp_path)
+    files = flight_files(tmp_path)
     assert len(files) == 1, files
     pm = F.load_postmortem(os.path.join(tmp_path, files[0]))
     assert pm["reason"] in ("nan-velocity", "poisson-nan-residual")
@@ -386,7 +372,7 @@ def test_dt_collapse_triggers_postmortem(tmp_path):
     sim.cfg.tend = max(sim.sim.time * 0.5, 1e-9)
     with pytest.raises(RuntimeError, match="dt policy collapse"):
         sim.calc_max_timestep()
-    files = _flight_files(tmp_path)
+    files = flight_files(tmp_path)
     assert len(files) == 1
     assert F.load_postmortem(
         os.path.join(tmp_path, files[0])

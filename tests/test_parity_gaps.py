@@ -7,27 +7,17 @@ import numpy as np
 import jax.numpy as jnp
 import pytest
 
-from cup3d_tpu.grid.blocks import BlockGrid
 from cup3d_tpu.grid.flux import build_flux_tables
-from cup3d_tpu.grid.octree import Octree, TreeConfig
 from cup3d_tpu.grid.uniform import BC, UniformGrid
 from cup3d_tpu.ops import amr_ops, krylov
-
-BS = 8
-
-
-def _two_level_grid():
-    t = Octree(TreeConfig((2, 2, 2), 2, (True,) * 3), 0)
-    t.refine((0, 0, 0, 0))
-    t.assert_balanced()
-    return BlockGrid(t, (1.0,) * 3, (BC.periodic,) * 3, bs=BS)
+from tests._grids import BS, two_level_grid
 
 
 @pytest.mark.parametrize("mode", [0, 1, 2, 3])
 def test_amr_mean_constraint_modes(mode):
     """Every mode must solve the compatible Poisson problem to the same
     GRADIENT (solutions differ by the nullspace constant only)."""
-    g = _two_level_grid()
+    g = two_level_grid()
     rng = np.random.default_rng(0)
     rhs = rng.standard_normal((g.nb, BS, BS, BS)).astype(np.float32)
     vol = (g.h**3).reshape(g.nb, 1, 1, 1)

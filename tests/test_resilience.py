@@ -19,6 +19,7 @@ The acceptance paths:
   or exits gracefully with a postmortem.
 """
 
+import functools
 import os
 import pickle
 import random
@@ -31,25 +32,13 @@ from cup3d_tpu.config import SimulationConfig
 from cup3d_tpu.obs import metrics as M
 from cup3d_tpu.resilience import faults
 from cup3d_tpu.resilience.recovery import RecoveryEngine, SimulationFailure
+from tests._cases import flight_files, iterative_tgv_cfg, mean_ke
 
 
-@pytest.fixture(autouse=True)
-def _clean_faults():
-    faults.clear()
-    yield
-    faults.clear()
+pytestmark = pytest.mark.usefixtures("clean_faults")
 
 
-def _uniform_cfg(tmp, **kw):
-    base = dict(
-        bpdx=2, bpdy=2, bpdz=2, levelMax=1, levelStart=0,
-        extent=2 * np.pi, CFL=0.3, nu=0.02, tend=0.5, nsteps=0, rampup=0,
-        initCond="taylorGreen", poissonSolver="iterative",
-        poissonTol=1e-6, poissonTolRel=1e-4, verbose=False,
-        freqDiagnostics=0, path4serialization=str(tmp),
-    )
-    base.update(kw)
-    return SimulationConfig(**base)
+_uniform_cfg = functools.partial(iterative_tgv_cfg, tend=0.5, nsteps=0)
 
 
 def _amr_cfg(tmp, **kw):
@@ -71,15 +60,6 @@ def _run_uniform(tmp, **kw):
     sim.init()
     sim.simulate()
     return sim
-
-
-def _flight_files(tmp):
-    return [f for f in os.listdir(tmp) if f.startswith("flight_")]
-
-
-def _ke(vel):
-    v = np.asarray(vel, np.float64)
-    return float(np.mean(np.sum(v * v, axis=-1)))
 
 
 # -- fault plan ------------------------------------------------------------
@@ -144,7 +124,7 @@ def test_uniform_nan_fault_recovers_and_matches_qoi(tmp_path):
     rollback — one rollback, <= 3 retries, no postmortem — and the final
     kinetic energy matches the unfaulted run within 5%."""
     ref = _run_uniform(tmp_path / "ref")
-    ke_ref = _ke(ref.sim.state["vel"])
+    ke_ref = mean_ke(ref.sim.state["vel"])
 
     faults.arm("step.nan_velocity", 2, 1)
     s0 = M.snapshot()
@@ -155,11 +135,11 @@ def test_uniform_nan_fault_recovers_and_matches_qoi(tmp_path):
     assert d.get("resilience.giveups", 0) == 0
     assert sum(v for k, v in d.items()
                if k.startswith("resilience.retries")) <= 3
-    assert _flight_files(tmp_path / "flt") == []  # recovered: no postmortem
+    assert flight_files(tmp_path / "flt") == []  # recovered: no postmortem
     ev = list(sim.flight.recovery_events)
     assert any(e.get("reason") == "nan-velocity" and e.get("stage")
                for e in ev)
-    ke = _ke(sim.sim.state["vel"])
+    ke = mean_ke(sim.sim.state["vel"])
     assert abs(ke - ke_ref) <= 0.05 * abs(ke_ref)
 
 
@@ -181,7 +161,7 @@ def test_uniform_recover_armed_is_bitwise_vs_legacy(tmp_path, monkeypatch):
     sim.init()
     with pytest.raises(RuntimeError, match="runaway"):
         sim.simulate()
-    files = _flight_files(tmp_path / "crash")
+    files = flight_files(tmp_path / "crash")
     assert len(files) == 1 and "nan-velocity" in files[0]
 
 
@@ -193,7 +173,7 @@ def test_amr_nan_fault_recovers_and_matches_qoi(tmp_path):
     ref = AMRSimulation(_amr_cfg(tmp_path / "ref"))
     ref.init()
     ref.simulate()
-    ke_ref = _ke(ref._unpad(ref.state["vel"]))
+    ke_ref = mean_ke(ref._unpad(ref.state["vel"]))
 
     faults.arm("step.nan_velocity", 2, 1)
     s0 = M.snapshot()
@@ -203,8 +183,8 @@ def test_amr_nan_fault_recovers_and_matches_qoi(tmp_path):
     d = M.delta(s0)
     assert sim.time >= sim.cfg.tend - 1e-9
     assert d["resilience.rollbacks"] == 1
-    assert _flight_files(tmp_path / "flt") == []
-    ke = _ke(sim._unpad(sim.state["vel"]))
+    assert flight_files(tmp_path / "flt") == []
+    ke = mean_ke(sim._unpad(sim.state["vel"]))
     assert abs(ke - ke_ref) <= 0.05 * abs(ke_ref)
 
 
@@ -219,7 +199,7 @@ def test_poisson_itercap_fault_walks_the_ladder(tmp_path):
     assert sim.sim.time >= sim.cfg.tend - 1e-9
     assert d["resilience.rollbacks"] == 1
     assert d["resilience.retries{stage=warm-restart}"] == 1
-    assert _flight_files(tmp_path) == []
+    assert flight_files(tmp_path) == []
     ev = list(sim.flight.recovery_events)
     assert any(e.get("reason") == "poisson-itercap" for e in ev)
 
@@ -245,7 +225,7 @@ def test_poisson_ladder_escalates_to_solver_rebuild(tmp_path):
     # the escalation really rebuilt the solve: bumped budget, postmortem
     # carries the recovery ring
     assert sim.sim.poisson_solver.maxiter == 4000
-    files = _flight_files(tmp_path)
+    files = flight_files(tmp_path)
     assert len(files) == 1
     from cup3d_tpu.obs.flight import load_postmortem
 
@@ -271,7 +251,7 @@ def test_give_up_writes_postmortem_and_restartable_checkpoint(tmp_path):
     d = M.delta(s0)
     assert d["resilience.giveups"] == 1
     assert d["resilience.rollbacks"] >= 1
-    files = _flight_files(tmp_path)
+    files = flight_files(tmp_path)
     assert len(files) == 1
     faults.clear()
     path = latest_valid_checkpoint(str(tmp_path))
@@ -384,7 +364,7 @@ def test_chaos_seeded_site_recovers_or_exits_gracefully(tmp_path):
         assert sim.sim.step >= cfg.nsteps
         assert np.all(np.isfinite(np.asarray(sim.sim.state["vel"])))
     else:
-        assert _flight_files(tmp_path), (
+        assert flight_files(tmp_path), (
             f"graceful exit for site {site!r} must leave a postmortem"
         )
 

@@ -8,33 +8,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from cup3d_tpu.grid.blocks import BlockGrid
-from cup3d_tpu.grid.octree import Octree, TreeConfig
+from cup3d_tpu.config import SimulationConfig
 from cup3d_tpu.grid.uniform import BC
 from cup3d_tpu.parallel.faces import build_sharded_face_tables
-from cup3d_tpu.parallel.forest import ShardedForest, make_block_mesh
-
-BS = 8
-
-
-def _grid(bc=(BC.periodic,) * 3, refine=((0, 0, 0, 0), (0, 1, 1, 1))):
-    tree = Octree(
-        TreeConfig((2, 2, 2), 3, tuple(b == BC.periodic for b in bc)), 0
-    )
-    for k in refine:
-        tree.refine(k)
-    tree.assert_balanced()
-    return BlockGrid(tree, (1.0, 1.0, 1.0), bc)
-
-
-def _forest(g, n=8):
-    return ShardedForest(g, make_block_mesh(jax.devices()[:n]))
-
-
-def _rand(g, ncomp=0, seed=0):
-    rng = np.random.default_rng(seed)
-    shape = (g.nb, BS, BS, BS) + ((ncomp,) if ncomp else ())
-    return jnp.asarray(rng.standard_normal(shape).astype(np.float32))
+from cup3d_tpu.parallel.forest import make_block_mesh
+from cup3d_tpu.sim.amr import AMRSimulation
+from tests._grids import (
+    BS, THREE_LEVEL, assemble, forest, laplacian, mixed_grid, rand,
+)
 
 
 @pytest.mark.parametrize("width", [1, 3])
@@ -42,30 +23,25 @@ def _rand(g, ncomp=0, seed=0):
     "refine",
     [
         ((0, 0, 0, 0), (0, 1, 1, 1)),  # two-level mixed
-        # three-level (pyramid exchange across a deeper subtree)
-        (
-            (0, 0, 0, 0), (0, 1, 0, 0), (0, 0, 1, 0), (0, 0, 0, 1),
-            (0, 1, 1, 0), (0, 1, 0, 1), (0, 0, 1, 1), (0, 1, 1, 1),
-            (1, 1, 1, 1),
-        ),
+        THREE_LEVEL,  # pyramid exchange across a deeper subtree
     ],
 )
 @pytest.mark.slow
 def test_sharded_faces_match_single_device(width, refine):
-    g = _grid(refine=refine)
-    fo = _forest(g)
+    g = mixed_grid(refine=refine)
+    fo = forest(g)
     tab = g.face_tables(width)
     stab = build_sharded_face_tables(fo, width)
 
-    x = _rand(g)
+    x = rand(g)
     ref = tab.assemble_scalar(x, BS)
-    got = fo.unpad(stab.assemble_scalar(fo.pad(x), BS))
+    got = fo.unpad(assemble(stab, "scalar", fo.pad(x)))
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=0, atol=2e-6)
 
-    v = _rand(g, 3, seed=1)
+    v = rand(g, 3, seed=1)
     refv = tab.assemble_vector(v, BS)
-    gotv = fo.unpad(stab.assemble_vector(fo.pad(v), BS))
+    gotv = fo.unpad(assemble(stab, "vector", fo.pad(v)))
     np.testing.assert_allclose(np.asarray(gotv), np.asarray(refv),
                                rtol=0, atol=2e-6)
 
@@ -75,34 +51,34 @@ def test_sharded_faces_match_single_device(width, refine):
     (BC.freespace,) * 3,
 ])
 def test_sharded_faces_closed_bcs(bc):
-    g = _grid(bc=bc)
-    fo = _forest(g)
+    g = mixed_grid(bc=bc)
+    fo = forest(g)
     tab = g.face_tables(1)
     if tab.fb_rows is not None:
         pytest.skip("degenerate topology: sharded path falls back")
     stab = build_sharded_face_tables(fo, 1)
-    v = _rand(g, 3, seed=2)
+    v = rand(g, 3, seed=2)
     refv = tab.assemble_vector(v, BS)
-    gotv = fo.unpad(stab.assemble_vector(fo.pad(v), BS))
+    gotv = fo.unpad(assemble(stab, "vector", fo.pad(v)))
     np.testing.assert_allclose(np.asarray(gotv), np.asarray(refv),
                                rtol=0, atol=2e-6)
     # component path (chi/p style scalars with a sign component)
     refc = tab.assemble_component(v[..., 0], BS, 0)
-    gotc = fo.unpad(stab.assemble_component(fo.pad(v[..., 0]), BS, 0))
+    gotc = fo.unpad(assemble(stab, "component", fo.pad(v[..., 0]), 0))
     np.testing.assert_allclose(np.asarray(gotc), np.asarray(refc),
                                rtol=0, atol=2e-6)
 
 
 def test_sharded_faces_uneven_shards():
     """nb not divisible by D: padding blocks stay exactly zero."""
-    g = _grid(refine=((0, 0, 0, 0),))  # 8 - 1 + 8 = 15 blocks
+    g = mixed_grid(refine=((0, 0, 0, 0),))  # 8 - 1 + 8 = 15 blocks
     assert g.nb % 8 != 0
-    fo = _forest(g)
+    fo = forest(g)
     stab = build_sharded_face_tables(fo, 1)
     tab = g.face_tables(1)
-    x = _rand(g, seed=3)
+    x = rand(g, seed=3)
     ref = tab.assemble_scalar(x, BS)
-    padded = stab.assemble_scalar(fo.pad(x), BS)
+    padded = assemble(stab, "scalar", fo.pad(x))
     got = fo.unpad(padded)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=0, atol=2e-6)
@@ -112,20 +88,34 @@ def test_sharded_faces_uneven_shards():
 def test_sharded_laplacian_with_face_tables():
     """The refluxed Laplacian on sharded face tables == single device."""
     from cup3d_tpu.grid.flux import build_flux_tables
-    from cup3d_tpu.ops import amr_ops
 
-    g = _grid()
-    fo = _forest(g)
+    g = mixed_grid()
+    fo = forest(g)
     stab = build_sharded_face_tables(fo, 1)
     tab = g.face_tables(1)
     ftab = build_flux_tables(g)
-    x = _rand(g, seed=4)
-    ref = amr_ops.laplacian_blocks(g, x, tab, ftab)
-    got = fo.unpad(
-        amr_ops.laplacian_blocks(fo.geom, fo.pad(x), stab, fo.flux_tables)
-    )
+    x = rand(g, seed=4)
+    ref = laplacian(g, x, tab, ftab)
+    got = fo.unpad(laplacian(fo.geom, fo.pad(x), stab, fo.flux_tables))
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=0, atol=5e-5)
+
+
+def _single_and_sharded(**cfg):
+    """The pipelined forest driver, adaptation off, for 4 steps of 1e-3:
+    on one device and on the 8-device block mesh."""
+    def run(mesh):
+        sim = AMRSimulation(SimulationConfig(
+            CFL=0.4, nu=1e-3, tend=0.0, nsteps=4, rampup=0, dt=1e-3,
+            poissonSolver="iterative", poissonTol=1e-5, poissonTolRel=1e-3,
+            verbose=False, freqDiagnostics=0, pipelined=True, **cfg,
+        ), mesh=mesh)
+        sim.init()
+        sim.adapt_enabled = False
+        sim.simulate()
+        return sim
+
+    return run(None), run(make_block_mesh(jax.devices()[:8]))
 
 
 @pytest.mark.slow
@@ -133,32 +123,14 @@ def test_pipelined_megastep_on_mesh_matches_single_device():
     """Round 4: the fused pipelined megastep runs ON the sharded forest
     (VERDICT r3 item 2) — trajectories match the single-device pipelined
     driver."""
-    from cup3d_tpu.config import SimulationConfig
-    from cup3d_tpu.parallel.forest import make_block_mesh
-    from cup3d_tpu.sim.amr import AMRSimulation
-
-    factory = (
-        "Sphere radius=0.12 xpos=0.35 ypos=0.5 zpos=0.5 xvel=0.3 "
-        "bForcedInSimFrame=1 bFixFrameOfRef=1\n"
-        "Sphere radius=0.1 xpos=0.7 ypos=0.45 zpos=0.5"
+    single, sharded = _single_and_sharded(
+        bpdx=1, bpdy=1, bpdz=1, levelMax=2, levelStart=1, extent=1.0,
+        Ctol=0.1, Rtol=5.0, factory_content=(
+            "Sphere radius=0.12 xpos=0.35 ypos=0.5 zpos=0.5 xvel=0.3 "
+            "bForcedInSimFrame=1 bFixFrameOfRef=1\n"
+            "Sphere radius=0.1 xpos=0.7 ypos=0.45 zpos=0.5"
+        ),
     )
-
-    def run(mesh):
-        cfg = SimulationConfig(
-            bpdx=1, bpdy=1, bpdz=1, levelMax=2, levelStart=1, extent=1.0,
-            CFL=0.4, Ctol=0.1, Rtol=5.0, nu=1e-3, tend=0.0, nsteps=4,
-            rampup=0, dt=1e-3, poissonSolver="iterative",
-            poissonTol=1e-5, poissonTolRel=1e-3, factory_content=factory,
-            verbose=False, freqDiagnostics=0, pipelined=True,
-        )
-        sim = AMRSimulation(cfg, mesh=mesh)
-        sim.init()
-        sim.adapt_enabled = False
-        sim.simulate()
-        return sim
-
-    single = run(None)
-    sharded = run(make_block_mesh(jax.devices()[:8]))
     assert sharded.forest is not None
     assert not sharded._pack_reader  # flushed
     for a, b in zip(single.obstacles, sharded.obstacles):
@@ -175,27 +147,11 @@ def test_pipelined_megastep_on_mesh_matches_single_device():
 
 def test_pipelined_free_megastep_on_mesh():
     """Obstacle-free fused stepping on the mesh (TGV regime)."""
-    from cup3d_tpu.config import SimulationConfig
-    from cup3d_tpu.parallel.forest import make_block_mesh
-    from cup3d_tpu.sim.amr import AMRSimulation
-
-    def run(mesh):
-        cfg = SimulationConfig(
-            bpdx=2, bpdy=2, bpdz=2, levelMax=2, levelStart=0,
-            extent=float(2 * np.pi), CFL=0.4, Rtol=1.8, Ctol=0.05,
-            nu=1e-3, tend=0.0, nsteps=4, rampup=0, dt=1e-3,
-            poissonSolver="iterative", poissonTol=1e-5, poissonTolRel=1e-3,
-            initCond="taylorGreen", verbose=False, freqDiagnostics=0,
-            pipelined=True,
-        )
-        sim = AMRSimulation(cfg, mesh=mesh)
-        sim.init()
-        sim.adapt_enabled = False
-        sim.simulate()
-        return sim
-
-    single = run(None)
-    sharded = run(make_block_mesh(jax.devices()[:8]))
+    single, sharded = _single_and_sharded(
+        bpdx=2, bpdy=2, bpdz=2, levelMax=2, levelStart=0,
+        extent=float(2 * np.pi), Rtol=1.8, Ctol=0.05,
+        initCond="taylorGreen",
+    )
     np.testing.assert_allclose(
         np.asarray(sharded.forest.unpad(sharded.state["vel"])),
         np.asarray(single._unpad(single.state["vel"])),

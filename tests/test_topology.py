@@ -35,53 +35,14 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from cup3d_tpu.config import SimulationConfig
 from cup3d_tpu.obs import metrics as M
 from cup3d_tpu.parallel import topology as topo
 from cup3d_tpu.resilience import faults
 from cup3d_tpu.sim.simulation import Simulation
+from tests._cases import fish_cfg, mean_ke, simulate, tgv_cfg
 
 
-@pytest.fixture(autouse=True)
-def _clean_faults():
-    faults.clear()
-    yield
-    faults.clear()
-
-
-def _tgv_cfg(tmp, **kw):
-    base = dict(
-        bpdx=2, bpdy=2, bpdz=2, levelMax=1, levelStart=0,
-        extent=2 * np.pi, CFL=0.3, nu=0.02, nsteps=16, tend=0.0,
-        rampup=0, initCond="taylorGreen", pipelined=True, verbose=False,
-        freqDiagnostics=0, path4serialization=str(tmp),
-    )
-    base.update(kw)
-    return SimulationConfig(**base)
-
-
-def _fish_cfg(tmp, **kw):
-    base = dict(
-        bpdx=1, bpdy=1, bpdz=1, levelMax=1, levelStart=0, block_size=32,
-        extent=1.0, CFL=0.3, nu=1e-4, nsteps=8, tend=0.0, rampup=0,
-        factory_content="stefanfish L=0.3 T=1.0 xpos=0.5",
-        dtype="float32", pipelined=True, verbose=False,
-        freqDiagnostics=0, path4serialization=str(tmp),
-    )
-    base.update(kw)
-    return SimulationConfig(**base)
-
-
-def _run(cfg):
-    sim = Simulation(cfg)
-    sim.init()
-    sim.simulate()
-    return sim
-
-
-def _ke(vel):
-    v = np.asarray(vel, np.float64)
-    return float(np.mean(np.sum(v * v, axis=-1)))
+pytestmark = pytest.mark.usefixtures("clean_faults")
 
 
 # -- factory + placement ---------------------------------------------------
@@ -162,7 +123,7 @@ def test_megaloop_mesh_gate_raises_when_unavailable(monkeypatch, tmp_path):
         topo.megaloop_mesh()
     # a solver with no slab form raises at the sharded build as well
     monkeypatch.setenv("CUP3D_MESH_X", "4")
-    sim = Simulation(_tgv_cfg(tmp_path, scan_k=8,
+    sim = Simulation(tgv_cfg(tmp_path, scan_k=8,
                               poissonSolver="iterative"))
     sim.init()
     with pytest.raises(NotImplementedError, match="spectral"):
@@ -190,14 +151,33 @@ def test_fleet_mesh_gate_and_loud_fallback(monkeypatch):
 # -- sharded megaloop equivalence ------------------------------------------
 
 
+def _run_canonical(tmp_path, source, **env_extra):
+    """Run ``source`` (argv[1] = tmp_path) in a process of its own under
+    the canonical compile, where the bitwise gates hold: XLA CPU fusion
+    is shape-dependent, XLA_FLAGS must be set before the CPU client
+    exists, and this process's client is long since alive."""
+    script = tmp_path / "canonical.py"
+    script.write_text(source)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, **env_extra)
+    env.pop("CUP3D_MESH_X", None)
+    env["JAX_PLATFORMS"] = "cpu"
+    env["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=8 "
+                        "--xla_disable_hlo_passes=fusion")
+    env["PYTHONPATH"] = repo + os.pathsep + env.get("PYTHONPATH", "")
+    proc = subprocess.run(
+        [sys.executable, str(script), str(tmp_path)],
+        capture_output=True, text=True, timeout=180, env=env)
+    assert proc.returncode == 0, proc.stdout + proc.stderr
+    assert "BITWISE-OK" in proc.stdout
+
+
 def test_sharded_tgv_bitwise_under_canonical_compile(tmp_path):
     """Solo-vs-sharded TGV is BITWISE when XLA's shape-dependent CPU
     fusion is pinned off (the canonical compile the Round-18 contract
-    is stated under — see VALIDATION.md).  Subprocess: XLA_FLAGS must
-    be set before the CPU client exists, and this process's client is
-    long since alive."""
-    script = tmp_path / "bitwise.py"
-    script.write_text(
+    is stated under — see VALIDATION.md)."""
+    _run_canonical(
+        tmp_path,
         "import os, sys\n"
         "import numpy as np\n"
         "from cup3d_tpu.config import SimulationConfig\n"
@@ -222,18 +202,6 @@ def test_sharded_tgv_bitwise_under_canonical_compile(tmp_path):
         "assert (solo == shd).all(), float(np.abs(solo - shd).max())\n"
         "print('BITWISE-OK')\n"
     )
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ)
-    env.pop("CUP3D_MESH_X", None)
-    env["JAX_PLATFORMS"] = "cpu"
-    env["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=8 "
-                        "--xla_disable_hlo_passes=fusion")
-    env["PYTHONPATH"] = repo + os.pathsep + env.get("PYTHONPATH", "")
-    proc = subprocess.run(
-        [sys.executable, str(script), str(tmp_path)],
-        capture_output=True, text=True, timeout=420, env=env)
-    assert proc.returncode == 0, proc.stdout + proc.stderr
-    assert "BITWISE-OK" in proc.stdout
 
 
 def test_sharded_tgv_matches_solo_inprocess(tmp_path, monkeypatch):
@@ -244,16 +212,16 @@ def test_sharded_tgv_matches_solo_inprocess(tmp_path, monkeypatch):
     from cup3d_tpu.analysis import runtime as R
 
     monkeypatch.delenv("CUP3D_MESH_X", raising=False)
-    a = _run(_tgv_cfg(tmp_path / "solo", scan_k=8))
+    a = simulate(tgv_cfg(tmp_path / "solo", scan_k=8))
     monkeypatch.setenv("CUP3D_MESH_X", "4")
     with R.RecompileCounter() as rc:
-        b = _run(_tgv_cfg(tmp_path / "shd", scan_k=8))
+        b = simulate(tgv_cfg(tmp_path / "shd", scan_k=8))
     assert b._scan_mesh is not None  # really sharded, not a fallback
     assert a.sim.step == b.sim.step == 16
     va = np.asarray(a.sim.state["vel"])
     vb = np.asarray(b.sim.state["vel"])
     np.testing.assert_allclose(vb, va, rtol=1e-5, atol=1e-6)
-    ke_a, ke_b = _ke(va), _ke(vb)
+    ke_a, ke_b = mean_ke(va), mean_ke(vb)
     assert abs(ke_a - ke_b) <= 1e-6 * max(abs(ke_a), 1e-12)
     # zero steady-state retraces: 16 steps / K=8 -> 2 dispatches, one
     # compiled specialization per function
@@ -265,12 +233,12 @@ def test_sharded_fish_ke(tmp_path, monkeypatch):
     x-slab build must hold the same 1e-6 relative-KE contract as the
     K-equivalence gate (test_megaloop.py)."""
     monkeypatch.delenv("CUP3D_MESH_X", raising=False)
-    a = _run(_fish_cfg(tmp_path / "solo", scan_k=8))
+    a = simulate(fish_cfg(tmp_path / "solo", scan_k=8))
     monkeypatch.setenv("CUP3D_MESH_X", "4")
-    b = _run(_fish_cfg(tmp_path / "shd", scan_k=8))
+    b = simulate(fish_cfg(tmp_path / "shd", scan_k=8))
     assert b._scan_mesh is not None
     assert a.sim.step == b.sim.step == 8
-    ke_a, ke_b = _ke(a.sim.state["vel"]), _ke(b.sim.state["vel"])
+    ke_a, ke_b = mean_ke(a.sim.state["vel"]), mean_ke(b.sim.state["vel"])
     assert abs(ke_a - ke_b) <= 1e-6 * max(abs(ke_a), 1e-12)
     np.testing.assert_allclose(
         a.sim.obstacles[0].position, b.sim.obstacles[0].position,
@@ -296,7 +264,21 @@ def _fleet_drain(mesh, workdir, arm_shard=None):
     return srv, out
 
 
-def test_fleet_sharded_drain_and_shard_loss(tmp_path, monkeypatch):
+def test_fleet_sharded_drain_and_shard_loss(tmp_path):
+    """The three drains below, under the canonical compile like the
+    megaloop's bitwise gate above: under the default compile the
+    sharded drain's QoI bytes are one bit off the unsharded one's
+    (fusion rounds the (2 lanes x 2) program's shapes differently)."""
+    _run_canonical(
+        tmp_path,
+        "import sys\n"
+        "from tests.test_topology import fleet_drain_three_ways\n"
+        "fleet_drain_three_ways(sys.argv[1])\n"
+        "print('BITWISE-OK')\n",
+        CUP3D_SCAN_K="4")
+
+
+def fleet_drain_three_ways(tmp):
     """One seeded 4-job TGV mix, drained three ways: unsharded vmap,
     sharded over the (2 lanes x 2) mesh, and sharded with a shard loss
     injected mid-drain.  The sharded drain must be BITWISE against the
@@ -305,12 +287,11 @@ def test_fleet_sharded_drain_and_shard_loss(tmp_path, monkeypatch):
     QoI bytes — the requeued jobs restart from their spec on surviving
     lanes, and a job's trajectory does not depend on which lane ran
     it."""
-    monkeypatch.setenv("CUP3D_SCAN_K", "4")
-    _, base = _fleet_drain(None, str(tmp_path / "base"))
+    _, base = _fleet_drain(None, os.path.join(tmp, "base"))
     assert all(st == "done" and n == 10 for st, n, _ in base.values())
 
     mesh = topo.make_mesh2d(lanes=2, x=2, devices=topo.device_order()[:4])
-    srv, shard = _fleet_drain(mesh, str(tmp_path / "shard"))
+    srv, shard = _fleet_drain(mesh, os.path.join(tmp, "shard"))
     for k in base:
         assert shard[k][:2] == base[k][:2]
         assert shard[k][2] == base[k][2], f"{k}: sharded QoI differs"
@@ -322,7 +303,7 @@ def test_fleet_sharded_drain_and_shard_loss(tmp_path, monkeypatch):
     # job completes with bytes matching the never-failed run
     losses0 = M.counter("fleet.shard_losses").value
     req0 = M.counter("fleet.elastic_requeues").value
-    srv2, lost = _fleet_drain(mesh, str(tmp_path / "loss"), arm_shard=1)
+    srv2, lost = _fleet_drain(mesh, os.path.join(tmp, "loss"), arm_shard=1)
     assert M.counter("fleet.shard_losses").value == losses0 + 1
     assert M.counter("fleet.elastic_requeues").value >= req0 + 1
     for k in base:
