@@ -240,5 +240,5 @@ def load_checkpoint(path: str, mesh=None):
         ob.sim = s
     driver._setup_operators()
     if s.obstacles:
-        driver.pipeline[0](0.0)  # CreateObstacles: rebuild chi/udef
+        driver.pipeline[0].rebuild()  # CreateObstacles: chi/udef
     return driver

@@ -150,6 +150,62 @@ def vel_unit_dev(v):
     return jnp.where(n > 1e-21, v / jnp.where(n > 0, n, 1.0), 0.0)
 
 
+# -- SDF -> chi/udef: the tail every body shares, traced inside one program --
+
+_EPS = 1e-6
+FRAME = 12  # position(3) + rotation matrix rows(9): Obstacle.host_frame
+
+
+def pos_rot_traced(frame):
+    """(position, rotation) inside a program, from what
+    ``Obstacle.frame_device`` hands it: the (FRAME,) upload of the host
+    mirrors, or the (RIGID_PACK,) device pack of the pipelined chain."""
+    # jax-lint: allow(JX003, a shape is static under trace: the two
+    # layouts are two programs)
+    if frame.shape[0] == RIGID_PACK:
+        return frame[6:9], quat_to_rot_dev(frame[15:19])
+    return frame[0:3], frame[3:FRAME].reshape(3, 3)
+
+
+_pos_rot = jax.jit(pos_rot_traced)
+
+
+def combine_obstacle_fields(chis, udefs):
+    """(n_obs, ...) stacks of per-body chi and masked udef -> the combined
+    fields the operators consume: chi the maximum over the bodies, udef
+    their chi-weighted mean (reference CreateObstacles,
+    main.cpp:13589-13621).  One expression for the uniform operator and
+    the forest driver (sim/amr.py)."""
+    chi = jnp.max(chis, axis=0)
+    den = jnp.maximum(jnp.sum(chis, axis=0), _EPS)[..., None]
+    udef = jnp.sum(chis[..., None] * udefs, axis=0) / den
+    return chi, udef
+
+
+def fields_from_sdf(grid: UniformGrid, sdf, udef, combine: bool):
+    """Dense SDF -> (chi, udef, combined), the tail every body shares:
+    Towers chi (reference Obstacle::create + chi kernel); the deformation
+    velocity kept only where it matters, inside the mollified band (a
+    rigid body, ``udef`` None, gets zeros); and, for a body alone on the
+    grid, the (chi, udef) pair of ``combine_obstacle_fields``, so that
+    one program goes all the way to what CreateObstacles writes."""
+    from cup3d_tpu.ops.chi import towers_chi
+
+    chi = towers_chi(grid.pad_scalar(sdf, 1), grid.h)
+    if udef is None:
+        udef = jnp.zeros(sdf.shape + (3,), sdf.dtype)
+    else:
+        udef = udef * (chi > 0)[..., None]
+    combined = (
+        combine_obstacle_fields(chi[None], udef[None]) if combine else None
+    )
+    return chi, udef, combined
+
+
+_fields_from_sdf = jax.jit(fields_from_sdf,
+                           static_argnames=("grid", "combine"))
+
+
 class Obstacle:
     """One immersed body.  Subclasses implement ``rasterize()`` (and
     optionally ``update_shape()`` for deforming bodies)."""
@@ -241,9 +297,22 @@ class Obstacle:
 
     # -- geometry ---------------------------------------------------------
 
+    @property
+    def _is_blocks(self) -> bool:
+        """Forest (nb, bs, bs, bs) layout, not the dense uniform one."""
+        return not hasattr(self.sim.grid, "shape")
+
     def rasterize(self, t: float):
         """Return (sdf, udef) dense fields; sdf > 0 inside, udef (.,3)."""
         raise NotImplementedError
+
+    def _cell_centers(self):
+        """Device cell centers for an analytic SDF: the uniform driver's
+        cached array; the forest's own (the one its driver caches may be
+        padded to a bucket's capacity)."""
+        if self._is_blocks:
+            return self.sim.grid.cell_centers(self.sim.dtype)
+        return self.sim.xc
 
     def max_body_speed(self, uinf=None) -> float:
         """Fresh host-side bound on this body's maximum material speed in
@@ -265,20 +334,19 @@ class Obstacle:
     def update_shape(self, t: float, dt: float) -> None:
         """Advance internal deformation kinematics (fish midline etc.)."""
 
-    def create(self, t: float) -> None:
-        """SDF -> chi + udef (reference Obstacle::create + chi kernel).
-        The SDF is kept: the surface-point force probe (ops/surface.py)
-        takes its outward normals from grad(phi) like the reference."""
-        from cup3d_tpu.ops.chi import towers_chi
-
+    def create(self, t: float, combine: bool = False):
+        """SDF -> chi + udef on the dense uniform grid, as ONE program
+        behind the subclass's rasterizer (``fields_from_sdf``).  The SDF
+        is kept: the surface-point force probe (ops/surface.py) takes its
+        outward normals from grad(phi) like the reference.  ``combine``
+        (this body is alone on the grid): the same program also returns
+        the combined (chi, udef) that CreateObstacles writes."""
         sdf, udef = self.rasterize(t)
         self.sdf = sdf
-        self.chi = towers_chi(
-            self.sim.grid.pad_scalar(sdf, 1), self.sim.grid.h
+        self.chi, self.udef, combined = _fields_from_sdf(
+            self.sim.grid, sdf, udef, combine
         )
-        self.udef = udef if udef is not None else jnp.zeros(
-            self.sim.grid.shape + (3,), self.sim.dtype
-        )
+        return combined
 
     # -- device fast path --------------------------------------------------
 
@@ -331,16 +399,29 @@ class Obstacle:
             self._block_src_cache = self.bBlockRotation
         return self._block_dev_cache
 
-    def pos_rot_device(self, dtype):
-        """(position, rotation-matrix) as device arrays for rasterization:
-        from the device rigid pack when pipelined chaining is active (the
-        host mirror trails one step there), else uploaded host mirrors."""
+    def host_frame(self) -> np.ndarray:
+        """(FRAME,) host mirrors a rasterizer needs: position, then the
+        rows of the rotation matrix."""
+        return np.concatenate(
+            [self.position, quat_to_rot(self.quaternion).ravel()]
+        )
+
+    def frame_device(self, dtype):
+        """The rasterizer's rigid frame as ONE device array, read inside
+        a program by ``pos_rot_traced``: the device rigid pack when
+        pipelined chaining is active (the host mirror trails one step
+        there), else one upload of the host mirrors."""
         d = self._dev_rigid
         if self.sim.cfg.pipelined and d is not None:
-            pack = d["pack"]
-            return pack[6:9], quat_to_rot_dev(pack[15:19])
-        return (jnp.asarray(self.position, dtype),
-                jnp.asarray(quat_to_rot(self.quaternion), dtype))
+            return d["pack"]
+        # cast on the host: jnp.asarray(float64, float32) is an upload AND
+        # a convert program
+        return jnp.asarray(self.host_frame().astype(dtype))
+
+    def pos_rot_device(self, dtype):
+        """(position, rotation-matrix) as device arrays, for a rasterizer
+        that takes them apart: one upload at most and one program."""
+        return _pos_rot(self.frame_device(dtype))
 
     def apply_rigid_pack(self, row: np.ndarray, clear_dev: bool = True) -> None:
         """(RIGID_PACK,) output of rigid_update_device -> host mirrors."""

@@ -16,13 +16,20 @@ The SDF/udef rasterization runs as one jitted window kernel
 from __future__ import annotations
 
 from collections import deque
+from functools import partial
 from typing import Dict
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from cup3d_tpu.models.base import Obstacle, quat_to_rot
+from cup3d_tpu.models.base import (
+    FRAME,
+    Obstacle,
+    fields_from_sdf,
+    pos_rot_traced,
+    quat_to_rot,
+)
 from cup3d_tpu.models.fish.curvature import CurvatureDefinedFishData
 from cup3d_tpu.models.fish.rasterize import rasterize_midline, rasterize_points
 from cup3d_tpu.models.fish.shapes import compute_widths_heights
@@ -45,31 +52,57 @@ def _raster_scatter_blocks(xc, scat, midline, position, rot):
     return sdf, udef
 
 
-from functools import partial as _partial
+def _split_midline(pack):
+    """(Nm, 20) device midline pack -> the rasterizer's dict."""
+    return {
+        "r": pack[:, 0:3], "v": pack[:, 3:6],
+        "nor": pack[:, 6:9], "vnor": pack[:, 9:12],
+        "bin": pack[:, 12:15], "vbin": pack[:, 15:18],
+        "width": pack[:, 18], "height": pack[:, 19],
+    }
 
 
-@_partial(jax.jit, static_argnames=("grid_shape", "window_shape"))
-def _raster_window_dense(pos, rot, midline, half, h, grid_shape,
-                         window_shape):
-    """Window snap + midline rasterization + dense placement as ONE jitted
-    dispatch (the eager tail cost ~10 dispatches per step)."""
-    dtype = half.dtype
+def _raster_window(pack, frame, grid, window_shape):
+    """Window snap + midline rasterization + dense placement, traced.
+    ``frame`` None: the pack's last row carries the host mirrors' frame
+    (``StefanFish._dense_inputs``).  The window half-width and ``h`` are
+    trace-time constants."""
+    dtype = pack.dtype
+    if frame is None:
+        pack, frame = pack[:-1], pack[-1, :FRAME]
+    pos, rot = pos_rot_traced(frame)
+    h = jnp.asarray(grid.h, dtype)
+    half = jnp.asarray(0.5 * np.asarray(window_shape) * grid.h, dtype)
     idx0 = jnp.clip(
         jnp.floor((pos - half) / h).astype(jnp.int32),
         0,
-        jnp.asarray(np.asarray(grid_shape) - np.asarray(window_shape),
+        jnp.asarray(np.asarray(grid.shape) - np.asarray(window_shape),
                     jnp.int32),
     )
     origin = idx0.astype(dtype) * h
     starts = (idx0[0], idx0[1], idx0[2])
     sdf_w, udef_w = rasterize_midline(
-        origin, h, window_shape, midline, pos, rot,
+        origin, h, window_shape, _split_midline(pack), pos, rot,
     )
-    sdf = jnp.full(grid_shape, -1.0, dtype)
+    sdf = jnp.full(grid.shape, -1.0, dtype)
     sdf = jax.lax.dynamic_update_slice(sdf, sdf_w, starts)
-    udef = jnp.zeros(tuple(grid_shape) + (3,), dtype)
+    udef = jnp.zeros(tuple(grid.shape) + (3,), dtype)
     udef = jax.lax.dynamic_update_slice(udef, udef_w, starts + (0,))
     return sdf, udef
+
+
+_raster_window_dense = jax.jit(_raster_window,
+                               static_argnames=("grid", "window_shape"))
+
+
+@partial(jax.jit, static_argnames=("grid", "window_shape", "combine"))
+def _create_dense(pack, frame, grid, window_shape, combine):
+    """CreateObstacles for one fish on the dense grid as ONE program: from
+    the step's single upload to (sdf, chi, udef, combined) — rasterizer,
+    ghost padding, Towers chi, band mask and, for a fish alone on the
+    grid, the combine (``models/base.fields_from_sdf``)."""
+    sdf, udef = _raster_window(pack, frame, grid, window_shape)
+    return (sdf,) + fields_from_sdf(grid, sdf, udef, combine)
 
 
 def _clip_quantities(fmax, dfmax, dt, fcandidate, dfcandidate, f, df):
@@ -120,7 +153,6 @@ class StefanFish(Obstacle):
         # dense uniform layout: a static rasterization window (the deformed
         # fish stays within ~0.6 L of its center; margin for the mollified
         # band).  Block layout: candidate blocks are found per call.
-        self._is_blocks = not hasattr(sim.grid, "shape")
         if not self._is_blocks:
             nw = int(np.ceil(1.25 * self.length / h)) + 8
             self._window_shape = tuple(min(nw, n) for n in sim.grid.shape)
@@ -196,23 +228,36 @@ class StefanFish(Obstacle):
                 gmax, dgdtmax, dt_eff, gg, dgdt, cf.gamma, cf.dgamma
             )
 
+    def _midline_pack(self) -> np.ndarray:
+        """(Nm, 20) host midline: frames, their velocities, profiles."""
+        cf = self.myFish
+        return np.concatenate(
+            [cf.r, cf.v, cf.nor, cf.vnor, cf.bin, cf.vbin,
+             cf.width[:, None], cf.height[:, None]], axis=1
+        )
+
     def _midline_device(self):
         """One packed (Nm, 20) host->device transfer per rasterization —
         eight separate uploads are eight blocking transfers —
         sliced back into the rasterizer's dict on device (free)."""
-        cf = self.myFish
-        dtype = self.sim.dtype
-        packed = np.concatenate(
-            [cf.r, cf.v, cf.nor, cf.vnor, cf.bin, cf.vbin,
-             cf.width[:, None], cf.height[:, None]], axis=1
-        )
-        dev = jnp.asarray(packed, dtype)
-        return {
-            "r": dev[:, 0:3], "v": dev[:, 3:6],
-            "nor": dev[:, 6:9], "vnor": dev[:, 9:12],
-            "bin": dev[:, 12:15], "vbin": dev[:, 15:18],
-            "width": dev[:, 18], "height": dev[:, 19],
-        }
+        return _split_midline(jnp.asarray(self._midline_pack(),
+                                          self.sim.dtype))
+
+    def _dense_inputs(self):
+        """(pack, frame) for the dense-layout programs: ONE upload per
+        call.  Host mirrors' frame: it rides as one more row of the
+        midline pack and ``frame`` is None.  Pipelined chaining: the frame
+        is the device rigid pack (host mirrors trail it one step)."""
+        pack = self._midline_pack()
+        d = self._dev_rigid
+        frame = d["pack"] if self.sim.cfg.pipelined and d is not None else None
+        if frame is None:
+            row = np.zeros((1, pack.shape[1]))
+            row[0, :FRAME] = self.host_frame()
+            pack = np.concatenate([pack, row])
+        # cast on the host: jnp.asarray(float64, float32) is an upload AND
+        # a convert program
+        return jnp.asarray(pack.astype(self.sim.dtype)), frame
 
     def _rasterize_blocks(self, t: float):
         """Block-layout rasterization: candidate blocks by AABB intersection
@@ -260,31 +305,17 @@ class StefanFish(Obstacle):
     def rasterize(self, t: float):
         if self._is_blocks:
             return self._rasterize_blocks(t)
-        cf = self.myFish
-        grid = self.sim.grid
-        h = grid.h
-        dtype = self.sim.dtype
-        half = 0.5 * np.asarray(self._window_shape) * h
-        # rigid state from the device pack in pipelined mode (host mirrors
-        # trail one step there), else uploaded mirrors; the window snap is
-        # traced either way so both branches share one code path
-        pos, rot = self.pos_rot_device(dtype)
         return _raster_window_dense(
-            pos, rot, self._midline_device(),
-            jnp.asarray(half, dtype), jnp.asarray(h, dtype),
-            tuple(grid.shape), tuple(self._window_shape),
+            *self._dense_inputs(), self.sim.grid, self._window_shape
         )
 
-    def create(self, t: float) -> None:
-        from cup3d_tpu.ops.chi import towers_chi
-
-        sdf, udef = self.rasterize(t)
-        self.sdf = sdf
-        self.chi = towers_chi(
-            self.sim.grid.pad_scalar(sdf, 1), self.sim.grid.h
+    def create(self, t: float, combine: bool = False):
+        """``Obstacle.create`` with the rasterizer inside the program."""
+        self.sdf, self.chi, self.udef, combined = _create_dense(
+            *self._dense_inputs(), self.sim.grid, self._window_shape,
+            combine,
         )
-        # deformation velocity only matters inside the mollified band
-        self.udef = udef * (self.chi > 0)[..., None]
+        return combined
 
     # -- rigid-body override: roll correction ------------------------------
 

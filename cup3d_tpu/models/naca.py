@@ -78,12 +78,10 @@ class Naca(Obstacle):
         # chord centered on the body origin, as the midline-frame fish
         self._xs = jnp.asarray(rs - 0.5 * self.length, dtype)
         self._ws = jnp.asarray(ws, dtype)
+        self._half_height_dev = jnp.asarray(self.half_height, dtype)
 
     def rasterize(self, t: float):
-        grid = self.sim.grid
-        dtype = self.sim.dtype
-        x = grid.cell_centers(dtype)
-        pos, rot = self.pos_rot_device(dtype)
-        sdf = _naca_sdf(x, pos, rot, self._xs, self._ws,
-                        jnp.asarray(self.half_height, dtype))
+        pos, rot = self.pos_rot_device(self.sim.dtype)
+        sdf = _naca_sdf(self._cell_centers(), pos, rot, self._xs, self._ws,
+                        self._half_height_dev)
         return sdf, None

@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from cup3d_tpu.models.base import (
+    combine_obstacle_fields,
     force_integrals,
     log_forces,
     momentum_integrals,
@@ -48,19 +49,46 @@ def _device_step(s) -> bool:
 
 class CreateObstacles(Operator):
     """Shape kinematics -> SDF -> chi/udef, then combine obstacle fields
-    (reference CreateObstacles, main.cpp:13589-13621)."""
+    (reference CreateObstacles, main.cpp:13589-13621).
+
+    The kinematics are host NumPy and take the step as ``sim.dt``, the
+    Python float the driver keeps (the ``dt`` every operator is handed is
+    a device scalar: arithmetic with it would move the midline onto the
+    device op by op).  Each body then costs one upload at most and one
+    program (``Obstacle.create``); a body alone on the grid gets the
+    combined fields out of that same program, several bodies one more
+    program over their stacked fields."""
+
+    def __init__(self, sim: SimulationData):
+        super().__init__(sim)
+        self._combine = jax.jit(
+            lambda chis, udefs: combine_obstacle_fields(
+                jnp.stack(chis), jnp.stack(udefs)
+            )
+        )
+        self._frame_velocity = jax.jit(lambda ts: -sum(ts) / len(ts))
 
     def __call__(self, dt):
+        self._create(self.sim.dt)
+
+    def rebuild(self):
+        """chi/udef of the bodies as they stand at ``sim.time`` with
+        nothing integrated: after a restore."""
+        self._create(0.0)
+
+    def _create(self, dt: float):
         s = self.sim
         self._update_uinf()
+        alone = len(s.obstacles) == 1
         for ob in s.obstacles:
             ob.update_shape(s.time, dt)
-            ob.create(s.time)
-        chis = jnp.stack([ob.chi for ob in s.obstacles])
-        s.state["chi"] = jnp.max(chis, axis=0)
-        num = sum(ob.chi[..., None] * ob.udef for ob in s.obstacles)
-        den = jnp.maximum(jnp.sum(chis, axis=0), _EPS)[..., None]
-        s.state["udef"] = num / den
+            combined = ob.create(s.time, combine=alone)
+        if not alone:
+            combined = self._combine(
+                tuple(ob.chi for ob in s.obstacles),
+                tuple(ob.udef for ob in s.obstacles),
+            )
+        s.state["chi"], s.state["udef"] = combined
 
     def _update_uinf(self):
         """Frame-fixed swimming: uinf counteracts the tracked obstacle's
@@ -74,7 +102,9 @@ class CreateObstacles(Operator):
         s.uinf = -np.mean([ob.transVel for ob in fixed], axis=0)
         devs = [ob._dev_rigid for ob in fixed]
         if s.cfg.pipelined and all(d is not None for d in devs):
-            s._uinf_dev = -sum(d["trans"] for d in devs) / len(devs)
+            s._uinf_dev = self._frame_velocity(
+                tuple(d["trans"] for d in devs)
+            )
 
 
 class UpdateObstacles(Operator):
@@ -143,7 +173,7 @@ class UpdateObstacles(Operator):
         M = np.asarray(M)
         for ob, row in zip(s.obstacles, M):
             ob.compute_velocities(unpack_moments(row))
-            ob.update(dt)
+            ob.update(s.dt)  # host kinematics: the float (CreateObstacles)
 
 
 class Penalization(Operator):
@@ -174,7 +204,7 @@ class Penalization(Operator):
             from cup3d_tpu.models.collisions import prevent_colliding_obstacles
 
             if prevent_colliding_obstacles(
-                s.obstacles, ubs, self._gradchi, self._xc, float(dt)
+                s.obstacles, ubs, self._gradchi, self._xc, s.dt
             ):
                 # collision overrode rigid velocities: rebuild the fields
                 ubs = [ob.body_velocity_field() for ob in s.obstacles]

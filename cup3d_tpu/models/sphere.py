@@ -8,10 +8,18 @@ analytic SDF, and flow past a sphere is the classic drag validation.
 
 from __future__ import annotations
 
-import jax.numpy as jnp
-import numpy as np
+from functools import partial
 
-from cup3d_tpu.models.base import Obstacle
+import jax
+import jax.numpy as jnp
+
+from cup3d_tpu.models.base import Obstacle, pos_rot_traced
+
+
+@partial(jax.jit, static_argnames=("radius",))
+def _sphere_sdf(x, frame, radius):
+    pos, _ = pos_rot_traced(frame)
+    return radius - jnp.linalg.norm(x - pos, axis=-1)  # > 0 inside
 
 
 class Sphere(Obstacle):
@@ -26,9 +34,7 @@ class Sphere(Obstacle):
         self.length = max(self.length, 2.0 * self.radius)
 
     def rasterize(self, t: float):
-        grid = self.sim.grid
-        x = grid.cell_centers(self.sim.dtype)
-        pos, _ = self.pos_rot_device(self.sim.dtype)
-        d = jnp.linalg.norm(x - pos, axis=-1)
-        sdf = self.radius - d  # > 0 inside
-        return sdf, None
+        return _sphere_sdf(
+            self._cell_centers(), self.frame_device(self.sim.dtype),
+            self.radius,
+        ), None

@@ -48,6 +48,7 @@ from cup3d_tpu.io.logging import BufferedLogger, Profiler
 from cup3d_tpu.models.base import (
     FORCE_PACK,
     RIGID_PACK,
+    combine_obstacle_fields,
     log_forces,
     momentum_integrals_core,
     pack_forces,
@@ -144,10 +145,7 @@ def _combine_obstacle_fields(sdfs, udefs, h_raw, combine=True, tab=None,
     udefs = udefs * (chis > 0)[..., None]
     if not combine:
         return chis, udefs, None, None
-    chi = jnp.max(chis, axis=0)
-    den = jnp.maximum(jnp.sum(chis, axis=0), _EPS)[..., None]
-    udef = jnp.sum(chis[..., None] * udefs, axis=0) / den
-    return chis, udefs, chi, udef
+    return (chis, udefs) + combine_obstacle_fields(chis, udefs)
 
 
 class AMRSimulation:
@@ -1097,9 +1095,7 @@ class AMRSimulation:
                  fixmask, slots, b0s, uinf, dt, lam, tab1, tab3, ftab,
                  xc, vol, profile, second_order):
             n_obs = chis.shape[0]
-            chi = jnp.max(chis, axis=0)
-            den = jnp.maximum(jnp.sum(chis, axis=0), _EPS)[..., None]
-            udef = jnp.sum(chis[..., None] * udefs, axis=0) / den
+            chi, udef = combine_obstacle_fields(chis, udefs)
 
             vel = advdiff_stage(vel, uinf, dt, tab1, tab3, ftab)
 
@@ -1353,9 +1349,7 @@ class AMRSimulation:
                 sol = partial(solver_core, geom=g_, vol=vol, pmask=mask,
                               graph=graph, slot0=slot0)
                 n_obs = chis.shape[0]
-                chi = jnp.max(chis, axis=0)
-                den = jnp.maximum(jnp.sum(chis, axis=0), _EPS)[..., None]
-                udef = jnp.sum(chis[..., None] * udefs, axis=0) / den
+                chi, udef = combine_obstacle_fields(chis, udefs)
 
                 vel = advdiff_stage(g_, vel, uinf, dt, tab1, tab3, ftab)
 

@@ -434,11 +434,18 @@ class Simulation:
         # trace record (CUP3D_TRACE=1) and the postmortem ring (always)
         with self._obs.step(s.step, s.time, dt, umax=self._last_umax):
             self._maybe_dump_save()
-            # ONE sanctioned host->device upload per step: every operator
-            # receives dt as the same device scalar, so the steady-state
-            # loop is provably transfer-clean under
-            # jax.transfer_guard("disallow") (analysis/runtime.py; the
-            # sanitizer contract in VALIDATION.md)
+            # the step's dt lives twice.  ``s.dt``: the Python float the
+            # obstacles' host kinematics take (update_shape, update, the
+            # collision latch).  And ONE sanctioned upload: every
+            # operator receives dt as the same device scalar.  What the
+            # tests prove under jax.transfer_guard: the obstacle-free
+            # loop makes no other transfer (tests/test_analysis.py, the
+            # sanitizer contract in VALIDATION.md); with bodies,
+            # update_shape touches no device and CreateObstacles makes
+            # one upload per body (tests/test_create_obstacles_dispatch
+            # .py) — the other obstacle operators still stage their
+            # rigid mirrors per step
+            s.dt = float(dt)
             dt_dev = device_scalar(dt, s.dtype, tag="dt-upload")
             for op in self.pipeline:
                 with s.profiler(op.name):
@@ -729,7 +736,7 @@ class Simulation:
         # mirrors queued from the abandoned trajectory must never apply
         self._pack_reader.abandon()
         if s.obstacles:
-            self.pipeline[0](0.0)  # CreateObstacles: rebuild chi/udef
+            self.pipeline[0].rebuild()  # CreateObstacles: chi/udef
 
     def _resilience_zero_pressure(self) -> None:
         """Escalation stage 'zero-guess': the warm start restarts from

@@ -52,6 +52,20 @@ def test_two_fish_swim(fish_sim):
         assert np.linalg.norm(ob.transVel) > 0.0
 
 
+def test_fish_kinematics_stay_host_numpy(fish_sim):
+    """The forest hands update_shape/update the step as a Python float,
+    like the uniform driver (tests/test_create_obstacles_dispatch.py):
+    nothing of the host kinematics has moved onto the device."""
+    for ob in fish_sim.obstacles:
+        held = [getattr(ob, k) for k in ("position", "quaternion",
+                                         "transVel", "angVel")]
+        held += [getattr(ob.myFish, k) for k in (
+            "r", "v", "nor", "vnor", "bin", "vbin", "quaternion_internal",
+            "angvel_internal")]
+        for a in held:
+            assert type(a) is np.ndarray and a.dtype == np.float64
+
+
 def test_interface_blocks_at_finest_level(fish_sim):
     sim = fish_sim
     # state rides bucket-padded (sim/amr.py module doc); unpad to the
