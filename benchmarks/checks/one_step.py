@@ -7,12 +7,12 @@ refreshes it from the body before it advects)."""
 from benchmarks.lib import drive
 
 
-def links(driver, traffic, config, spans, seed):
-    pre = drive.capture(driver, config)
+def links(driver, grid, traffic, config, spans, seed):
+    pre = drive.capture(driver, grid, config)
     spans.last_dt = None
     drive.run_steps(driver, 1)
     drive.sync(driver)
-    post = drive.capture(driver, config)
+    post = drive.capture(driver, grid, config)
     if spans.last_dt is None:
         raise SystemExit("benchmark: the checked step did not go through "
                          "advance(dt)")

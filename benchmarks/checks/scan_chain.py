@@ -46,7 +46,7 @@ def _gap(timed, chain, start):
     return max(gaps)
 
 
-def links(driver, traffic, config, spans, seed):
+def links(driver, grid, traffic, config, spans, seed):
     import jax
     import jax.numpy as jnp
 
@@ -69,7 +69,7 @@ def links(driver, traffic, config, spans, seed):
     timed = _host(drive.need(driver, "_scan_carry"))
     # state and carry may differ if something rewrote the state after
     # the dispatch: what the driver would hand on is what is judged
-    timed.update({k: np.asarray(driver.sim.state[k])
+    timed.update({k: grid.host(driver, drive.need(driver.sim.state, k))
                   for k in ("vel", "p", "chi", "udef")})
 
     k_steps = int(kept["cfl"].shape[0])
@@ -87,18 +87,19 @@ def links(driver, traffic, config, spans, seed):
     jax.block_until_ready(carry)
     del carry, kept
 
-    grid, (shape,) = drive.grid_of(driver), drive.body_shapes(config)
+    geom = grid.geometry(driver, config)
+    (shape,) = drive.body_shapes(config)
     out = []
     for k in sorted(chosen):
-        pre = _capture(states[k], grid, shape)
-        post = _capture(states[k + 1], grid, shape)
+        pre = _capture(states[k], geom, shape)
+        post = _capture(states[k + 1], geom, shape)
         # a body that fixes the frame: the step advects with minus the
         # body's velocity before its update
         post["uinf"] = (-pre["bodies"][0]["trans"] if shape["fixes_frame"]
                         else np.array(driver.sim.uinf, np.float64))
         out.append((pre, post))
     # the last link ends on the timed dispatch's own product
-    last = _capture(timed, grid, shape)
+    last = _capture(timed, geom, shape)
     last["uinf"] = out[-1][1]["uinf"]
     out[-1] = (out[-1][0], last)
     return out, {"scan_chain_gap": _gap(timed, states[k_steps], states[0])}

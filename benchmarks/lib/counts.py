@@ -41,6 +41,29 @@ def bicgstab_iteration(cells: int) -> dict:
             "flops": FLOPS_PER_CELL * cells, "vectors": vectors}
 
 
+def forest_bicgstab_iteration(cells: int, bs: int) -> dict:
+    """The same iteration on the leaves of an octree of ``bs^3`` blocks
+    (``krylov.bicgstab`` with ``laplacian_blocks`` as the operator): the
+    vectors above (``s.x``, ``s.r``, ``rhat``, ``p``, ``v``, ``svec``,
+    ``t``, ``y``, ``z``), and beside them what a block cannot have from
+    its own cells: the halo of the operator's input.  ``v = A y`` and ``t
+    = A z`` each read, per block, the face planes of ``y`` or ``z`` that
+    its neighbours hold: the 7-point operator reaches one cell across
+    each of the six faces and no edge or corner, so ``6 bs^2`` values per
+    ``bs^3`` block, ``6 / bs`` of a vector per apply.  (The lab the
+    program assembles, ``(bs + 2)^3`` per block, holds edges and corners
+    too: 95 % of a vector more where ``bs = 8``.  The strict count leaves
+    out what no 7-point stencil reads.)  Left out as well, so that the
+    count stays a lower bound: the restriction pyramid under finer
+    neighbours, the interpolation under coarser ones, the flux tables
+    (``grid/faces.py``, ``grid/flux.py``: each a few per cent of the
+    cells) and the per-block spacing."""
+    halo_vectors = 2 * 6.0 / bs
+    vectors = sum(r + w for r, w in PHASES) + halo_vectors
+    return {"bytes": vectors * cells * BYTES_PER_VALUE,
+            "flops": FLOPS_PER_CELL * cells, "vectors": vectors}
+
+
 def roofline_seconds(work: dict, chip: dict) -> dict:
     """Least time the chip could take and which bound it is."""
     t_mem = work["bytes"] / chip["hbm_bytes_per_s"]

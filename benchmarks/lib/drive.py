@@ -1,7 +1,9 @@
 """Drives the system under test: builds the driver the CLI would build,
 puts the harness's spans around the calls into it, warms it up, runs the
 measured window through ``simulate()`` and captures states for the
-comparison.
+comparison.  What depends on the kind of grid is the adapter's
+(``benchmarks/grids/<driver.kind>.py``, which ``run.py`` loads once and
+hands down as ``grid``).
 
 Everything the harness takes from the program is named here, and a name
 that is gone raises (``need``): nothing falls back in silence.
@@ -10,21 +12,34 @@ that is gone raises (``need``): nothing falls back in silence.
   ``init()``, ``simulate()`` and the methods a traffic file lists under
   ``spans`` (``calc_max_timestep``, ``advance``, ``advance_megaloop``,
   ``flush_packs``);
-- ``driver.sim``: ``state`` (vel, p, chi, udef), ``grid`` (``shape``,
-  ``h``, ``cell_centers``), ``obstacles`` (``centerOfMass``,
-  ``transVel``, ``angVel``, ``chi``, ``udef``), ``time``, ``dt``, ``step``,
-  ``uinf``, ``profiler.totals``;
+- ``driver.sim``: ``state`` (vel, p, chi, udef), ``grid``, ``obstacles``
+  (``centerOfMass``, ``transVel``, ``angVel``, ``chi``, ``udef``),
+  ``time``, ``dt``, ``step``, ``uinf``, ``profiler.totals``;
+- of a uniform grid (``grids/uniform.py``): ``shape``, ``h``,
+  ``cell_centers``;
+- of a forest (``grids/forest.py``): ``grid.keys`` (one ``(level, i, j,
+  k)`` per leaf, in the order of the fields' rows), ``grid.nb``,
+  ``grid.bs``; the fields' rows past ``nb`` are the bucket's padding and
+  are dropped.  For the solve probe: ``sim._solver`` (called as
+  ``solver(rhs, x0, tab_arg=, flux_arg=, with_stats=True)``),
+  ``sim._geom``, ``sim._tab1``, ``sim._ftab`` (the geometry and the
+  width-1 halo and flux tables the driver's projection is bound to), and
+  the program's own forest operators
+  ``cup3d_tpu.ops.amr_ops.pressure_rhs_blocks`` and ``grad_blocks``;
 - of a driver on the scan megaloop, ``_megaloop`` (the jitted scan and its
   row width) and ``_scan_carry`` (vel, p, chi, udef, rigid, dt, time; rigid
   laid out as ``models.base.RIGID_STATE``): ``checks/scan_chain.py``;
 - the solver a configuration names under ``driver.solver``, called as
   ``solver(rhs, x0, with_stats=True)``: ``probe.py``;
-- ``cup3d_tpu.obs.metrics`` (``snapshot``, ``delta``) and
-  ``cup3d_tpu.native.available``: ``run.py``."""
+- ``cup3d_tpu.obs.metrics`` (``snapshot``, ``delta``; the counters
+  ``amr.regrids``, ``amr.regrid_noops``, ``poisson.iters_hist``,
+  ``stream.stall_s``, ``resilience.rollbacks``) and
+  ``cup3d_tpu.native.available``: ``run.py`` and the readers."""
 
 from __future__ import annotations
 
 import contextlib
+import functools
 import time
 
 import numpy as np
@@ -50,19 +65,16 @@ class Spans:
         self.last_dt = None
 
 
-def cells(grid) -> int:
-    return int(np.prod(need(grid, "shape")))
-
-
-def wrap_spans(driver, names, spans: Spans):
+def wrap_spans(driver, names, spans: Spans, cells):
     """Replace each named method of ``driver`` by a timed twin.  In a
-    traced run the span is also written into the profiler's trace."""
+    traced run the span is also written into the profiler's trace.
+    ``cells`` counts the cells of the driver's grid (the adapter's)."""
     import jax
 
     def make(name, fn):
         def timed(*args, **kwargs):
             d = driver.sim
-            step0, n_cells = d.step, cells(d.grid)
+            step0 = d.step
             if name == "advance" and args:
                 spans.last_dt = float(args[0])
             t0 = time.perf_counter()
@@ -73,6 +85,9 @@ def wrap_spans(driver, names, spans: Spans):
             finally:
                 t1 = time.perf_counter()
                 steps = d.step - step0
+                # an adaptation pass runs inside the call: the step was
+                # taken on the grid the call leaves
+                n_cells = cells(need(d, "grid"))
                 spans.rows.append((name, t0, t1, steps, n_cells * steps))
         return timed
 
@@ -124,25 +139,20 @@ def body_shapes(config: dict):
     return [need(b, "shape") for b in config["bodies"]]
 
 
-def grid_of(driver) -> dict:
-    grid = need(driver.sim, "grid")
-    return {"x": np.asarray(need(grid, "cell_centers")(np.float64)),
-            "h": float(need(grid, "h"))}
-
-
-def fluid_state(driver) -> dict:
+def fluid_state(driver, grid, config: dict) -> dict:
     """Host copy of the velocity and chi alone (what the divergence
     guarantee reads on the state the window opens on)."""
     state = need(driver.sim, "state")
-    return {"h": float(need(driver.sim.grid, "h")),
-            "vel": np.asarray(need(state, "vel")),
-            "chi": np.asarray(need(state, "chi"))}
+    return {**grid.geometry(driver, config),
+            "vel": grid.host(driver, need(state, "vel")),
+            "chi": grid.host(driver, need(state, "chi"))}
 
 
-def capture(driver, config: dict) -> dict:
+def capture(driver, grid, config: dict) -> dict:
     """Host copy of what the comparison needs, from the driver's own
     state and the host mirrors of its bodies (current on a driver that
     reads its packs every step)."""
+    host = functools.partial(grid.host, driver)
     d = driver.sim
     state = need(d, "state")
     obstacles = need(d, "obstacles")
@@ -152,14 +162,13 @@ def capture(driver, config: dict) -> dict:
         chi, udef = ((state["chi"], state["udef"]) if len(obstacles) == 1
                      else (need(ob, "chi"), need(ob, "udef")))
         bodies.append({
-            **shape, "chi": np.asarray(chi), "udef": np.asarray(udef),
+            **shape, "chi": host(chi), "udef": host(udef),
             **{k: np.array(need(ob, a), np.float64) for k, a in
                (("cm", "centerOfMass"), ("trans", "transVel"),
                 ("ang", "angVel"))}})
     return {
-        **grid_of(driver),
-        **{k: np.asarray(need(state, k)) for k in
-           ("vel", "p", "chi", "udef")},
+        **grid.geometry(driver, config),
+        **{k: host(need(state, k)) for k in ("vel", "p", "chi", "udef")},
         "time": float(d.time), "dt": float(d.dt), "step": int(d.step),
         "uinf": np.array(need(d, "uinf"), np.float64), "bodies": bodies,
     }

@@ -32,7 +32,7 @@ def build_argv(config: dict, traffic: dict, seed: int, workdir: str):
         argv += ["-" + key, str(value)]
     # the step budget is stated at build (a run without one cannot take
     # the scan megaloop); the harness raises it as the run goes on
-    argv += ["-nsteps", str(traffic["warmup_steps"]),
-             "-factory-content", "\n".join(body_lines(config, seed)),
-             "-path4serialization", workdir]
-    return argv
+    argv += ["-nsteps", str(traffic["warmup_steps"])]
+    if config["bodies"]:
+        argv += ["-factory-content", "\n".join(body_lines(config, seed))]
+    return argv + ["-path4serialization", workdir]

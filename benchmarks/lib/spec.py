@@ -1,7 +1,7 @@
 """Finds the benchmark's data by name: cells, configurations, traffic
-files and per-layer readers all come from ``BENCHMARK.json`` and from
-files named after its entries.  No cell, configuration or metric name
-appears in harness code."""
+files, per-layer readers, checks and grid adapters all come from
+``BENCHMARK.json`` and from files named after its entries.  No cell,
+configuration, metric or kind of grid is named in harness code."""
 
 from __future__ import annotations
 
@@ -34,9 +34,19 @@ def load_cell(bench: dict, name: str, root: str = ROOT):
     cell = by_name(bench["workloads"], name, "workload")
     cfg_entry = by_name(bench["configs"], cell["config"], "config")
     config = load_json(os.path.join(root, cfg_entry["file"]))
-    bench_dir = os.path.join(root, bench["paths"][0])
-    traffic = load_json(os.path.join(bench_dir, "workloads", name + ".json"))
+    traffic = load_json(_find(bench, "workloads", name + ".json", root))
     return cell, config, traffic
+
+
+def _find(bench: dict, folder: str, filename: str, root: str) -> str:
+    """``<path>/<folder>/<filename>`` under the first of the benchmark's
+    ``paths`` that holds it."""
+    tried = [os.path.join(root, p, folder, filename) for p in bench["paths"]]
+    for path in tried:
+        if os.path.exists(path):
+            return path
+    raise SystemExit(f"benchmark: no file {tried[0]!r} (looked under "
+                     f"every entry of paths)")
 
 
 def metrics_of(bench: dict, cell_name: str, kind: str):
@@ -52,7 +62,7 @@ def metrics_of(bench: dict, cell_name: str, kind: str):
 
 
 def _load(bench: dict, folder: str, name: str, root: str):
-    path = os.path.join(root, bench["paths"][0], folder, name + ".py")
+    path = _find(bench, folder, name + ".py", root)
     spec = importlib.util.spec_from_file_location(
         f"bench_{folder}_" + name.replace(".", "_").replace("-", "_"), path)
     mod = importlib.util.module_from_spec(spec)
@@ -61,11 +71,17 @@ def _load(bench: dict, folder: str, name: str, root: str):
 
 
 def load_reader(bench: dict, name: str, root: str = ROOT):
-    """The module ``<paths[0]>/metrics/<name>.py``: ``META`` and ``read``."""
+    """The module ``<path>/metrics/<name>.py``: ``META`` and ``read``."""
     return _load(bench, "metrics", name, root)
 
 
 def load_check(bench: dict, kind: str, root: str = ROOT):
-    """The module ``<paths[0]>/checks/<kind>.py``: ``links``, which takes
+    """The module ``<path>/checks/<kind>.py``: ``links``, which takes
     one more unit of the timed entry and hands back what is compared."""
     return _load(bench, "checks", kind, root)
+
+
+def load_grid(bench: dict, kind: str, root: str = ROOT):
+    """The module ``<path>/grids/<kind>.py``: what depends on the
+    kind of grid a configuration's driver runs on (``driver.kind``)."""
+    return _load(bench, "grids", kind, root)

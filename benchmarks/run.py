@@ -104,7 +104,8 @@ def device_or_exit(chips: int, rehearse: bool):
     return devices
 
 
-def traced_window(driver, traffic, solver_spec, spans, workdir, rehearse):
+def traced_window(driver, grid, traffic, solver_spec, spans, workdir,
+                  rehearse):
     """``--trace 1``: some further chunks and the solve probe under the
     profiler, reduced to busy time, top operations and named gaps."""
     import jax
@@ -125,7 +126,7 @@ def traced_window(driver, traffic, solver_spec, spans, workdir, rehearse):
     probe_out = None
     if "solver" in solver_spec:
         with jax.profiler.TraceAnnotation("bench:solve_probe"):
-            probe_out = probe.run(driver, solver_spec, p_before)
+            probe_out = probe.run(driver, grid, solver_spec, p_before)
     jax.profiler.stop_trace()
     spans.annotate = False
     trace = trace_reduce.reduce_dir(tdir, window="bench:traced_window",
@@ -158,6 +159,7 @@ def main(argv=None) -> int:
     from cup3d_tpu.obs import metrics as obs
 
     chip = None if args.rehearse else peaks.peaks_for_kind(device.device_kind)
+    grid = spec.load_grid(bench, config["driver"]["kind"])
     cache_dir = enable_cache()
     cached_before = cache_entries(cache_dir)
     compiles = CompileLog()
@@ -167,14 +169,15 @@ def main(argv=None) -> int:
         argv_run = seeding.build_argv(config, traffic, args.seed, workdir)
         driver = build_driver(argv_run)
         spans = drive.Spans()
-        drive.wrap_spans(driver, traffic["spans"], spans)
+        drive.wrap_spans(driver, traffic["spans"], spans, grid.cells)
         driver.init()
         drive.run_steps(driver, traffic["warmup_steps"])
         drive.sync(driver)
         log(native_tables_loaded=bool(native.available()),
             cache_dir=cache_dir, cache_entries_before=len(cached_before),
-            warmup_steps=int(driver.sim.step), argv=argv_run[:-6])
-        at_open = drive.fluid_state(driver)
+            warmup_steps=int(driver.sim.step),
+            argv=argv_run[:argv_run.index("-nsteps")])
+        at_open = drive.fluid_state(driver, grid, config)
         setup_s = time.perf_counter() - T_START
 
         # -- the measured window ------------------------------------------
@@ -198,18 +201,20 @@ def main(argv=None) -> int:
                 "peak_bytes_in_use"),
             "chip": chip,
         }
-        ctx["trace"] = (traced_window(driver, traffic, config["driver"],
-                                      spans, workdir, args.rehearse)
+        ctx["trace"] = (traced_window(driver, grid, traffic,
+                                      config["driver"], spans, workdir,
+                                      args.rehearse)
                         if args.trace else None)
 
         # -- correctness: one more unit of the timed entry, the reference -
         links, extra = spec.load_check(bench, traffic["check"]["kind"]).links(
-            driver, traffic, config, spans, args.seed)
-        ctx["cells"] = drive.cells(driver.sim.grid)
+            driver, grid, traffic, config, spans, args.seed)
+        ctx["cells"] = grid.cells(driver.sim.grid)
+        ctx["iteration_work"] = grid.iteration_work(driver.sim.grid)
         del driver  # the program's state goes before the reference runs
         gc.collect()
         passed, compared, guar = compare.judge(
-            links, extra, at_open, config, traffic["limits"])
+            grid, links, extra, at_open, config, traffic["limits"])
     ctx["cache_new_entries"] = len(cache_entries(cache_dir) - cached_before)
     failed_steps = int(ctx["obs"].get("resilience.rollbacks", 0)) \
         + (1 if win["error"] else 0)
@@ -242,6 +247,7 @@ def main(argv=None) -> int:
     result["seed"] = args.seed
     result["window"] = {k: win[k] for k in ("steps", "wall_s", "cells",
                                             "error")}
+    result["grid"] = grid.counters(ctx["obs"])
     result["check"] = {"passed": passed, "compared": compared,
                        "guarantees": guar, "links": len(links),
                        "unit_steps": int(traffic["check_unit_steps"])}

@@ -1,8 +1,33 @@
 """Faults planted under the timed path, for ``test_faults.py``: the
 driver ``build_driver`` returns gets a step that is broken from a given
-step on, in one of the ways a later change could break it."""
+step on, in one of the ways a later change could break it.  And faults of
+one kind of grid, planted in the reference that is put in the program's
+place (``GRID_FAULTS``, ``faulty``): ``test_control.py``,
+``seed_sweep.py``."""
 
 import contextlib
+
+from benchmarks.lib import reference_forest as rf
+
+#: by kind of grid, the faults that only a reference of that kind can
+#: see.  A forest's lie at its coarse-fine faces; each is a composite grid
+#: built with the fault (and a solve loose enough to end on it)
+GRID_FAULTS = {
+    "forest": {
+        # cells under a coarser leaf copied from it, not interpolated
+        "ghost_inject": {"prolong": rf.prolong_inject,
+                         "solve": (1e-6, 100, False)},
+        # the coarse side of coarse-fine faces left uncorrected
+        "no_reflux": {"reflux": False, "solve": (1e-6, 100, False)},
+    },
+}
+
+
+def faulty(grid, kind: str, geom: dict, fault: str):
+    """The adapter's reference on ``geom`` with ``fault`` planted."""
+    return grid.Reference(geom, rf.Forest(
+        geom["leaves"], geom["blocks0"], geom["bs"], geom["h0"],
+        **GRID_FAULTS[kind][fault]))
 
 
 def break_driver(driver, fault: str, from_step: int):
