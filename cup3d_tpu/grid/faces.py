@@ -52,6 +52,8 @@ import numpy as np
 
 from cup3d_tpu.grid.uniform import BC
 
+_HI = jax.lax.Precision.HIGHEST
+
 __all__ = ["FaceTables", "build_face_tables", "pad_face_tables"]
 
 
@@ -575,9 +577,14 @@ def _coarse_halo(t: FaceTables, ext: jnp.ndarray, f: int) -> jnp.ndarray:
     Tt = t.interp_t  # (bs, S)
     # each tensordot appends its output axis:
     # (n,C,d,S,S) -> (n,C,S,S,w) -> (n,C,S,w,bs) -> (n,C,w,bs,bs)
-    out = jnp.tensordot(win, Tn.astype(win.dtype), axes=[[2], [1]])
-    out = jnp.tensordot(out, Tt.astype(win.dtype), axes=[[2], [1]])
-    out = jnp.tensordot(out, Tt.astype(win.dtype), axes=[[2], [1]])
+    # float32 operands as they are: the default precision rounds them to
+    # bfloat16 on the TPU, and a ghost cell is then good to 3 digits
+    out = jnp.tensordot(win, Tn.astype(win.dtype), axes=[[2], [1]],
+                        precision=_HI)
+    out = jnp.tensordot(out, Tt.astype(win.dtype), axes=[[2], [1]],
+                        precision=_HI)
+    out = jnp.tensordot(out, Tt.astype(win.dtype), axes=[[2], [1]],
+                        precision=_HI)
     return out  # (ncf, C, w, bs, bs)
 
 

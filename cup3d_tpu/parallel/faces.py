@@ -41,7 +41,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from cup3d_tpu.grid.faces import FaceTables, _place, _restrict8, _slab
+from cup3d_tpu.grid.faces import _HI, FaceTables, _place, _restrict8, _slab
 from cup3d_tpu.parallel.compat import shard_map
 
 __all__ = ["ShardedFaceTables", "build_sharded_face_tables"]
@@ -328,9 +328,13 @@ class ShardedFaceTables:
         win = jax.vmap(tslice)(slab16, toff)
         Tn = t.interp_n_hi if hi else t.interp_n_lo
         Tt = t.interp_t
-        out = jnp.tensordot(win, Tn.astype(win.dtype), axes=[[2], [1]])
-        out = jnp.tensordot(out, Tt.astype(win.dtype), axes=[[2], [1]])
-        out = jnp.tensordot(out, Tt.astype(win.dtype), axes=[[2], [1]])
+        # float32 operands as they are, as grid/faces.py::_coarse_halo
+        out = jnp.tensordot(win, Tn.astype(win.dtype), axes=[[2], [1]],
+                            precision=_HI)
+        out = jnp.tensordot(out, Tt.astype(win.dtype), axes=[[2], [1]],
+                            precision=_HI)
+        out = jnp.tensordot(out, Tt.astype(win.dtype), axes=[[2], [1]],
+                            precision=_HI)
         return out
 
 

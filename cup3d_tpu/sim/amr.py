@@ -1121,6 +1121,7 @@ class AMRSimulation:
                 )
                 + udefs
             )  # (n_obs, nb, bs,bs,bs, 3)
+            den = jnp.maximum(jnp.sum(chis, axis=0), _EPS)[..., None]
             ubody = jnp.sum(chis[..., None] * ub, axis=0) / den
 
             vel_old = vel
@@ -1377,6 +1378,7 @@ class AMRSimulation:
                     )
                     + udefs
                 )
+                den = jnp.maximum(jnp.sum(chis, axis=0), _EPS)[..., None]
                 ubody = jnp.sum(chis[..., None] * ub, axis=0) / den
 
                 vel_old = vel
@@ -1977,7 +1979,6 @@ class AMRSimulation:
             self._uinf_dev = None
         s = self.state
         dt_j = device_scalar(dt, self.dtype, tag="dt-upload")
-        uinf = self.uinf_device()
 
         self._maybe_dump_save()
         if self.adapt_enabled and (
@@ -1988,6 +1989,9 @@ class AMRSimulation:
 
         with self.profiler("CreateObstacles"):
             self.create_obstacles(dt)
+        # after create_obstacles: it refreshes the frame velocity from the
+        # bodies that fix the frame, and this step advects with that one
+        uinf = self.uinf_device()
         with self.profiler("AdvectionDiffusion"):
             s["vel"] = self._advdiff(s["vel"], dt_j, uinf)
         if self.obstacles:

@@ -6,6 +6,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from cup3d_tpu.analysis.ir import iter_eqns
 from cup3d_tpu.grid.blocks import BlockGrid
 from cup3d_tpu.grid.octree import Octree, TreeConfig
 from cup3d_tpu.grid.uniform import BC, UniformGrid
@@ -83,3 +84,17 @@ def laplacian(geom, field, tab, ftab):
     return bind_step_executable(
         lambda a, *tabs: amr_ops.laplacian_blocks(geom, a, *tabs),
         tab, ftab)(field)
+
+
+def assert_dots_highest(jaxpr, at_least):
+    """Every dot_general of the program asks for Precision.HIGHEST: on
+    the TPU the default rounds float32 operands to bfloat16, which no
+    CPU run can see."""
+    dots = [eqn for eqn, _, _ in iter_eqns(jaxpr)
+            if eqn.primitive.name == "dot_general"]
+    assert len(dots) >= at_least
+    for eqn in dots:
+        p = eqn.params["precision"]
+        assert p is not None, eqn
+        for side in p if isinstance(p, tuple) else (p,):
+            assert side == jax.lax.Precision.HIGHEST, eqn

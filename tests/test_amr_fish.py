@@ -16,6 +16,36 @@ from cup3d_tpu.sim.amr import AMRSimulation
 pytestmark = pytest.mark.filterwarnings("ignore::DeprecationWarning")
 
 
+class _FrameSpy(AMRSimulation):
+    """The driver itself, keeping for every step the frame velocity that
+    AdvectionDiffusion was handed (``frame_seen``) beside the one upstream
+    prescribes: minus the mean translational velocity, before the step,
+    of the bodies that fix the frame (``frame_due``).  ``_advdiff`` is
+    rebound at every regrid, so the spy sits on the attribute."""
+
+    def __init__(self, cfg):
+        self.frame_seen, self.frame_due = [], []
+        super().__init__(cfg)
+
+    def advance(self, dt):
+        fixed = [ob for ob in self.obstacles if ob.bFixFrameOfRef]
+        self.frame_due.append(
+            -np.mean([np.array(ob.transVel) for ob in fixed], axis=0))
+        return super().advance(dt)
+
+    @property
+    def _advdiff(self):
+        def spy(vel, dt, uinf):
+            self.frame_seen.append(np.asarray(uinf, np.float64))
+            return self._advdiff_bound(vel, dt, uinf)
+
+        return spy
+
+    @_advdiff.setter
+    def _advdiff(self, fn):
+        self._advdiff_bound = fn
+
+
 @pytest.fixture(scope="module")
 def fish_sim():
     cfg = SimulationConfig(
@@ -36,10 +66,25 @@ def fish_sim():
         freqDiagnostics=1, poissonTol=1e-5, poissonTolRel=1e-3,
         dtype="float32",
     )
-    sim = AMRSimulation(cfg)
+    sim = _FrameSpy(cfg)
     sim.init()
     sim.simulate()
     return sim
+
+
+def test_advection_takes_the_refreshed_frame_velocity(fish_sim):
+    """_advance_host advects with the frame velocity of THIS step, which
+    create_obstacles refreshes from the bodies that fix the frame, as
+    upstream, the uniform driver and advance_pipelined do; read before
+    the refresh it is the previous step's, off by a step's acceleration
+    (3e-5 here, where float32 rounding leaves 1e-11)."""
+    sim = fish_sim
+    assert not sim.cfg.pipelined and len(sim.frame_seen) == sim.step_idx
+    assert len(set(np.asarray(sim.grid.level).tolist())) >= 2
+    seen, due = np.array(sim.frame_seen), np.array(sim.frame_due)
+    # the bodies do accelerate, so a stale value cannot pass
+    assert np.abs(np.diff(due, axis=0)).max() > 1e-5
+    np.testing.assert_allclose(seen, due, rtol=1e-6, atol=1e-9)
 
 
 def test_two_fish_swim(fish_sim):

@@ -25,10 +25,11 @@ TWO_SPHERES = (
 
 
 def _run(pipelined, nsteps=5, factory=TWO_SPHERES, adapt=True,
-         level_max=2):
+         level_max=2, bpd=1, level_start=None):
     cfg = SimulationConfig(
-        bpdx=1, bpdy=1, bpdz=1, levelMax=level_max,
-        levelStart=level_max - 1, extent=1.0,
+        bpdx=bpd, bpdy=bpd, bpdz=bpd, levelMax=level_max,
+        levelStart=level_max - 1 if level_start is None else level_start,
+        extent=1.0,
         CFL=0.4, Ctol=0.1, Rtol=5.0, nu=1e-3, tend=0.0, nsteps=nsteps,
         rampup=0, dt=1e-3, poissonSolver="iterative",
         poissonTol=1e-6, poissonTolRel=1e-4, factory_content=factory,
@@ -39,6 +40,38 @@ def _run(pipelined, nsteps=5, factory=TWO_SPHERES, adapt=True,
     sim.adapt_enabled = adapt
     sim.simulate()
     return sim
+
+
+def test_pipelined_two_bodies_two_levels_match_host_path():
+    """The bucketed megastep with bodies traces and runs (PR 28 had left
+    its body velocity dividing by a name that was gone): two spheres in
+    the refined octant of a two-level forest (7 coarse blocks, 8 fine),
+    three steps, so both orders of the projection compile.  Fixed dt:
+    measured 1.5e-8 on a velocity of 0.21; the host path solves the
+    rigid 6x6 in float64, the device chain in float32."""
+    factory = (
+        "Sphere radius=0.07 xpos=0.36 ypos=0.36 zpos=0.36 xvel=0.3 "
+        "bForcedInSimFrame=1 bFixFrameOfRef=1\n"
+        "Sphere radius=0.06 xpos=0.36 ypos=0.36 zpos=0.14"
+    )
+    pipe = _run(True, nsteps=3, factory=factory, adapt=False, bpd=2,
+                level_start=0)
+    ref = _run(False, nsteps=3, factory=factory, adapt=False, bpd=2,
+               level_start=0)
+    assert not pipe._pack_reader  # flushed
+    assert sorted(np.bincount(np.asarray(pipe.grid.level))) == [7, 8]
+    assert pipe.grid.nb == ref.grid.nb == 15
+    np.testing.assert_allclose(
+        np.asarray(pipe.state["vel"]), np.asarray(ref.state["vel"]),
+        rtol=0, atol=1e-6,
+    )
+    for op, orf in zip(pipe.obstacles, ref.obstacles):
+        assert np.asarray(op.chi).sum() > 10.0  # the body is on the grid
+        np.testing.assert_allclose(op.position, orf.position,
+                                   rtol=1e-6, atol=1e-7)
+        np.testing.assert_allclose(op.transVel, orf.transVel,
+                                   rtol=1e-3, atol=1e-6)
+    np.testing.assert_allclose(pipe.uinf, ref.uinf, rtol=1e-6, atol=1e-7)
 
 
 @pytest.mark.parametrize("adapt", [False, True])

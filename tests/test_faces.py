@@ -3,6 +3,7 @@ per-cell LabTables reference on every face ghost, across BCs, widths,
 scalar/vector, and mixed-level topologies — and the hot operators built on
 it (Laplacian, Poisson solve) must match."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -12,7 +13,7 @@ from cup3d_tpu.grid.flux import build_flux_tables
 from cup3d_tpu.grid.octree import Octree, TreeConfig
 from cup3d_tpu.grid.uniform import BC
 from cup3d_tpu.ops import amr_ops
-from tests._grids import BS, THREE_LEVEL
+from tests._grids import BS, THREE_LEVEL, assert_dots_highest
 
 
 def _grid(levels=2, bc=(BC.periodic,) * 3, refine=((0, 0, 0, 0),),
@@ -177,3 +178,82 @@ def test_rk3_advection_parity():
         amr_ops.rk3_step_blocks(g, vel, 1e-3, 1e-3, uinf, g.face_tables(3), ft)
     )
     np.testing.assert_allclose(new, ref, rtol=0, atol=2e-6)
+
+
+# -- the coarse-fine interpolation is exact in float32 on every backend ----
+
+
+@pytest.mark.parametrize("kind,ncomp", [("scalar", 0), ("vector", 3)])
+@pytest.mark.parametrize("w", [1, 3])
+def test_coarse_halo_dots_carry_highest_precision(w, kind, ncomp):
+    """A CPU run cannot see operands rounded to bfloat16, which is what
+    the TPU makes of a float32 dot at the default precision: the guard is
+    the program itself.  Every dot_general of the halo assembly, the three
+    of _coarse_halo per face among them, asks for Precision.HIGHEST."""
+    g = _grid()
+    tab = g.face_tables(w)
+    f = jnp.zeros((g.nb, BS, BS, BS) + ((ncomp,) if ncomp else ()),
+                  jnp.float32)
+    closed = jax.make_jaxpr(
+        lambda a: getattr(tab, "assemble_" + kind)(a, BS))(f)
+    assert_dots_highest(closed.jaxpr, at_least=3)
+
+
+def _prolong_f64(a):
+    """Dense periodic coarse array -> twice as fine, float64: per axis the
+    parabola through a cell and its two neighbours, a quarter cell off the
+    centre (weights 5/32, 15/16, -3/32; grid/faces.py's module text)."""
+    for ax in range(3):
+        lo, hi = np.roll(a, 1, axis=ax), np.roll(a, -1, axis=ax)
+        even = 5 / 32 * lo + 15 / 16 * a - 3 / 32 * hi
+        odd = -3 / 32 * lo + 15 / 16 * a + 5 / 32 * hi
+        shape = list(a.shape)
+        shape[ax] *= 2
+        a = np.stack([even, odd], axis=ax + 1).reshape(shape)
+    return a
+
+
+@pytest.mark.parametrize("w", [1, 3])
+def test_coarse_ghosts_equal_the_float64_parabola(w):
+    """Two levels, periodic, a quadratic field: the ghosts a fine block
+    takes from a coarser neighbour equal, to 1e-6, the separable parabola
+    computed in float64 from the coarse level's composite array (its own
+    leaves, and the 8-to-1 average under the fine ones).  With operands
+    rounded to bfloat16 they are off by 4e-3."""
+    g = _grid()
+    nc = 2 * BS  # coarse cells per axis; the fine level has twice as many
+    level = np.asarray(g.level)
+    h, origin = np.asarray(g.h), np.asarray(g.origin)
+    cells = np.arange(BS) + 0.5
+    field = np.zeros((g.nb, BS, BS, BS))
+    coarse = np.zeros((nc,) * 3)
+    fine = np.zeros((2 * nc,) * 3)
+    covered = np.zeros((2 * nc,) * 3, bool)
+    for b in range(g.nb):
+        x, y, z = np.meshgrid(*(origin[b, a] + cells * h[b] for a in range(3)),
+                              indexing="ij")
+        field[b] = (0.3 * x * x - 0.2 * y * y + 0.15 * z * z + 0.4 * x * y
+                    - 0.25 * y * z + 0.1 * x + 0.05)
+        i0 = np.rint(origin[b] / h[b]).astype(int)
+        box = tuple(slice(i, i + BS) for i in i0)
+        if level[b] == 0:
+            coarse[box] = field[b]
+        else:
+            fine[box], covered[box] = field[b], True
+    under = fine.reshape(nc, 2, nc, 2, nc, 2).mean(axis=(1, 3, 5))
+    under_fine = covered.reshape(nc, 2, nc, 2, nc, 2).all(axis=(1, 3, 5))
+    due = _prolong_f64(np.where(under_fine, under, coarse))
+
+    lab = np.asarray(g.face_tables(w).assemble_scalar(
+        jnp.asarray(field, jnp.float32), BS), np.float64)
+    idx = np.argwhere(_face_region_mask(BS + 2 * w, w, BS))
+    checked = 0
+    for b in np.flatnonzero(level == 1):
+        i0 = np.rint(origin[b] / h[b]).astype(int)
+        gi = tuple(((i0 + idx - w) % (2 * nc)).T)
+        ghost = ~covered[gi]  # the cell lies in a coarser leaf
+        got = lab[b][tuple(idx[ghost].T)]
+        np.testing.assert_allclose(
+            got, due[tuple(a[ghost] for a in gi)], rtol=0, atol=1e-6)
+        checked += int(ghost.sum())
+    assert checked == 24 * BS * BS * w  # 8 fine blocks, 3 outer faces each

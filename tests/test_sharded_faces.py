@@ -14,7 +14,8 @@ from cup3d_tpu.parallel.faces import build_sharded_face_tables
 from cup3d_tpu.parallel.forest import make_block_mesh
 from cup3d_tpu.sim.amr import AMRSimulation
 from tests._grids import (
-    BS, THREE_LEVEL, assemble, forest, laplacian, mixed_grid, rand,
+    BS, THREE_LEVEL, assemble, assert_dots_highest, forest, laplacian,
+    mixed_grid, rand,
 )
 
 
@@ -44,6 +45,17 @@ def test_sharded_faces_match_single_device(width, refine):
     gotv = fo.unpad(assemble(stab, "vector", fo.pad(v)))
     np.testing.assert_allclose(np.asarray(gotv), np.asarray(refv),
                                rtol=0, atol=2e-6)
+
+
+def test_sharded_coarse_halo_dots_carry_highest_precision():
+    """The sharded twin of grid/faces.py::_coarse_halo interpolates in
+    float32 on every backend (tests/test_faces.py has the single-device
+    guard and the value test)."""
+    fo = forest(mixed_grid())
+    stab = build_sharded_face_tables(fo, 1)
+    closed = jax.make_jaxpr(lambda a: stab.assemble_scalar(a, BS))(
+        fo.pad(rand(fo.grid)))
+    assert_dots_highest(closed.jaxpr, at_least=3)
 
 
 @pytest.mark.parametrize("bc", [
