@@ -36,22 +36,6 @@ from cup3d_tpu.models.fish.shapes import compute_widths_heights
 from cup3d_tpu.ops.chi import heaviside
 
 
-@jax.jit
-def _raster_scatter_blocks(xc, scat, midline, position, rot):
-    """Gather candidate block centers -> midline rasterization -> scatter
-    back into full forest arrays, as ONE jitted dispatch.  Padded rows of
-    ``scat`` point one past the end: the gather fills far-away centers
-    (sdf -> -inf side) and the scatter drops them."""
-    centers = jnp.take(xc, scat, axis=0, mode="fill", fill_value=1e6)
-    sdf_c, udef_c = rasterize_points(centers, midline, position, rot)
-    nb = xc.shape[0]
-    sdf = jnp.full((nb,) + xc.shape[1:4], -1.0, xc.dtype)
-    sdf = sdf.at[scat].set(sdf_c, mode="drop")
-    udef = jnp.zeros(xc.shape[:4] + (3,), xc.dtype)
-    udef = udef.at[scat].set(udef_c, mode="drop")
-    return sdf, udef
-
-
 def _split_midline(pack):
     """(Nm, 20) device midline pack -> the rasterizer's dict."""
     return {
@@ -60,6 +44,35 @@ def _split_midline(pack):
         "bin": pack[:, 12:15], "vbin": pack[:, 15:18],
         "width": pack[:, 18], "height": pack[:, 19],
     }
+
+
+def raster_blocks(xc, real, slots, pack, frame):
+    """Block-layout rasterization, traced: gather the candidate blocks'
+    centers from ``xc`` (rows, bs, bs, bs, 3) -> midline distance over
+    their cells -> scatter into arrays of ``xc``'s row count.  A real
+    block that is no candidate holds SDF -1 and udef 0; a padding row of a
+    capacity bucket (``real`` (rows, 1, 1, 1) is 0 there; None: no
+    padding) holds the all-zero SDF the bucket's invariants rest on.
+    Padded entries of ``slots`` lie past the end: the gather fills
+    far-away centers and the scatter drops them.  ``frame`` None: the
+    pack's last row carries the host mirrors' frame, as in
+    ``_raster_window``."""
+    if frame is None:
+        pack, frame = pack[:-1], pack[-1, :FRAME]
+    pos, rot = pos_rot_traced(frame)
+    centers = jnp.take(xc, slots, axis=0, mode="fill", fill_value=1e6)
+    sdf_c, udef_c = rasterize_points(centers, _split_midline(pack), pos, rot)
+    shape = xc.shape[:4]
+    sdf = jnp.full(shape, -1.0, xc.dtype)
+    if real is not None:
+        sdf = jnp.where(real > 0, sdf, 0.0)
+    sdf = sdf.at[slots].set(sdf_c, mode="drop")
+    udef = jnp.zeros(shape + (3,), xc.dtype)
+    udef = udef.at[slots].set(udef_c, mode="drop")
+    return sdf, udef
+
+
+_raster_blocks = jax.jit(raster_blocks)
 
 
 def _raster_window(pack, frame, grid, window_shape):
@@ -236,13 +249,6 @@ class StefanFish(Obstacle):
              cf.width[:, None], cf.height[:, None]], axis=1
         )
 
-    def _midline_device(self):
-        """One packed (Nm, 20) host->device transfer per rasterization —
-        eight separate uploads are eight blocking transfers —
-        sliced back into the rasterizer's dict on device (free)."""
-        return _split_midline(jnp.asarray(self._midline_pack(),
-                                          self.sim.dtype))
-
     def _dense_inputs(self):
         """(pack, frame) for the dense-layout programs: ONE upload per
         call.  Host mirrors' frame: it rides as one more row of the
@@ -259,48 +265,57 @@ class StefanFish(Obstacle):
         # a convert program
         return jnp.asarray(pack.astype(self.sim.dtype)), frame
 
-    def _rasterize_blocks(self, t: float):
-        """Block-layout rasterization: candidate blocks by AABB intersection
-        (the TPU analogue of prepare_segPerBlock, main.cpp:10672-10717),
-        one batched midline-distance evaluation over their cells, scattered
-        into the (nb, bs, bs, bs) forest arrays.
+    #: the traced half of the block-layout rasterizer: a forest driver
+    #: that finds it on a body traces it inside its own program
+    raster_blocks = staticmethod(raster_blocks)
 
-        The candidate cell centers are GATHERED from the driver's cached
-        device centers (sim._xc) inside one jitted call — rebuilding and
-        uploading them on host, plus the eager scatters, cost host time
-        on every step of every fish."""
-        grid = self.sim.grid
-        dtype = self.sim.dtype
-        bs = grid.bs
-        # fish AABB around the body center, padded per block by the
-        # mollification band at that block's spacing (the same margin the
-        # surface-probe windows use — ops/surface.probe_margin)
+    def block_inputs(self):
+        """The host half of the block-layout rasterizer, NumPy only:
+        ``(pack, frame, slots)`` for ``raster_blocks``.
+
+        ``slots``: candidate blocks by AABB intersection (the TPU analogue
+        of prepare_segPerBlock, main.cpp:10672-10717), the fish AABB
+        around the body center padded per block by the mollification band
+        at that block's spacing (the same margin the surface-probe windows
+        use — ops/surface.probe_margin); the count is bucketed so XLA
+        retraces only on bucket changes.  ``pack``: the float64 midline.
+        ``frame``: the device rigid pack in pipelined mode (exact current
+        state; the host mirror only sizes the AABB, whose 8h margin covers
+        the grouped-read staleness of ~8 steps x CFL*h of drift), else
+        None and the host mirrors' frame rides as the pack's last row."""
         from cup3d_tpu.ops.surface import probe_margin
 
+        grid = self.sim.grid
         half = probe_margin(self.length, grid.h)  # (nb,)
         lo = grid.origin  # (nb, 3)
-        hi = grid.origin + (bs * grid.h)[:, None]
+        hi = grid.origin + (grid.bs * grid.h)[:, None]
         cand = np.all(hi > self.position - half[:, None], axis=1) & np.all(
             lo < self.position + half[:, None], axis=1
         )
         idx = np.where(cand)[0]
-        m = len(idx)
-        # bucket the candidate count so XLA retraces only on bucket changes
-        mpad = max(16, -(-m // 16) * 16)
-        idx_pad = np.full(mpad, grid.nb, np.int64)  # OOB rows -> dropped
-        idx_pad[:m] = idx
+        mpad = max(16, -(-len(idx) // 16) * 16)
+        slots = np.full(mpad, np.iinfo(np.int32).max, np.int32)
+        slots[: len(idx)] = idx
+        pack = self._midline_pack()
+        d = self._dev_rigid
+        frame = d["pack"] if self.sim.cfg.pipelined and d is not None else None
+        if frame is None:
+            row = np.zeros((1, pack.shape[1]))
+            row[0, :FRAME] = self.host_frame()
+            pack = np.concatenate([pack, row])
+        return pack, frame, slots
+
+    def _rasterize_blocks(self, t: float):
+        """``rasterize`` on the block layout, (nb, bs, bs, bs) arrays: for
+        a driver that does its own padding (the sharded forest)."""
+        grid = self.sim.grid
+        dtype = self.sim.dtype
         xc = getattr(self.sim, "_xc", None)
         if xc is None or xc.shape[0] != grid.nb:
             xc = jnp.asarray(grid.cell_centers(dtype))
-        # position/rotation from the device rigid chain in pipelined mode
-        # (exact current state; the host mirror above only sizes the AABB,
-        # whose 8h margin covers the grouped-read staleness of ~8 steps x
-        # CFL*h of drift — see ops/surface.probe_margin)
-        pos, rot = self.pos_rot_device(dtype)
-        return _raster_scatter_blocks(
-            xc, jnp.asarray(idx_pad, jnp.int32), self._midline_device(),
-            pos, rot,
-        )
+        pack, frame, slots = self.block_inputs()
+        return _raster_blocks(xc, None, jnp.asarray(slots),
+                              jnp.asarray(pack.astype(dtype)), frame)
 
     def rasterize(self, t: float):
         if self._is_blocks:

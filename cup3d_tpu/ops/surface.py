@@ -380,7 +380,7 @@ def surface_force_window(
 def probe_margin(length: float, h: float) -> float:
     """Half-extent of an obstacle's working AABB: body half-length plus an
     8h band.  THE single source for the rasterizer's candidate search
-    (stefanfish._rasterize_blocks) and both probe windows — these must
+    (StefanFish.block_inputs) and both probe windows — these must
     stay mutually consistent or surface cells silently fall outside the
     window.  8h also covers the pipelined host-mirror staleness (~8 steps
     x CFL*h <= 3.2h of position drift, sim/pack.py)."""

@@ -40,6 +40,7 @@ import numpy as np
 from cup3d_tpu.analysis.runtime import device_scalar, sanctioned_transfer
 from cup3d_tpu.config import SimulationConfig, parse_factory
 from cup3d_tpu.grid import adapt as ad
+from cup3d_tpu.grid import bucket as bk
 from cup3d_tpu.grid.blocks import BlockGrid, assemble_vector_lab
 from cup3d_tpu.grid.flux import build_flux_tables
 from cup3d_tpu.grid.octree import Octree, TreeConfig
@@ -60,7 +61,7 @@ from cup3d_tpu.models.base import (
     vel_unit,
 )
 from cup3d_tpu.ops import amr_ops
-from cup3d_tpu.ops.chi import heaviside
+from cup3d_tpu.ops.chi import towers_chi
 from cup3d_tpu.ops.penalization import (
     penalize,
     per_obstacle_penalization_force,
@@ -120,32 +121,50 @@ from cup3d_tpu.sim.dtpolicy import (  # noqa: E402 (placed with jit helpers)
 )
 
 
-@partial(jax.jit, static_argnames=("combine", "bs"))
-def _combine_obstacle_fields(sdfs, udefs, h_raw, combine=True, tab=None,
-                             bs=8):
-    """(n_obs, nb, ...) sdf/udef stacks -> per-obstacle chi/masked-udef +
-    (optionally) the chi-weighted combined fields, in one dispatch.  The
-    pipelined megastep recombines on device, so it passes combine=False.
+@partial(jax.jit, static_argnames=("rasters", "cuts", "combine", "bs"))
+def _create_blocks(packs, slots, frames, given, xc, real, h_raw, tab,
+                   rasters, cuts, combine, bs):
+    """CreateObstacles on the single-device forest as ONE program: from
+    the step's uploads to every field the step reads.
 
-    With ``tab`` (face tables) the chi is the reference's Towers
-    construction from the halo'd SDF (ops/chi.py towers_chi, +-1h band);
-    without neighbor data (sharded-forest create) the sine Heaviside
-    fallback keeps the old +-2h band."""
-    if tab is not None:
-        from cup3d_tpu.ops.chi import towers_chi
+    Body ``i`` with a traced block rasterizer (``rasters[i]``, e.g.
+    ``StefanFish.raster_blocks``) takes its ``cuts[i]`` = (rows, entries)
+    of ``packs``, the host midlines of all such bodies in one upload, and
+    of ``slots``, their candidate blocks in another, and writes its SDF
+    and udef at ``xc``'s row count, bucket padding included.  A body
+    without one (``rasters[i]`` None) brings ``given[i]``, the (sdf, udef
+    or None) of its own ``rasterize()`` on the grid's real blocks, padded
+    here.  Padding rows hold an all-zero SDF, on which the Towers chi is
+    exactly 0 (ops/chi.py), so the bucket's invariants hold unmasked.
 
-        chis = jnp.stack(
-            [
-                towers_chi(tab.assemble_scalar(sdfs[i], bs), h_raw)
-                for i in range(sdfs.shape[0])
-            ]
-        )
-    else:
-        chis = heaviside(sdfs, h_raw[None])
-    udefs = udefs * (chis > 0)[..., None]
-    if not combine:
-        return chis, udefs, None, None
-    return (chis, udefs) + combine_obstacle_fields(chis, udefs)
+    Then the tail every body shares: Towers chi from the halo'd SDF
+    (+-1h band), udef kept inside the band, and with ``combine`` the
+    chi-weighted combined fields (the pipelined megastep recombines on
+    device and passes False).  Returns the per-body (sdfs, chis, udefs)
+    as tuples of separate outputs and (chi, udef) or (None, None):
+    nothing is sliced or stacked afterwards."""
+    rows = xc.shape[0]
+    sdfs, chis, udefs = [], [], []
+    p0 = s0 = 0
+    for raster, (np_, ns), frame, own in zip(rasters, cuts, frames, given):
+        if raster is None:
+            sdf, udef = own
+            sdf = bk.pad_field(sdf, rows)
+            udef = (jnp.zeros(sdf.shape + (3,), sdf.dtype) if udef is None
+                    else bk.pad_field(udef, rows))
+        else:
+            sdf, udef = raster(xc, real, slots[s0:s0 + ns],
+                               packs[p0:p0 + np_], frame)
+            p0, s0 = p0 + np_, s0 + ns
+        chi = towers_chi(tab.assemble_scalar(sdf, bs), h_raw)
+        sdfs.append(sdf)
+        chis.append(chi)
+        udefs.append(udef * (chi > 0)[..., None])
+    combined = (
+        combine_obstacle_fields(jnp.stack(chis), jnp.stack(udefs))
+        if combine else (None, None)
+    )
+    return (tuple(sdfs), tuple(chis), tuple(udefs)) + combined
 
 
 class AMRSimulation:
@@ -334,8 +353,6 @@ class AMRSimulation:
         if self.forest is not None:
             return self.forest.pad(field)
         if self._bucketing:
-            from cup3d_tpu.grid import bucket as bk
-
             return bk.pad_field(field, self._cap)
         return field
 
@@ -679,7 +696,6 @@ class AMRSimulation:
         """
         g, cfg = self.grid, self.cfg
         self.forest = None
-        from cup3d_tpu.grid import bucket as bk
         from cup3d_tpu.grid.faces import pad_face_tables
         from cup3d_tpu.grid.flux import pad_flux_tables
         from cup3d_tpu.ops import krylov
@@ -1480,57 +1496,30 @@ class AMRSimulation:
 
     def create_obstacles(self, dt: float = 0.0, combine: bool = True):
         """Reference CreateObstacles (main.cpp:13589-13621) on blocks.
-        Heaviside + masking + the chi-weighted combine run as ONE jitted
-        dispatch over all obstacles (eagerly they cost ~10 dispatches
-        per step).  advance_pipelined passes combine=False: the
-        megastep recombines on device, so the combined-state write here
-        would be dead work (every other caller needs it)."""
+        Single device: host work in NumPy, then two uploads at most and
+        ONE program for all bodies (``_create_blocks``).
+        advance_pipelined passes combine=False: the megastep recombines
+        on device, so the combined-state write here would be dead work
+        (every other caller needs it)."""
         if not self.obstacles:
             return
         fixed = [ob for ob in self.obstacles if ob.bFixFrameOfRef]
         if fixed:
             self.uinf = -np.mean([ob.transVel for ob in fixed], axis=0)
-        bucketed = self.forest is None and self._bucketing
-        h_raw = (
-            self._h_col if bucketed
-            else jnp.asarray(
-                self.grid.h.reshape(self.grid.nb, 1, 1, 1), self.dtype
-            )
-        )
+        if self.forest is None:
+            return self._create_obstacles_single(dt, combine)
         sdfs, udefs = [], []
         for ob in self.obstacles:
             ob.update_shape(self.time, dt)
             sdf, udef = ob.rasterize(self.time)  # unpadded (nb, ...)
             if udef is None:
                 udef = self.grid.zeros(3, self.dtype)
-            if bucketed:
-                # bucket-capacity padding BEFORE the combine: the padded
-                # tables assemble (cap,...) labs, and the Towers chi is
-                # exactly 0 on the all-zero padding SDF (ops/chi.py), so
-                # the padding invariants hold without masking
-                sdf, udef = self._pad(sdf), self._pad(udef)
             sdfs.append(sdf)
             udefs.append(udef)
-        if self.forest is None:
-            chis, udefs, chi, udef = _combine_obstacle_fields(
-                jnp.stack(sdfs), jnp.stack(udefs), h_raw, combine=combine,
-                tab=self._tab1, bs=self.grid.bs,
-            )
-            for i, ob in enumerate(self.obstacles):
-                ob.chi = chis[i]
-                ob.udef = udefs[i]
-                # kept for the surface-point force probe (ops/surface.py)
-                ob.sdf = sdfs[i]
-            if combine:
-                self.state["chi"] = chi
-                self.state["udef"] = udef
-            return
         # mesh mode: the Towers chi needs SDF halos, which live behind the
         # sharded forest's exchange — pad first, assemble, then combine
         # (same construction as the single-device path, so sharded-vs-
         # single trajectories stay comparable)
-        from cup3d_tpu.ops.chi import towers_chi
-
         chis_p, udefs_p = [], []
         for ob, sdf, ud in zip(self.obstacles, sdfs, udefs):
             sdf_p = self._pad(sdf)
@@ -1548,6 +1537,43 @@ class AMRSimulation:
         self.state["udef"] = (
             sum(c[..., None] * u for c, u in zip(chis_p, udefs_p)) / den
         )
+
+    def _create_obstacles_single(self, dt: float, combine: bool):
+        """The host half of ``_create_blocks``.  A body that offers the
+        traced block rasterizer (``raster_blocks`` with its NumPy
+        ``block_inputs``) is rasterized inside the program; any other
+        dispatches its own ``rasterize()`` first."""
+        packs, slots, bodies = [], [], []  # (raster, cut, frame, given)
+        for ob in self.obstacles:
+            ob.update_shape(self.time, dt)
+            raster = getattr(ob, "raster_blocks", None)
+            if raster is None:
+                bodies.append((None, (0, 0), None, ob.rasterize(self.time)))
+            else:
+                pack, frame, idx = ob.block_inputs()
+                packs.append(pack)
+                slots.append(idx)
+                bodies.append((raster, (len(pack), len(idx)), frame, None))
+        rasters, cuts, frames, given = zip(*bodies)
+        if packs:
+            # cast on the host: jnp.asarray(float64, float32) is an upload
+            # AND a convert program
+            packs = jnp.asarray(np.concatenate(packs).astype(self.dtype))
+            slots = jnp.asarray(np.concatenate(slots))
+        else:
+            packs = slots = None
+        sdfs, chis, udefs, chi, udef = _create_blocks(
+            packs, slots, frames, given, self._xc, self._real_mask,
+            self._h_col, self._tab1, rasters=rasters, cuts=cuts,
+            combine=combine, bs=self.grid.bs,
+        )
+        for i, ob in enumerate(self.obstacles):
+            # the SDF is kept for the surface-point force probe
+            # (ops/surface.py)
+            ob.sdf, ob.chi, ob.udef = sdfs[i], chis[i], udefs[i]
+        if combine:
+            self.state["chi"] = chi
+            self.state["udef"] = udef
 
     def _obstacle_ubody(self, ob):
         # cached per (step, rigid state); penalization and the force pass

@@ -5,13 +5,26 @@ suite stays fast).
 Asserts the judge's done-criteria for "fish on AMR": the fish swims
 (|transVel| > 0, all state finite), interface blocks sit at the finest
 level, and the post-projection divergence gate holds.
+
+CreateObstacles on the single-device forest (the last tests of the file,
+which drive the module's forest on): host NumPy kinematics, two uploads
+and one program for all bodies (sim/amr.py ``_create_blocks``), counted
+as ``tests/_dispatch.py`` says, and held to the chain the parent
+dispatched op by op, written out below as plain ``jnp`` calls.
 """
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from cup3d_tpu.config import SimulationConfig
+from cup3d_tpu.grid.octree import Octree, TreeConfig
+from cup3d_tpu.models.base import combine_obstacle_fields, quat_to_rot
+from cup3d_tpu.models.fish.rasterize import rasterize_points
+from cup3d_tpu.ops.chi import towers_chi
 from cup3d_tpu.sim.amr import AMRSimulation
+from tests._dispatch import SpannedProfiler, dispatches, span
 
 pytestmark = pytest.mark.filterwarnings("ignore::DeprecationWarning")
 
@@ -173,3 +186,142 @@ def test_planar_angle_flips_heading():
     R = quat_to_rot(sim.obstacles[0].quaternion)
     # 180-degree yaw: body +x maps to computational -x
     assert np.allclose(R @ np.array([1.0, 0, 0]), [-1.0, 0, 0], atol=1e-12)
+
+
+# -- CreateObstacles on the forest: dispatches, and the parent's chain --------
+
+
+def test_update_shape_touches_no_device(fish_sim):
+    sim = fish_sim
+    with jax.transfer_guard("disallow_explicit"):
+        for ob in sim.obstacles:
+            ob.update_shape(sim.time, sim.dt)
+
+
+def test_create_obstacles_reads_nothing_back(fish_sim):
+    with jax.transfer_guard_device_to_host("disallow_explicit"):
+        fish_sim.create_obstacles(fish_sim.dt)
+
+
+def test_forest_step_dispatch_counts(fish_sim, tmp_path):
+    """One more ``advance()``, every profiler section a counted span."""
+    sim = fish_sim
+    blocks = sim.grid.nb
+    profiler = sim.profiler
+    sim.profiler = SpannedProfiler(profiler)
+
+    def one_more_advance():
+        dt = sim.calc_max_timestep()
+        with span("advance"):
+            sim.advance(dt)
+        jax.block_until_ready(sim.state["vel"])
+
+    try:
+        counts = dispatches(one_more_advance, str(tmp_path))
+    finally:
+        sim.profiler = profiler
+    assert sim.grid.nb == blocks, "a regrid retraces: count another step"
+    # two fish: 1 program and 2 uploads (the midlines with their frames,
+    # the candidate blocks); 55 and 66 before
+    programs, uploads = counts["CreateObstacles"]
+    assert programs <= 2 and uploads <= 2, counts
+    assert counts["advance"][0] <= 105, counts  # 151 before
+
+
+def parent_chain(sim, ob):
+    """(sdf, chi, udef) of one body from its host mirrors, as the parent
+    dispatched it: upload, candidate blocks by AABB, rasterizer on their
+    gathered centers, scatter, bucket padding, halo assembly, Towers chi,
+    band mask."""
+    grid, dtype = sim.grid, sim.dtype
+    pos = jnp.asarray(ob.position, dtype)
+    xc = jnp.asarray(grid.cell_centers(dtype))
+    if not hasattr(ob, "myFish"):
+        sdf = ob.radius - jnp.linalg.norm(xc - pos, axis=-1)
+        udef = jnp.zeros(sdf.shape + (3,), dtype)
+    else:
+        half = (0.625 * ob.length + 8.0 * grid.h)[:, None]
+        hi = grid.origin + (grid.bs * grid.h)[:, None]
+        idx = np.where(np.all(hi > ob.position - half, axis=1)
+                       & np.all(grid.origin < ob.position + half, axis=1))[0]
+        assert 0 < len(idx) < grid.nb, "the AABB chooses"
+        cf = ob.myFish
+        dev = jnp.asarray(np.concatenate(
+            [cf.r, cf.v, cf.nor, cf.vnor, cf.bin, cf.vbin,
+             cf.width[:, None], cf.height[:, None]], axis=1), dtype)
+        mid = {"r": dev[:, 0:3], "v": dev[:, 3:6], "nor": dev[:, 6:9],
+               "vnor": dev[:, 9:12], "bin": dev[:, 12:15],
+               "vbin": dev[:, 15:18], "width": dev[:, 18],
+               "height": dev[:, 19]}
+        rot = jnp.asarray(quat_to_rot(ob.quaternion), dtype)
+        sdf_c, udef_c = rasterize_points(xc[idx], mid, pos, rot)
+        sdf = jnp.full(xc.shape[:4], -1.0, dtype).at[idx].set(sdf_c)
+        udef = jnp.zeros(xc.shape, dtype).at[idx].set(udef_c)
+    sdf, udef = sim._pad(sdf), sim._pad(udef)
+    chi = towers_chi(sim._tab1.assemble_scalar(sdf, grid.bs), sim._h_col)
+    return sdf, chi, udef * (chi > 0)[..., None]
+
+
+def assert_matches_parent_chain(sim, combine):
+    """The fields ``create_obstacles`` has just written, against the
+    chain on the host mirrors it was given; padding rows exactly 0."""
+    want = [parent_chain(sim, ob) for ob in sim.obstacles]
+    nb = sim.grid.nb
+
+    def close(got, ref, what):
+        assert got.shape == ref.shape and got.shape[0] == sim._cap > nb
+        scale = max(float(jnp.max(jnp.abs(ref))), 1e-3)
+        gap = float(jnp.max(jnp.abs(got - ref)))
+        assert gap <= 1e-6 * scale, (what, gap)
+        assert not np.asarray(got[nb:]).any(), what
+
+    for i, (ob, (sdf, chi, udef)) in enumerate(zip(sim.obstacles, want)):
+        close(ob.sdf, sdf, ("sdf", i))
+        close(ob.chi, chi, ("chi", i))
+        close(ob.udef, udef, ("udef", i))
+        assert float(jnp.sum(chi)) > 10.0, "the body is on the grid"
+    if combine:
+        chi, udef = combine_obstacle_fields(
+            jnp.stack([c for _, c, _ in want]),
+            jnp.stack([u for _, _, u in want]))
+        close(sim.state["chi"], chi, ("state chi",))
+        close(sim.state["udef"], udef, ("state udef",))
+
+
+@pytest.mark.parametrize("combine", [True, False])
+def test_fused_program_matches_the_parents_chain(fish_sim, combine):
+    sim = fish_sim
+    kept = sim.state["chi"], sim.state["udef"]
+    sim.create_obstacles(sim.dt, combine=combine)
+    assert_matches_parent_chain(sim, combine)
+    if not combine:  # the megastep recombines: nothing is written
+        assert sim.state["chi"] is kept[0] and sim.state["udef"] is kept[1]
+
+
+def test_sphere_goes_through_the_generic_tail(tmp_path):
+    """A body without a traced block rasterizer on a two-level forest
+    (7 coarse blocks, 8 fine): its own ``rasterize()`` and the tail as
+    one more program."""
+    tree = Octree(TreeConfig((2, 2, 2), 2, (True,) * 3), 0)
+    tree.refine((0, 0, 0, 0))
+    tree.assert_balanced()
+    sim = AMRSimulation(SimulationConfig(
+        bpdx=2, bpdy=2, bpdz=2, levelMax=2, levelStart=0, extent=1.0,
+        nsteps=1, verbose=False,
+        factory_content="Sphere radius=0.07 xpos=0.36 ypos=0.36 zpos=0.36",
+        path4serialization=str(tmp_path / "run"),
+    ), tree=tree)
+    sim._add_obstacles()
+    sim.create_obstacles()  # compiles
+
+    def create():
+        with span("CreateObstacles"):
+            sim.create_obstacles()
+        jax.block_until_ready(sim.state["chi"])
+
+    counts = dispatches(create, str(tmp_path / "trace"))
+    # its SDF (the centers and the frame uploaded) and the tail; the
+    # parent dispatched 16 programs and 16 uploads here
+    assert counts["CreateObstacles"] == (2, 2), counts
+    assert sorted(np.bincount(np.asarray(sim.grid.level))) == [7, 8]
+    assert_matches_parent_chain(sim, combine=True)

@@ -3,12 +3,8 @@ one upload per body, one program per body (models/pipeline.py).
 
 The cases are the benchmark's 32^3 rehearsal of ``fish128`` built through
 ``build_driver`` with ``-pipelined 0``, a sphere and two fish on the same
-grid.  Programs and uploads are counted the way the benchmark's traced
-runs see them: a profiler trace at host tracer level 2
-(``benchmarks/lib/trace_reduce.start``), the executed programs
-(``PjRtCpuExecutable::Execute``) and uploads (``DevicePut*``) inside a
-``TraceAnnotation`` around each operator.  A count of dispatches does not
-depend on the platform.
+grid.  Programs and uploads are counted as ``tests/_dispatch.py`` says,
+inside a span around each operator.
 
 The equivalence cases hold the fused programs to the chain the parent
 dispatched op by op, written out below as plain ``jnp`` calls.  The step
@@ -16,7 +12,6 @@ path against the scan body: ``tests/test_megaloop.py::
 test_device_midline_chi_udef_matches_host`` (unchanged, same limits).
 """
 
-import glob
 import json
 import os
 
@@ -26,12 +21,12 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from benchmarks.lib import trace_reduce
 from cup3d_tpu.__main__ import build_driver
 from cup3d_tpu.analysis.runtime import device_scalar
 from cup3d_tpu.models.base import quat_to_rot
 from cup3d_tpu.models.fish.rasterize import rasterize_midline
 from cup3d_tpu.ops.chi import towers_chi
+from tests._dispatch import dispatches, span
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -94,46 +89,25 @@ class Annotated:
         self.op, self.name = op, op.name
 
     def __call__(self, dt):
-        with jax.profiler.TraceAnnotation("op:" + self.name):
+        with span(self.name):
             return self.op(dt)
 
 
-def dispatches(driver, directory):
-    """{span: (programs executed, uploads)} of one more ``advance()``."""
-    from jax.profiler import ProfileData
-
+def one_more_advance(driver):
+    """One more ``advance()``, every operator of the pipeline in a span."""
     pipeline = driver.pipeline
     driver.pipeline = [Annotated(op) for op in pipeline]
-    trace_reduce.start(directory)
     try:
         dt = driver.calc_max_timestep()
-        with jax.profiler.TraceAnnotation("op:advance"):
+        with span("advance"):
             driver.advance(dt)
         jax.block_until_ready(driver.sim.state["vel"])
     finally:
-        jax.profiler.stop_trace()
         driver.pipeline = pipeline
-    path, = glob.glob(os.path.join(directory, "**", "*.xplane.pb"),
-                      recursive=True)
-    spans, programs, uploads = [], [], []
-    for plane in ProfileData.from_file(path).planes:
-        for line in plane.lines:
-            for e in line.events:
-                if e.name.startswith("op:"):
-                    spans.append((e.start_ns, e.start_ns + e.duration_ns,
-                                  e.name[3:]))
-                elif e.name == "PjRtCpuExecutable::Execute":
-                    programs.append(e.start_ns)
-                elif e.name.startswith("DevicePut"):  # ...WithSharding
-                    uploads.append(e.start_ns)
-    assert programs and uploads, "the trace names its events otherwise"
-    return {name: (sum(a <= t < b for t in programs),
-                   sum(a <= t < b for t in uploads))
-            for a, b, name in spans}
 
 
 def test_create_obstacles_dispatch_counts(stepped, tmp_path):
-    counts = dispatches(stepped, str(tmp_path))
+    counts = dispatches(lambda: one_more_advance(stepped), str(tmp_path))
     programs, uploads = counts["CreateObstacles"]
     # one fish: 1 and 1 (157 and 98 before); a sphere: its SDF and the
     # shared tail; two fish: one program each and the combine
