@@ -367,7 +367,7 @@ class _AMRLaneDriver:
 
     def _megaloop_eligible(self) -> bool:
         s, cfg = self.sim, self.cfg
-        return (not s.obstacles and s.forest is None and s._bucketing
+        return (not s.obstacles and s.forest is None
                 and not cfg.implicitDiffusion and not cfg.bFixMassFlux
                 and cfg.uMax_forced <= 0)
 

@@ -272,7 +272,7 @@ def build_amr_poisson_solver(
         )
     vol_total = jnp.sum(vol) * grid.bs**3
     # square in f32 AFTER the dtype cast: bit-identical to the dynamic
-    # builder's h_col * h_col (tests/test_bucketing equivalence)
+    # builder's h_col * h_col
     h_col = jnp.asarray(grid.h.reshape(grid.nb, 1, 1, 1), jnp.float32)
     h2 = h_col * h_col
     # corner block: the reference pins block .index == (0,0,0); in the

@@ -20,16 +20,21 @@ tables, per-block h, cell volumes/centers, the coarse block graph)
 travels as traced jit ARGUMENTS, so a regrid that stays within a bucket
 reuses every compiled executable — zero retraces — and only pays the
 host table build (itself memoized by octree signature, so ping-pong
-regrids A->B->A skip even that).  CUP3D_BUCKET=0 restores the legacy
-retrace-per-regrid path (the equivalence baseline in tests); the
-sharded-forest path keeps its closure-style rebuild (per-shard scale is
-bounded, and its duck-typed tables are not pytrees) — the reference's
-"re-_Setup all synchronizers" cost model (main.cpp:5153-5157).
+regrids A->B->A skip even that).
+
+Every step kernel and both megasteps are written once, in
+sim/amr_step.py, as functions of their state and one geometry view; this
+module binds them two ways.  The BUCKETED binder (_rebuild_bucketed,
+every single-device run) jits them with the _geo_args bundle as trailing
+traced arguments and rebuilds the view inside the trace.  The SHARDED
+FOREST binder (_rebuild, ``mesh=``) builds the view once per octree
+signature and closes over it (per-shard scale is bounded, and its
+duck-typed tables are not pytrees) — the reference's "re-_Setup all
+synchronizers" cost model (main.cpp:5153-5157).
 """
 
 from __future__ import annotations
 
-import os
 from functools import partial
 from typing import Dict, List, Optional
 
@@ -41,7 +46,7 @@ from cup3d_tpu.analysis.runtime import device_scalar, sanctioned_transfer
 from cup3d_tpu.config import SimulationConfig, parse_factory
 from cup3d_tpu.grid import adapt as ad
 from cup3d_tpu.grid import bucket as bk
-from cup3d_tpu.grid.blocks import BlockGrid, assemble_vector_lab
+from cup3d_tpu.grid.blocks import BlockGrid
 from cup3d_tpu.grid.flux import build_flux_tables
 from cup3d_tpu.grid.octree import Octree, TreeConfig
 from cup3d_tpu.grid.uniform import BC
@@ -51,9 +56,7 @@ from cup3d_tpu.models.base import (
     RIGID_PACK,
     combine_obstacle_fields,
     log_forces,
-    momentum_integrals_core,
     pack_forces,
-    pack_moments,
     store_force_qoi,
     unpack_forces,
     unpack_moments,
@@ -62,12 +65,10 @@ from cup3d_tpu.models.base import (
 )
 from cup3d_tpu.ops import amr_ops
 from cup3d_tpu.ops.chi import towers_chi
-from cup3d_tpu.ops.penalization import (
-    penalize,
-    per_obstacle_penalization_force,
-)
+from cup3d_tpu.ops.penalization import penalize
 from cup3d_tpu.resilience import faults
 from cup3d_tpu.resilience.recovery import SimulationFailure
+from cup3d_tpu.sim.amr_step import GeomView, make_step_bodies
 
 ADAPT_EVERY = 20  # reference cadence (main.cpp:15314)
 _EPS = 1e-6
@@ -80,11 +81,22 @@ _EPS = 1e-6
 #: topology, and equal signatures guarantee bitwise-equal tables.
 _FOREST_EXEC_ATTRS = (
     "forest", "_tab1", "_tab3", "_ftab", "_solver", "_vol", "_h_col",
-    "_xc", "_real_mask", "_geom", "_advdiff", "_project", "_project_2nd",
-    "_penalize", "_penal_force", "_ubody", "_divnorms", "_dissipation",
-    "_gradchi", "_omega_mag", "_scores", "_moments", "_maxu",
-    "_megastep", "_megastep_free", "_fix_flux", "_device_tags",
+    "_xc", "_real_mask", "_geom", "_view", "_advdiff", "_project",
+    "_project_2nd", "_penalize", "_penal_force", "_ubody", "_divnorms",
+    "_dissipation", "_gradchi", "_omega_mag", "_scores", "_moments",
+    "_maxu", "_megastep", "_megastep_free", "_fix_flux", "_device_tags",
 )
+
+#: the step kernels both binders bind: amr_step body (also the program's
+#: name and, after "_", the attribute) -> donated state argnums, the
+#: buffers the caller rebinds from the return value (JX002 burn-down).
+#: ``tags`` (bucketed only) and ``fix_flux`` (donated by the bucketed
+#: binder only) are bound beside this table.
+_STEP_KERNELS = {
+    "advdiff": (0,), "project": (0, 4), "project_2nd": (0, 4),
+    "penal_force": (), "ubody": (), "divnorms": (), "dissipation": (),
+    "gradchi": (), "omega_mag": (), "scores": (), "moments": (),
+}
 
 
 class _ArgGeom:
@@ -275,14 +287,11 @@ class AMRSimulation:
         # the device compute + transfer overlap the inter-step host work
         self._scores_prefetch = None
         # bucketed path binds the on-device tag decision in
-        # _bind_bucket_executables; None = host tagging (forest/legacy)
+        # _bind_bucket_executables; None = host tagging (forest)
         self._device_tags = None
         # capacity bucketing (module doc): single-device regrids reuse
         # compiled executables while the padded table shapes stay inside
-        # a bucket; CUP3D_BUCKET=0 restores the legacy retrace path
-        self._bucketing = (
-            mesh is None and os.environ.get("CUP3D_BUCKET", "1") != "0"
-        )
+        # a bucket
         self._table_memo: Dict = {}   # octree signature -> padded bundle
         self._exec_cache: Dict = {}   # bucket key -> jitted executables
         # octree signature -> the forest path's full executable bundle
@@ -311,7 +320,6 @@ class AMRSimulation:
             "driver": "amr",
             "blocks": int(g.nb),
             "bucket_capacity": int(getattr(self, "_cap", g.nb)),
-            "bucketing": bool(self._bucketing),
             "levels": sorted(set(int(l) for l in np.asarray(g.level))),
             "table_memo_entries": len(self._table_memo),
             "exec_cache_entries": len(self._exec_cache),
@@ -352,16 +360,12 @@ class AMRSimulation:
         padding on the single-device path (padding rows stay 0)."""
         if self.forest is not None:
             return self.forest.pad(field)
-        if self._bucketing:
-            return bk.pad_field(field, self._cap)
-        return field
+        return bk.pad_field(field, self._cap)
 
     def _unpad(self, field):
         if self.forest is not None:
             return self.forest.unpad(field)
-        if self._bucketing:
-            return field[: self.grid.nb]
-        return field
+        return field[: self.grid.nb]
 
     def uinf_device(self):
         # identity-keyed upload cache: uinf is only ever REASSIGNED (the
@@ -423,277 +427,143 @@ class AMRSimulation:
              if self.mesh is not None else None),
         )
 
-    def _rebuild(self):
-        if self.mesh is None and self._bucketing:
-            return self._rebuild_bucketed()
-        # forest/legacy paths keep the host tagging decision
-        self._device_tags = None
+    def _h_finest(self) -> float:
+        """Spacing of the finest level the octree allows (constant over
+        regrids): what the surface probe and its budgets are sized by."""
         g = self.grid
-        cfg = self.cfg
-        if self.mesh is not None:
-            from cup3d_tpu.parallel.forest import cached_forest
+        return float(g.h0 / (1 << (len(g._slot_maps) - 1)))
 
-            # within-signature regrids (the ping-pong A->B->A pattern)
-            # rebind the memoized executable bundle: zero retraces, zero
-            # table rebuilds (parallel/forest.py cached_forest shares
-            # the key discipline)
-            sig = g.signature
-            memo = self._forest_memo.get(sig)
-            if memo is not None:
-                for k, v in memo.items():
-                    setattr(self, k, v)
-                return
-            self.forest = cached_forest(g, self.mesh)
-            geom = self.forest.geom
-            # round 4: mesh mode runs the face-slab fast path too
-            # (parallel/faces.py; falls back to per-ghost lab tables only
-            # on degenerate closed-boundary topologies)
-            self._tab1 = self.forest.face_tables(1)
-            self._tab3 = self.forest.face_tables(3)
-            self._ftab = self.forest.flux_tables
-            self._solver = self.forest.build_poisson_solver(
-                tol_abs=cfg.poissonTol, tol_rel=cfg.poissonTolRel,
-                mean_constraint=cfg.bMeanConstraint,
-            )
-            # padded geometry arrays; cell volume is 0 on padding blocks so
-            # every volume-weighted reduction ignores them, and the padding
-            # rows of all fields are kept at 0 (labs of padding blocks
-            # assemble to zero, so operators never write garbage there)
-            self._vol = jnp.asarray(self.forest.vol, self.dtype)
-            self._h_col = self._pad(
-                jnp.asarray(g.h.reshape(g.nb, 1, 1, 1), self.dtype)
-            )
-            self._xc = self._pad(jnp.asarray(g.cell_centers(self.dtype)))
-            self._real_mask = jnp.asarray(self.forest.pmask, self.dtype)
-        else:
-            self.forest = None
-            geom = g
-            # face-slab fast-path tables (grid/faces.py): every operator in
-            # the step is an axis-stencil consumer, and the per-cell gather
-            # tables measured ~10-80x slower on TPU (VERDICT r2 item 1)
-            self._tab1 = g.face_tables(1)
-            self._tab3 = g.face_tables(3)
-            self._ftab = build_flux_tables(g)
-            self._solver = amr_ops.build_amr_poisson_solver(
-                g, tol_abs=cfg.poissonTol, tol_rel=cfg.poissonTolRel,
-                maxiter=self._poisson_maxiter,
-                tab=self._tab1, flux_tab=self._ftab,
-                mean_constraint=cfg.bMeanConstraint,
-                two_level=self._poisson_two_level,
-            )
-            self._h_col = jnp.asarray(
-                g.h.reshape(g.nb, 1, 1, 1), self.dtype
-            )
-            self._vol = self._h_col**3
-            self._xc = jnp.asarray(g.cell_centers(self.dtype))
-            self._real_mask = None
-        self._geom = geom
-
-        # The jitted step functions take the gather tables and cell-center
-        # arrays as trailing ARGUMENTS (LabTables/FluxTables are registered
-        # pytrees, grid/blocks.py): closure-captured arrays are embedded
-        # into the lowered HLO as constants, which at a few thousand blocks
-        # made the lowered program tens-to-hundreds of MB to compile
-        # and re-embedded everything on every adaptation
-        # re-layout.  The sharded forest's duck-typed tables are not
-        # pytrees, so that path keeps the closure style (its scale is
-        # bounded by per-device shards anyway).
-        # round 21: forest-bound executables persist in the AOT store
-        # under (octree signature + closure-content) keys — equal keys
-        # guarantee bitwise-equal bound tables, so a restarted process
-        # reloads the serialized executable instead of retracing
-        aot_sig = (self._aot_content_sig(g.signature)
-                   if self.mesh is not None else None)
-
-        def jit_bound(fn, *bound, donate=(), name=None):
-            # donate: positional argnums of the CALLER-facing signature
-            # (the bound tables sit after them, so the numbers agree on
-            # both paths).  Donated args are the step state buffers the
-            # caller rebinds from the return value (JX002 burn-down).
-            if self.forest is not None:
-                # the jit construction site lives in parallel/forest.py
-                # (bind_step_executable), outside the adaptation path:
-                # a NEW octree signature binds once and the bundle rides
-                # _forest_memo after (zero steady-state retraces across
-                # the regrid ping-pong — the JX007 burn-down)
-                from cup3d_tpu.parallel.forest import bind_step_executable
-
-                return bind_step_executable(fn, *bound, donate=donate,
-                                            name=name, store_sig=aot_sig)
-            # jax-lint: allow(JX007, legacy CUP3D_BUCKET=0 path kept as
-            # the bucketing equivalence baseline (tests/test_bucketing);
-            # production single-device runs use _rebuild_bucketed)
-            jf = jax.jit(fn, donate_argnums=donate)
-            return lambda *a: jf(*a, *bound)
-
+    def _step_bodies(self, budgets=()):
+        """sim/amr_step.py's bodies for this run's configuration (and,
+        for the megastep, the obstacles' static probe ``budgets``)."""
+        cfg, g = self.cfg, self.grid
+        helm = None
         if cfg.implicitDiffusion:
             from cup3d_tpu.ops import diffusion as dif
 
+            # the captured tables are fallbacks only: the traced
+            # tab1/ftab flow through helm's tab_arg/flux_arg at call time
+            # (ADVICE r2), and the bucketed view hands it a traced geom
             helm = dif.build_amr_helmholtz_solver(
-                geom, tol_abs=cfg.diffusionTol, tol_rel=cfg.diffusionTolRel,
+                g if self.forest is None else self._geom,
+                tol_abs=cfg.diffusionTol, tol_rel=cfg.diffusionTolRel,
                 tab=self._tab1, flux_tab=self._ftab,
             )
-            # the Helmholtz tables travel as traced args too (ADVICE r2):
-            # the closure-built helm's captured tables stay unused
-            self._advdiff = jit_bound(
-                lambda vel, dt, uinf, tab3, tab1, ftab:
-                dif.implicit_step_blocks(
-                    geom, vel, dt, self.nu, uinf, tab3,
-                    lambda u, nudt: helm(
-                        u, nudt, tab_arg=tab1, flux_arg=ftab
-                    ),
-                ),
-                self._tab3, self._tab1, self._ftab,
-                donate=(0,), name="advdiff_imp",
-            )
-        else:
-            self._advdiff = jit_bound(
-                lambda vel, dt, uinf, tab3, ftab: amr_ops.rk3_step_blocks(
-                    geom, vel, dt, self.nu, uinf, tab3, ftab
-                ),
-                self._tab3, self._ftab,
-                donate=(0,), name="advdiff",
-            )
-        # with_stats: (vel, p, [resid, iters]) — the stats vector joins
-        # the end-of-step packed QoI read (zeros on the stats-less
-        # forest solver), so solver telemetry never adds a host sync
-        self._project = jit_bound(
-            lambda vel, dt, chi, udef, p_old, tab1, ftab:
-            amr_ops.project_blocks(
-                geom, vel, dt, self._solver, tab1, ftab, chi, udef,
-                p_init=p_old, with_stats=True,
-            ),
-            self._tab1, self._ftab,
-            donate=(0, 4), name="project",
-        )
-        self._project_2nd = jit_bound(
-            lambda vel, dt, chi, udef, p_old, tab1, ftab:
-            amr_ops.project_blocks(
-                geom, vel, dt, self._solver, tab1, ftab, chi, udef,
-                p_init=p_old, second_order=True, with_stats=True,
-            ),
-            self._tab1, self._ftab,
-            donate=(0, 4), name="project_2nd",
-        )
-        self._penalize = _penalize_j
-        self._penal_force = jit_bound(
-            lambda vn, vo, chis, dt, cms, vol, xc:
-            per_obstacle_penalization_force(vn, vo, chis, dt, vol, xc, cms),
-            self._vol, self._xc, name="penal_force",
-        )
-        # ALL obstacles' force QoI in one (n_obs, FORCE_PACK) host read per
-        # step
-        # per-obstacle rigid+deformation velocity field from the cached
-        # device cell centers (avoids Obstacle.body_velocity_field's host
-        # rebuild of cell_centers every step)
-        self._ubody = jit_bound(
-            lambda udef, cm, ut, om, xc: ut
-            + jnp.cross(jnp.broadcast_to(om, xc.shape), xc - cm)
-            + udef,
-            self._xc, name="ubody",
-        )
-        self._divnorms = jit_bound(
-            lambda vel, tab1: amr_ops.divergence_norms_blocks(geom, vel, tab1),
-            self._tab1, name="divnorms",
-        )
-        self._dissipation = jit_bound(
-            lambda vel, tab1: amr_ops.dissipation_blocks(
-                geom, vel, self.nu, tab1
-            ),
-            self._tab1, name="dissipation",
-        )
-        self._gradchi = jit_bound(
-            lambda chi, tab1: amr_ops.grad_blocks(
-                geom, tab1.assemble_scalar(chi, g.bs), tab1.width
-            ),
-            self._tab1, name="gradchi",
-        )
-        self._omega_mag = jit_bound(
-            lambda vel, tab1: jnp.sqrt(
-                jnp.sum(
-                    amr_ops.curl_blocks(
-                        geom, tab1.assemble_vector(vel, g.bs), tab1.width
-                    )
-                    ** 2,
-                    axis=-1,
-                )
-            ),
-            self._tab1, name="omega_mag",
+        return make_step_bodies(
+            nu=self.nu, bs=g.bs, dtype=self.dtype, helm=helm,
+            fix_mass_flux=cfg.bFixMassFlux, umax_forced=cfg.uMax_forced,
+            tag_rule=(cfg.Rtol, cfg.Ctol, cfg.levelMax,
+                      cfg.levelMaxVorticity, bool(cfg.bAdaptChiGradient)),
+            h_fine=self._h_finest(), budgets=budgets,
         )
 
-        self._scores = jit_bound(
-            lambda vel, chi, tab1: (
-                amr_ops.vorticity_score(geom, vel, tab1),
-                amr_ops.gradchi_mask(geom, chi, tab1),
-            ),
-            self._tab1, name="scores",
+    def _rebuild(self):
+        """Tables, view and binding of the sharded forest (``mesh=``);
+        single-device runs go to _rebuild_bucketed."""
+        if self.mesh is None:
+            return self._rebuild_bucketed()
+        from cup3d_tpu.parallel.forest import (
+            bind_step_executable,
+            cached_forest,
         )
 
-        if cfg.pipelined:
-            self._build_megastep(geom)
-
-        self._moments = jit_bound(
-            lambda chis, vel, cms, xc, vol: jnp.stack(
-                [
-                    pack_moments(
-                        momentum_integrals_core(xc, vol, c, vel, cms[i])
-                    )
-                    for i, c in enumerate(chis)
-                ]
-            ),
-            self._xc, self._vol, name="moments",
+        # the forest keeps the host tagging decision
+        self._device_tags = None
+        g = self.grid
+        cfg = self.cfg
+        # within-signature regrids (the ping-pong A->B->A pattern)
+        # rebind the memoized executable bundle: zero retraces, zero
+        # table rebuilds (parallel/forest.py cached_forest shares
+        # the key discipline)
+        sig = g.signature
+        memo = self._forest_memo.get(sig)
+        if memo is not None:
+            for k, v in memo.items():
+                setattr(self, k, v)
+            return
+        self.forest = cached_forest(g, self.mesh)
+        self._geom = self.forest.geom
+        # round 4: mesh mode runs the face-slab fast path too
+        # (parallel/faces.py; falls back to per-ghost lab tables only
+        # on degenerate closed-boundary topologies)
+        self._tab1 = self.forest.face_tables(1)
+        self._tab3 = self.forest.face_tables(3)
+        self._ftab = self.forest.flux_tables
+        self._solver = self.forest.build_poisson_solver(
+            tol_abs=cfg.poissonTol, tol_rel=cfg.poissonTolRel,
+            mean_constraint=cfg.bMeanConstraint,
         )
-
-        self._maxu = _maxu_j
-
+        # padded geometry arrays; cell volume is 0 on padding blocks so
+        # every volume-weighted reduction ignores them, and the padding
+        # rows of all fields are kept at 0 (labs of padding blocks
+        # assemble to zero, so operators never write garbage there)
+        self._vol = jnp.asarray(self.forest.vol, self.dtype)
+        self._h_col = self._pad(
+            jnp.asarray(g.h.reshape(g.nb, 1, 1, 1), self.dtype)
+        )
+        self._xc = self._pad(jnp.asarray(g.cell_centers(self.dtype)))
+        self._real_mask = jnp.asarray(self.forest.pmask, self.dtype)
+        profile = vol_total = None
         if cfg.bFixMassFlux:
-            # FixMassFlux on the forest (reference avgUx_nonUniform +
-            # parabolic add, main.cpp:12199-12249): volume-weighted mean of
-            # u+uinf, then u += delta * 6 eta(1-eta) (exact restoration;
-            # see sim/operators.py FixMassFlux for the documented
-            # divergence from the reference's 6x-amplifying constant)
             vol_total = float(np.sum(g.h**3) * g.bs**3)
             eta = jnp.asarray(
                 (self._xc[..., 1] / g.extent[1]), self.dtype
             )
-            profile = 6.0 * eta * (1.0 - eta)
-            if self._real_mask is not None:
-                # (nb_pad,1,1,1) mask broadcasts over the (nb_pad,8,8,8)
-                # profile; padding rows stay 0
-                profile = profile * self._real_mask
+            # (nb_pad,1,1,1) mask broadcasts over the (nb_pad,8,8,8)
+            # profile; padding rows stay 0
+            profile = 6.0 * eta * (1.0 - eta) * self._real_mask
+        # The sharded forest's duck-typed tables are not pytrees, so the
+        # view is built here, once per octree signature, and the bound
+        # executables close over it (per-shard scale is bounded).  What
+        # this binder has always computed differently from the bucketed
+        # one stays in the view: host-float vol_total, the constant
+        # acceleration unmasked, the stats-less solver, helm's own geom.
+        self._view = GeomView(
+            geom=self._geom, sol=self._solver, tab1=self._tab1,
+            tab3=self._tab3, ftab=self._ftab, vol=self._vol, xc=self._xc,
+            profile=profile, vol_total=vol_total,
+        )
+        # round 21: forest-bound executables persist in the AOT store
+        # under (octree signature + closure-content) keys — equal keys
+        # guarantee bitwise-equal bound tables, so a restarted process
+        # reloads the serialized executable instead of retracing
+        aot_sig = self._aot_content_sig(sig)
+        bodies = self._step_bodies()
 
-            def fix_flux(vel, uinf_x, u_target):
-                u_msr = (
-                    jnp.sum((vel[..., 0] + uinf_x) * self._vol) / vol_total
-                )
-                delta = u_target - u_msr
-                return vel.at[..., 0].add(delta * profile), u_msr
+        def bind(name, donate=()):
+            # the jit construction site lives in parallel/forest.py
+            # (bind_step_executable), outside the adaptation path: a NEW
+            # octree signature binds once and the bundle rides
+            # _forest_memo after (zero steady-state retraces across the
+            # regrid ping-pong — the JX007 burn-down)
+            label = ("advdiff_imp" if name == "advdiff"
+                     and cfg.implicitDiffusion else name)
+            return bind_step_executable(
+                getattr(bodies, name), self._view, donate=donate,
+                name=label, store_sig=aot_sig)
 
-            # jit construction via parallel/forest.bind_step_executable
-            # (the JX007 burn-down): closes over this layout's profile +
-            # vol_total; a NEW forest topology binds once and joins the
-            # signature memo below; the legacy single-device path
-            # retraces per regrid as the bucketing equivalence baseline
-            from cup3d_tpu.parallel.forest import bind_step_executable
-
-            self._fix_flux = bind_step_executable(
-                fix_flux, name="fix_flux", store_sig=aot_sig)
-
-        if self.mesh is not None:
-            self._forest_memo.put(sig, {
-                k: getattr(self, k) for k in _FOREST_EXEC_ATTRS
-                if hasattr(self, k)
-            })
+        for name, donate in _STEP_KERNELS.items():
+            setattr(self, "_" + name, bind(name, donate))
+        if cfg.bFixMassFlux:
+            self._fix_flux = bind("fix_flux")
+        self._penalize = _penalize_j
+        self._maxu = _maxu_j
+        if cfg.pipelined:
+            self._build_megastep()
+        self._forest_memo.put(sig, {
+            k: getattr(self, k) for k in _FOREST_EXEC_ATTRS
+            if hasattr(self, k)
+        })
 
     # -- capacity-bucketed rebuild (the single-device production path) -----
 
     def _rebuild_bucketed(self):
-        """Bucketed twin of _rebuild (module doc): pad every topology
-        artifact to the capacity ladder, memoize the padded bundle by
-        octree signature, and bind jitted executables from the
-        compiled-step cache keyed on (capacity, table treedef + shapes,
-        donation signature) — a regrid inside a bucket reuses them all.
-        """
+        """Tables and binding of the single device (module doc): pad
+        every topology artifact to the capacity ladder, memoize the
+        padded bundle by octree signature, and bind jitted executables
+        from the compiled-step cache keyed on (capacity, table treedef +
+        shapes, donation signature) — a regrid inside a bucket reuses
+        them all."""
         g, cfg = self.grid, self.cfg
         self.forest = None
         from cup3d_tpu.grid.faces import pad_face_tables
@@ -802,7 +672,7 @@ class AMRSimulation:
             self._exec_cache[key] = ex
         self._bind_bucket_executables(ex)
         if cfg.pipelined:
-            self._build_megastep(self._geom)
+            self._build_megastep()
 
     def _geo_args(self):
         """The canonical traced-geometry bundle every bucketed
@@ -821,212 +691,87 @@ class AMRSimulation:
         shapes = tuple((tuple(l.shape), str(l.dtype)) for l in leaves)
         return (self._cap, treedef, shapes)
 
-    def _build_bucket_executables(self):
-        """jit the step kernels ONCE per bucket.  Every function takes
-        the _geo_args bundle as trailing traced arguments and rebuilds
-        its geometry view (_ArgGeom) inside the trace — no topology
-        constants in the HLO, so the compiled executables serve every
-        regrid whose bucket key matches."""
-        cfg = self.cfg
-        nu = self.nu
-        bs = self.grid.bs
-        cap = self._cap
-        extent = self.grid.extent
+    def _view_of(self):
+        """``view_of(geo)``: the GeomView of one _geo_args bundle, for
+        use INSIDE a trace — geometry (_ArgGeom) and Poisson solve are
+        rebuilt from the traced arrays, so the compiled executables
+        carry no topology constant and serve every regrid whose bucket
+        key matches."""
+        bs, cap, extent = self.grid.bs, self._cap, self.grid.extent
         solver_core = self._solver_core
+        fix_mass_flux = self.cfg.bFixMassFlux
 
-        def geom_of(h):
-            return _ArgGeom(bs, cap, h, extent)
-
-        def solver_for(geo):
-            _, _, _, h, vol, _, mask, graph, slot0, _ = geo
-            return partial(solver_core, geom=geom_of(h), vol=vol,
-                           pmask=mask, graph=graph, slot0=slot0)
-
-        helm = None
-        if cfg.implicitDiffusion:
-            from cup3d_tpu.ops import diffusion as dif
-
-            # closure tables are dead weight: callers pass tab_arg/
-            # flux_arg + geom, so the built solve carries no topology
-            helm = dif.build_amr_helmholtz_solver(
-                self.grid, tol_abs=cfg.diffusionTol,
-                tol_rel=cfg.diffusionTolRel, tab=self._tab1,
-                flux_tab=self._ftab,
+        def view_of(geo):
+            tab1, tab3, ftab, h, vol, xc, mask, graph, slot0, profile = geo
+            g_ = _ArgGeom(bs, cap, h, extent)
+            return GeomView(
+                geom=g_,
+                sol=partial(solver_core, geom=g_, vol=vol, pmask=mask,
+                            graph=graph, slot0=slot0),
+                tab1=tab1, tab3=tab3, ftab=ftab, vol=vol, xc=xc, mask=mask,
+                profile=profile,
+                vol_total=jnp.sum(vol) * bs**3 if fix_mass_flux else None,
+                helm_geom=g_,
             )
 
-        ex = {}
+        return view_of
 
-        def advdiff(vel, dt, uinf, *geo):
-            tab1, tab3, ftab, h = geo[0], geo[1], geo[2], geo[3]
-            g_ = geom_of(h)
-            if cfg.implicitDiffusion:
-                from cup3d_tpu.ops import diffusion as dif
+    def _geo_binder(self):
+        """The bucketed binder: ``jit_geo(body, name, donate)`` jits
+        ``body(*state, view_of(geo))`` with the _geo_args bundle as
+        trailing traced arguments (unused entries are DCE'd by XLA).
+        ``donate`` names the state argnums the caller rebinds from the
+        return value (JX002 burn-down)."""
+        view_of = self._view_of()
+        n_geo = len(self._geo_args())
 
-                return dif.implicit_step_blocks(
-                    g_, vel, dt, nu, uinf, tab3,
-                    lambda u, nudt: helm(u, nudt, tab_arg=tab1,
-                                         flux_arg=ftab, geom=g_),
-                )
-            return amr_ops.rk3_step_blocks(g_, vel, dt, nu, uinf, tab3,
-                                           ftab)
+        def jit_geo(body, name, donate=(), **kw):
+            def fn(*a):
+                return body(*a[:-n_geo], view_of(a[-n_geo:]), **kw)
 
-        ex["advdiff"] = jax.jit(advdiff, donate_argnums=(0,))
+            fn.__name__ = name
+            return jax.jit(fn, donate_argnums=donate)
 
-        def make_project(so):
-            def project(vel, dt, chi, udef, p_old, *geo):
-                g_ = geom_of(geo[3])
-                return amr_ops.project_blocks(
-                    g_, vel, dt, solver_for(geo), geo[0], geo[2], chi,
-                    udef, p_init=p_old, second_order=so, with_stats=True,
-                )
-            project.__name__ = "project_2nd" if so else "project"
-            return jax.jit(project, donate_argnums=(0, 4))
+        return jit_geo
 
-        ex["project"] = make_project(False)
-        ex["project_2nd"] = make_project(True)
-
-        def penal_force(vn, vo, chis, dt, cms, *geo):
-            return per_obstacle_penalization_force(
-                vn, vo, chis, dt, geo[4], geo[5], cms
-            )
-
-        ex["penal_force"] = jax.jit(penal_force)
-
-        def ubody(udef, cm, ut, om, *geo):
-            xc = geo[5]
-            return (ut + jnp.cross(jnp.broadcast_to(om, xc.shape),
-                                   xc - cm) + udef)
-
-        ex["ubody"] = jax.jit(ubody)
-
-        def divnorms(vel, *geo):
-            return amr_ops.divergence_norms_blocks(
-                geom_of(geo[3]), vel, geo[0]
-            )
-
-        ex["divnorms"] = jax.jit(divnorms)
-
-        def dissipation(vel, *geo):
-            return amr_ops.dissipation_blocks(geom_of(geo[3]), vel, nu,
-                                              geo[0])
-
-        ex["dissipation"] = jax.jit(dissipation)
-
-        def gradchi(chi, *geo):
-            tab1 = geo[0]
-            return amr_ops.grad_blocks(
-                geom_of(geo[3]), tab1.assemble_scalar(chi, bs), tab1.width
-            )
-
-        ex["gradchi"] = jax.jit(gradchi)
-
-        def omega_mag(vel, *geo):
-            tab1 = geo[0]
-            return jnp.sqrt(jnp.sum(
-                amr_ops.curl_blocks(
-                    geom_of(geo[3]), tab1.assemble_vector(vel, bs),
-                    tab1.width
-                ) ** 2,
-                axis=-1,
-            ))
-
-        # jax-lint: allow(JX002, diagnostic over a persistent field (the
-        # name matches the step regex via omega, not megastep))
-        ex["omega_mag"] = jax.jit(omega_mag)
-
-        def scores(vel, chi, *geo):
-            g_ = geom_of(geo[3])
-            return (amr_ops.vorticity_score(g_, vel, geo[0]),
-                    amr_ops.gradchi_mask(g_, chi, geo[0]))
-
-        ex["scores"] = jax.jit(scores)
-
-        def tags(vel, chi, level, *geo):
-            # on-device regrid DECISION: scores -> per-slot int8 tag in
-            # one dispatch, so adapt_mesh downloads (cap,) bytes instead
-            # of two full score fields (grid/adapt.py device_tags)
-            g_ = geom_of(geo[3])
-            vort = amr_ops.vorticity_score(g_, vel, geo[0])
-            near = amr_ops.gradchi_mask(g_, chi, geo[0])
-            return ad.device_tags(
-                vort, near, level, cfg.Rtol, cfg.Ctol,
-                cfg.levelMax, cfg.levelMaxVorticity,
-                bool(cfg.bAdaptChiGradient),
-            )
-
-        ex["tags"] = jax.jit(tags)
-
-        def moments(chis, vel, cms, *geo):
-            vol, xc = geo[4], geo[5]
-            return jnp.stack([
-                pack_moments(
-                    momentum_integrals_core(xc, vol, c, vel, cms[i])
-                )
-                for i, c in enumerate(chis)
-            ])
-
-        ex["moments"] = jax.jit(moments)
-
-        if cfg.bFixMassFlux:
-            def fix_flux(vel, uinf_x, u_target, *geo):
-                vol, profile = geo[4], geo[9]
-                vol_total = jnp.sum(vol) * bs**3
-                u_msr = (
-                    jnp.sum((vel[..., 0] + uinf_x) * vol) / vol_total
-                )
-                delta = u_target - u_msr
-                return vel.at[..., 0].add(delta * profile), u_msr
-
-            ex["fix_flux"] = jax.jit(fix_flux, donate_argnums=(0,))
+    def _build_bucket_executables(self):
+        """jit the step kernels ONCE per bucket."""
+        bodies = self._step_bodies()
+        jit_geo = self._geo_binder()
+        ex = {
+            name: jit_geo(getattr(bodies, name), name, donate)
+            for name, donate in _STEP_KERNELS.items()
+        }
+        ex["tags"] = jit_geo(bodies.tags, "tags")
+        if self.cfg.bFixMassFlux:
+            ex["fix_flux"] = jit_geo(bodies.fix_flux, "fix_flux", (0,))
         return ex
 
     def _bind_bucket_executables(self, ex):
         geo = self._geo_args
-        self._advdiff = (
-            lambda vel, dt, uinf: ex["advdiff"](vel, dt, uinf, *geo())
-        )
-        self._project = (
-            lambda vel, dt, chi, udef, p:
-            ex["project"](vel, dt, chi, udef, p, *geo())
-        )
-        self._project_2nd = (
-            lambda vel, dt, chi, udef, p:
-            ex["project_2nd"](vel, dt, chi, udef, p, *geo())
-        )
-        self._penalize = _penalize_j
-        self._penal_force = (
-            lambda vn, vo, chis, dt, cms:
-            ex["penal_force"](vn, vo, chis, dt, cms, *geo())
-        )
-        self._ubody = (
-            lambda udef, cm, ut, om:
-            ex["ubody"](udef, cm, ut, om, *geo())
-        )
-        self._divnorms = lambda vel: ex["divnorms"](vel, *geo())
-        self._dissipation = lambda vel: ex["dissipation"](vel, *geo())
-        self._gradchi = lambda chi: ex["gradchi"](chi, *geo())
-        self._omega_mag = lambda vel: ex["omega_mag"](vel, *geo())
-        self._scores = lambda vel, chi: ex["scores"](vel, chi, *geo())
+
+        def bound(fn):
+            return lambda *a: fn(*a, *geo())
+
+        for name in _STEP_KERNELS:
+            setattr(self, "_" + name, bound(ex[name]))
         self._device_tags = (
             lambda vel, chi:
             ex["tags"](vel, chi, self._level_arr, *geo())
         )
-        self._moments = (
-            lambda chis, vel, cms: ex["moments"](chis, vel, cms, *geo())
-        )
-        self._maxu = _maxu_j
         if self.cfg.bFixMassFlux:
-            self._fix_flux = (
-                lambda vel, ux, ut: ex["fix_flux"](vel, ux, ut, *geo())
-            )
+            self._fix_flux = bound(ex["fix_flux"])
+        self._penalize = _penalize_j
+        self._maxu = _maxu_j
 
-    # -- pipelined megastep (single-device fast path) ----------------------
+    # -- pipelined megastep ------------------------------------------------
 
-    def _build_megastep(self, geom):
-        """ONE jitted function for the whole obstacle step: advection ->
-        vmapped device rigid update -> penalization -> projection -> force
-        QoI -> packed read vector.  The AMR twin of the uniform driver's
-        device fast path (models/pipeline.py UpdateObstacles +
+    def _build_megastep(self):
+        """ONE jitted function for the whole obstacle step (amr_step.py
+        ``mega``): advection -> vmapped device rigid update ->
+        penalization -> projection -> force QoI -> packed read vector.
+        The AMR twin of the uniform driver's device fast path
+        (models/pipeline.py UpdateObstacles +
         models/base.rigid_update_device), generalized to MULTI-obstacle by
         vmapping the rigid update; collision response stays host-side via a
         stale overlap pre-check in the pack (see advance_pipelined).
@@ -1035,454 +780,63 @@ class AMRSimulation:
         has a host cost and every blocking device->host read stalls the
         dispatch queue; the non-pipelined AMR step pays ~15 dispatches +
         2 blocking reads of pure latency.  This path pays ~1 dispatch and
-        reads one pack, one step late, on a worker thread."""
-        if self.forest is None and self._bucketing:
-            return self._build_megastep_bucketed()
-        from cup3d_tpu.models.base import (
-            pack_forces, pack_moments, rigid_update_device,
-        )
-        from cup3d_tpu.models.collisions import overlap_count
+        reads one pack, one step late, on a worker thread.
+
+        Single device: the jits live in the compiled-step cache keyed by
+        (bucket, probe budgets, n_obs), with all topology data as traced
+        args — regrids within a bucket AND ping-pong probe-budget moves
+        reuse compiled executables.  Forest: bound to this signature's
+        view through parallel/forest.py, once per pressure order."""
         from cup3d_tpu.ops.surface import obstacle_probe_budget
 
-        cfg = self.cfg
         g = self.grid
-        nu = self.nu
         # probe slot budgets are STATIC inside the trace: snapshot them at
         # build time and let advance_pipelined trigger a rebuild when the
         # adaptive budget moves (code-review r4 — without this, a
         # static-mesh run freezes the generous pre-measurement prior)
-        hf0 = float(g.h0 / (1 << (len(g._slot_maps) - 1)))
+        hf0 = self._h_finest()
         self._megastep_budgets = tuple(
             obstacle_probe_budget(ob, hf0) for ob in self.obstacles
         )
-        rigid_vmapped = jax.vmap(
-            rigid_update_device, in_axes=(0, 0, 0, 0, None, None)
-        )
-        if cfg.bFixMassFlux:
-            vol_total = float(np.sum(g.h**3) * g.bs**3)
-            eta = jnp.asarray((self._xc[..., 1] / g.extent[1]), self.dtype)
-            profile_arr = 6.0 * eta * (1.0 - eta)
+        if self.forest is not None:
+            from cup3d_tpu.parallel.forest import bind_order_executables
+
+            bodies = self._step_bodies(self._megastep_budgets)
+            # (first, second pressure order) per body; vel, p donated
+            jits, jits_free = (
+                bind_order_executables(
+                    body, (self._view,), donate=(0, 1),
+                    store_sig=self._aot_content_sig(g.signature))
+                for body in (bodies.mega, bodies.mega_free)
+            )
+
+            def geo():  # the view is closed over: no trailing args
+                return ()
         else:
-            profile_arr = jnp.zeros((), self.dtype)  # unused placeholder
-        helm = None
-        if cfg.implicitDiffusion:
-            from cup3d_tpu.ops import diffusion as dif
-
-            # the captured tables are fallbacks only: the traced tab1/ftab
-            # arguments flow through helm's tab_arg/flux_arg at call time
-            helm = dif.build_amr_helmholtz_solver(
-                geom, tol_abs=cfg.diffusionTol, tol_rel=cfg.diffusionTolRel,
-                tab=self._tab1, flux_tab=self._ftab,
-            )
-
-        h_fine = float(g.h0 / (1 << (len(g._slot_maps) - 1)))
-
-        def advdiff_stage(vel, uinf, dt, tab1, tab3, ftab):
-            """Advection-diffusion honoring cfg.implicitDiffusion — shared
-            by the obstacle and obstacle-free megasteps."""
-            if cfg.implicitDiffusion:
-                from cup3d_tpu.ops import diffusion as dif
-
-                return dif.implicit_step_blocks(
-                    geom, vel, dt, nu, uinf, tab3,
-                    lambda u, nudt: helm(
-                        u, nudt, tab_arg=tab1, flux_arg=ftab
-                    ),
+            key = ("mega", self._bucket_key(), self._megastep_budgets,
+                   len(self.obstacles), bool(self.cfg.bFixMassFlux))
+            ex = self._exec_cache.get(key)
+            if ex is None:
+                bodies = self._step_bodies(self._megastep_budgets)
+                jit_geo = self._geo_binder()
+                ex = tuple(
+                    tuple(jit_geo(body, name + ("_2nd" if so else ""),
+                                  (0, 1), second_order=so)
+                          for so in (False, True))
+                    for name, body in (("mega", bodies.mega),
+                                       ("mega_free", bodies.mega_free))
                 )
-            return amr_ops.rk3_step_blocks(geom, vel, dt, nu, uinf, tab3,
-                                           ftab)
-
-        def forcing_stage(vel, uinf, dt, vol, profile):
-            """FixMassFlux / uMax_forced forcing — shared by both
-            megasteps.  Returns (vel, flux_msr (1,))."""
-            flux_msr = jnp.zeros(1, self.dtype)
-            if cfg.bFixMassFlux:
-                u_target = 2.0 / 3.0 * cfg.uMax_forced
-                u_msr = jnp.sum((vel[..., 0] + uinf[0]) * vol) / vol_total
-                vel = vel.at[..., 0].add((u_target - u_msr) * profile)
-                flux_msr = u_msr.reshape(1)
-            elif cfg.uMax_forced > 0:
-                H = g.extent[1]
-                accel = 8.0 * nu * cfg.uMax_forced / (H * H)
-                vel = vel.at[..., 0].add(accel * dt)
-            return vel, flux_msr
-
-        def mega(vel, p, chis, udefs, sdfs, rigid, forced, blocked,
-                 fixmask, slots, b0s, uinf, dt, lam, tab1, tab3, ftab,
-                 xc, vol, profile, second_order):
-            n_obs = chis.shape[0]
-            chi, udef = combine_obstacle_fields(chis, udefs)
-
-            vel = advdiff_stage(vel, uinf, dt, tab1, tab3, ftab)
-
-            # rigid update on device, all obstacles at once
-            cms = rigid[:, 12:15]
-            M = jnp.stack(
-                [
-                    pack_moments(
-                        momentum_integrals_core(xc, vol, chis[i], vel, cms[i])
-                    )
-                    for i in range(n_obs)
-                ]
-            )
-            out = rigid_vmapped(M, rigid, forced, blocked, uinf, dt)
-            cm_new = out[:, 12:15]
-            ub = (
-                out[:, None, None, None, None, 0:3]
-                + jnp.cross(
-                    jnp.broadcast_to(
-                        out[:, None, None, None, None, 3:6], udefs.shape
-                    ),
-                    xc[None] - out[:, None, None, None, None, 12:15],
-                )
-                + udefs
-            )  # (n_obs, nb, bs,bs,bs, 3)
-            den = jnp.maximum(jnp.sum(chis, axis=0), _EPS)[..., None]
-            ubody = jnp.sum(chis[..., None] * ub, axis=0) / den
-
-            vel_old = vel
-            vel = penalize(vel, chi, ubody, lam, dt)
-            PF = -per_obstacle_penalization_force(
-                vel, vel_old, tuple(chis[i] for i in range(n_obs)),
-                dt, vol, xc, cm_new,
-            )
-
-            vel, flux_msr = forcing_stage(vel, uinf, dt, vol, profile)
-
-            vel, p = amr_ops.project_blocks(
-                geom, vel, dt, self._solver, tab1, ftab, chi, udef,
-                p_init=p, second_order=second_order,
-            )
-
-            # surface-point probe per obstacle (ops/surface.py): the
-            # production force measure, on the obstacle's dense window,
-            # compacted to a static per-obstacle point budget
-            from cup3d_tpu.ops.surface import (
-                obstacle_probe_budget, probe_blocks_core,
-            )
-
-            F = jnp.stack(
-                [
-                    pack_forces(
-                        probe_blocks_core(
-                            vel, p, chis[i], sdfs[i], udefs[i],
-                            slots[i], b0s[i],
-                            jnp.asarray(h_fine, vel.dtype), nu,
-                            cm_new[i], out[i, 0:3], out[i, 3:6],
-                            max_points=self._megastep_budgets[i],
-                        )
-                    )
-                    for i in range(n_obs)
-                ]
-            )
-
-            pairs = [
-                (i, j) for i in range(n_obs) for j in range(i + 1, n_obs)
-            ]
-            overlaps = (
-                jnp.stack(
-                    [
-                        overlap_count(chis[i], chis[j]).astype(self.dtype)
-                        for i, j in pairs
-                    ]
-                )
-                if pairs
-                else jnp.zeros(0, self.dtype)
-            )
-
-            # next step's frame velocity from the NEW rigid state, so the
-            # device chain matches non-pipelined uinf semantics exactly
-            nfix = jnp.sum(fixmask)
-            mean_tv = jnp.sum(
-                out[:, 0:3] * fixmask[:, None], axis=0
-            ) / jnp.maximum(nfix, 1.0)
-            uinf_next = jnp.where(nfix > 0, -mean_tv, uinf)
-            umax = jnp.maximum(
-                jnp.max(jnp.abs(vel + uinf_next)),
-                jnp.max(jnp.abs(udef)),
-            ).reshape(1)
-            pack = jnp.concatenate(
-                [out.reshape(-1), PF.reshape(-1).astype(self.dtype),
-                 F.reshape(-1), overlaps, flux_msr, umax]
-            )
-            return vel, p, chi, udef, uinf_next, pack
-
-        # tables AND field-sized geometry (cell centers, volumes, forcing
-        # profile) travel as jit ARGUMENTS, not closure constants — the
-        # compile-payload rule of _rebuild applies here too.  The sharded
-        # forest's duck-typed tables are NOT pytrees, so the mesh path
-        # keeps the closure style (its per-shard scale is bounded).
-        def order_dispatch(fn, tabs, donate=()):
-            """jit fn once per pressure order; pick by step index at call
-            time.  Forest mode closes over the (non-pytree) tables;
-            single-device passes them as traced call args.  ``donate``
-            names the caller-facing state argnums (vel/p) the megastep
-            rebinds from its outputs (JX002 burn-down)."""
-            if self.forest is not None:
-                # jit construction delegated to parallel/forest.py
-                # (bind_order_executables): once per NEW signature, then
-                # _forest_memo — the JX007 burn-down, as in jit_bound
-                from cup3d_tpu.parallel.forest import (
-                    bind_order_executables,
-                )
-
-                jits = bind_order_executables(
-                    fn, tabs, donate=donate,
-                    store_sig=self._aot_content_sig(self.grid.signature))
-                return lambda *a: jits[
-                    self.step_idx >= self.cfg.step_2nd_start
-                ](*a)
-            # jax-lint: allow(JX007, legacy CUP3D_BUCKET=0 equivalence
-            # baseline; production single-device megasteps come from the
-            # compiled-step cache in _build_megastep_bucketed)
-            jits = [jax.jit(partial(fn, second_order=so),
-                            donate_argnums=donate)
-                    for so in (False, True)]
-            return lambda *a: jits[
-                self.step_idx >= self.cfg.step_2nd_start
-            ](*a, *tabs)
-
-        self._megastep = order_dispatch(
-            mega, (self._tab1, self._tab3, self._ftab, self._xc,
-                   self._vol, profile_arr),
-            donate=(0, 1),  # vel, p -> vel, p
-        )
-
-        # obstacle-free fused step (amr_tgv-style runs): advection +
-        # forcing + projection + max|u| in one dispatch, same pack scheme
-        def mega_free(vel, p, uinf, dt, tab1, tab3, ftab, vol, profile,
-                      second_order):
-            vel = advdiff_stage(vel, uinf, dt, tab1, tab3, ftab)
-            vel, flux_msr = forcing_stage(vel, uinf, dt, vol, profile)
-            vel, p = amr_ops.project_blocks(
-                geom, vel, dt, self._solver, tab1, ftab,
-                p_init=p, second_order=second_order,
-            )
-            umax = jnp.max(jnp.abs(vel + uinf)).reshape(1)
-            pack = jnp.concatenate([flux_msr, umax])
-            return vel, p, pack
-
-        self._megastep_free = order_dispatch(
-            mega_free, (self._tab1, self._tab3, self._ftab, self._vol,
-                        profile_arr),
-            donate=(0, 1),  # vel, p -> vel, p
-        )
-
-    def _build_megastep_bucketed(self):
-        """Bucketed twin of _build_megastep: the megastep jits live in
-        the compiled-step cache keyed by (bucket, probe budgets, n_obs),
-        with all topology data as traced args — regrids within a bucket
-        AND ping-pong probe-budget moves reuse compiled executables."""
-        from cup3d_tpu.ops.surface import obstacle_probe_budget
-
-        g = self.grid
-        hf0 = float(g.h0 / (1 << (len(g._slot_maps) - 1)))
-        self._megastep_budgets = tuple(
-            obstacle_probe_budget(ob, hf0) for ob in self.obstacles
-        )
-        key = ("mega", self._bucket_key(), self._megastep_budgets,
-               len(self.obstacles), bool(self.cfg.bFixMassFlux))
-        ex = self._exec_cache.get(key)
-        if ex is None:
-            ex = self._build_megastep_executables(self._megastep_budgets)
-            self._exec_cache[key] = ex
-        jits, jits_free = ex
+                self._exec_cache[key] = ex
+            jits, jits_free = ex
+            geo = self._geo_args
+        # the order switch is two cached executables, picked by step
+        # index at call time
         self._megastep = lambda *a: jits[
             int(self.step_idx >= self.cfg.step_2nd_start)
-        ](*a, *self._geo_args())
+        ](*a, *geo())
         self._megastep_free = lambda *a: jits_free[
             int(self.step_idx >= self.cfg.step_2nd_start)
-        ](*a, *self._geo_args())
-
-    def _build_megastep_executables(self, budgets):
-        """The megastep bodies of _build_megastep with every topology
-        array drawn from the traced _geo_args bundle (geometry view
-        rebuilt inside the trace, solver bound per call)."""
-        from cup3d_tpu.models.base import (
-            pack_forces, pack_moments, rigid_update_device,
-        )
-        from cup3d_tpu.models.collisions import overlap_count
-        from cup3d_tpu.ops.surface import probe_blocks_core
-
-        cfg = self.cfg
-        g = self.grid
-        nu = self.nu
-        bs = g.bs
-        cap = self._cap
-        extent = g.extent
-        dtype = self.dtype
-        solver_core = self._solver_core
-        h_fine = float(g.h0 / (1 << (len(g._slot_maps) - 1)))
-        rigid_vmapped = jax.vmap(
-            rigid_update_device, in_axes=(0, 0, 0, 0, None, None)
-        )
-        helm = None
-        if cfg.implicitDiffusion:
-            from cup3d_tpu.ops import diffusion as dif
-
-            helm = dif.build_amr_helmholtz_solver(
-                g, tol_abs=cfg.diffusionTol, tol_rel=cfg.diffusionTolRel,
-                tab=self._tab1, flux_tab=self._ftab,
-            )
-
-        def geom_of(h):
-            return _ArgGeom(bs, cap, h, extent)
-
-        def advdiff_stage(g_, vel, uinf, dt, tab1, tab3, ftab):
-            if cfg.implicitDiffusion:
-                from cup3d_tpu.ops import diffusion as dif
-
-                return dif.implicit_step_blocks(
-                    g_, vel, dt, nu, uinf, tab3,
-                    lambda u, nudt: helm(u, nudt, tab_arg=tab1,
-                                         flux_arg=ftab, geom=g_),
-                )
-            return amr_ops.rk3_step_blocks(g_, vel, dt, nu, uinf, tab3,
-                                           ftab)
-
-        def forcing_stage(vel, uinf, dt, vol, mask, profile):
-            """FixMassFlux / uMax_forced forcing; padding rows stay 0
-            (profile carries the real-block mask; the constant
-            acceleration is masked explicitly)."""
-            flux_msr = jnp.zeros(1, dtype)
-            if cfg.bFixMassFlux:
-                vol_total = jnp.sum(vol) * bs**3
-                u_target = 2.0 / 3.0 * cfg.uMax_forced
-                u_msr = jnp.sum((vel[..., 0] + uinf[0]) * vol) / vol_total
-                vel = vel.at[..., 0].add((u_target - u_msr) * profile)
-                flux_msr = u_msr.reshape(1)
-            elif cfg.uMax_forced > 0:
-                H = extent[1]
-                accel = 8.0 * nu * cfg.uMax_forced / (H * H)
-                vel = vel.at[..., 0].add(accel * dt * mask)
-            return vel, flux_msr
-
-        def make_mega(so):
-            def mega(vel, p, chis, udefs, sdfs, rigid, forced, blocked,
-                     fixmask, slots, b0s, uinf, dt, lam, *geo):
-                (tab1, tab3, ftab, h, vol, xc, mask, graph, slot0,
-                 profile) = geo
-                g_ = geom_of(h)
-                sol = partial(solver_core, geom=g_, vol=vol, pmask=mask,
-                              graph=graph, slot0=slot0)
-                n_obs = chis.shape[0]
-                chi, udef = combine_obstacle_fields(chis, udefs)
-
-                vel = advdiff_stage(g_, vel, uinf, dt, tab1, tab3, ftab)
-
-                cms = rigid[:, 12:15]
-                M = jnp.stack(
-                    [
-                        pack_moments(
-                            momentum_integrals_core(
-                                xc, vol, chis[i], vel, cms[i]
-                            )
-                        )
-                        for i in range(n_obs)
-                    ]
-                )
-                out = rigid_vmapped(M, rigid, forced, blocked, uinf, dt)
-                cm_new = out[:, 12:15]
-                ub = (
-                    out[:, None, None, None, None, 0:3]
-                    + jnp.cross(
-                        jnp.broadcast_to(
-                            out[:, None, None, None, None, 3:6],
-                            udefs.shape
-                        ),
-                        xc[None] - out[:, None, None, None, None, 12:15],
-                    )
-                    + udefs
-                )
-                den = jnp.maximum(jnp.sum(chis, axis=0), _EPS)[..., None]
-                ubody = jnp.sum(chis[..., None] * ub, axis=0) / den
-
-                vel_old = vel
-                vel = penalize(vel, chi, ubody, lam, dt)
-                PF = -per_obstacle_penalization_force(
-                    vel, vel_old, tuple(chis[i] for i in range(n_obs)),
-                    dt, vol, xc, cm_new,
-                )
-
-                vel, flux_msr = forcing_stage(vel, uinf, dt, vol, mask,
-                                              profile)
-
-                vel, p = amr_ops.project_blocks(
-                    g_, vel, dt, sol, tab1, ftab, chi, udef,
-                    p_init=p, second_order=so,
-                )
-
-                F = jnp.stack(
-                    [
-                        pack_forces(
-                            probe_blocks_core(
-                                vel, p, chis[i], sdfs[i], udefs[i],
-                                slots[i], b0s[i],
-                                jnp.asarray(h_fine, vel.dtype), nu,
-                                cm_new[i], out[i, 0:3], out[i, 3:6],
-                                max_points=budgets[i],
-                            )
-                        )
-                        for i in range(n_obs)
-                    ]
-                )
-
-                pairs = [
-                    (i, j)
-                    for i in range(n_obs) for j in range(i + 1, n_obs)
-                ]
-                overlaps = (
-                    jnp.stack(
-                        [
-                            overlap_count(chis[i], chis[j]).astype(dtype)
-                            for i, j in pairs
-                        ]
-                    )
-                    if pairs
-                    else jnp.zeros(0, dtype)
-                )
-
-                nfix = jnp.sum(fixmask)
-                mean_tv = jnp.sum(
-                    out[:, 0:3] * fixmask[:, None], axis=0
-                ) / jnp.maximum(nfix, 1.0)
-                uinf_next = jnp.where(nfix > 0, -mean_tv, uinf)
-                umax = jnp.maximum(
-                    jnp.max(jnp.abs(vel + uinf_next)),
-                    jnp.max(jnp.abs(udef)),
-                ).reshape(1)
-                pack = jnp.concatenate(
-                    [out.reshape(-1), PF.reshape(-1).astype(dtype),
-                     F.reshape(-1), overlaps, flux_msr, umax]
-                )
-                return vel, p, chi, udef, uinf_next, pack
-
-            mega.__name__ = "mega_2nd" if so else "mega"
-            return jax.jit(mega, donate_argnums=(0, 1))
-
-        def make_mega_free(so):
-            def mega_free(vel, p, uinf, dt, *geo):
-                (tab1, tab3, ftab, h, vol, xc, mask, graph, slot0,
-                 profile) = geo
-                g_ = geom_of(h)
-                sol = partial(solver_core, geom=g_, vol=vol, pmask=mask,
-                              graph=graph, slot0=slot0)
-                vel = advdiff_stage(g_, vel, uinf, dt, tab1, tab3, ftab)
-                vel, flux_msr = forcing_stage(vel, uinf, dt, vol, mask,
-                                              profile)
-                vel, p = amr_ops.project_blocks(
-                    g_, vel, dt, sol, tab1, ftab,
-                    p_init=p, second_order=so,
-                )
-                umax = jnp.max(jnp.abs(vel + uinf)).reshape(1)
-                pack = jnp.concatenate([flux_msr, umax])
-                return vel, p, pack
-
-            mega_free.__name__ = "mega_free_2nd" if so else "mega_free"
-            return jax.jit(mega_free, donate_argnums=(0, 1))
-
-        return ((make_mega(False), make_mega(True)),
-                (make_mega_free(False), make_mega_free(True)))
+        ](*a, *geo())
 
     # -- obstacles ---------------------------------------------------------
 
@@ -1641,8 +995,8 @@ class AMRSimulation:
     def _apply_states(self, states) -> bool:
         """Adaptation tail (plan -> transfer -> rebuild -> repad), split
         from the tagging so tests can force arbitrary regrid cycles
-        (tests/test_bucketing.py drives refine->coarsen->refine through
-        here and asserts the compiled-step cache absorbs them)."""
+        (the capacity-bucket tests drive refine->coarsen->refine through
+        here and assert the compiled-step cache absorbs them)."""
         from cup3d_tpu.obs import metrics as obs_metrics
 
         g = self.grid
@@ -1977,7 +1331,7 @@ class AMRSimulation:
         # the record carries the pre-step topology (nb/bucket) so regrid
         # and bucket transitions are visible across consecutive records
         extra = {"nb": int(self.grid.nb)}
-        if self._bucketing and hasattr(self, "_cap"):
+        if self.forest is None:
             extra["bucket_capacity"] = int(self._cap)
         if self._last_umax is not None:
             extra["umax"] = float(self._last_umax)
@@ -1993,6 +1347,35 @@ class AMRSimulation:
                     late["regrid"] = True
                     late["nb_post"] = int(self.grid.nb)
 
+    def _adapt_due(self, step: int) -> bool:
+        """The adaptation cadence: each of the first 10 steps, then every
+        ADAPT_EVERY (main.cpp:15314)."""
+        return self.adapt_enabled and (
+            step < 10 or step % ADAPT_EVERY == 0
+        )
+
+    def _prefetch_regrid_decision(self):
+        """Pipelined advances, after their megastep: when the NEXT step
+        adapts, dispatch its refinement decision now, so the compute and
+        transfer overlap this step's pack read + host work (staged
+        through the stream so its bytes are counted).  The bucketed path
+        ships (cap,) device tags; the forest ships the raw score
+        fields."""
+        if not self._adapt_due(self.step_idx + 1):
+            return
+        s = self.state
+        if self._device_tags is not None:
+            t = self._device_tags(s["vel"], s["chi"])
+            # -1/0/1 are exact in any float dtype
+            packed = self._pack_reader.stage(t.astype(self.dtype))
+            self._scores_prefetch = (packed, self.grid.nb, "tags")
+        else:
+            vort, near = self._scores(s["vel"], s["chi"])
+            packed = self._pack_reader.stage(jnp.concatenate(
+                [vort.astype(self.dtype), near.astype(self.dtype)]
+            ))
+            self._scores_prefetch = (packed, self.grid.nb, "scores")
+
     def _advance_host(self, dt: float):
         """Non-pipelined stepping (also the collision fallback path)."""
         if self._pack_reader:
@@ -2007,9 +1390,7 @@ class AMRSimulation:
         dt_j = device_scalar(dt, self.dtype, tag="dt-upload")
 
         self._maybe_dump_save()
-        if self.adapt_enabled and (
-            self.step_idx < 10 or self.step_idx % ADAPT_EVERY == 0
-        ):
+        if self._adapt_due(self.step_idx):
             with self.profiler("AdaptMesh"):
                 self.adapt_mesh()
 
@@ -2144,9 +1525,7 @@ class AMRSimulation:
         s = self.state
         dt_j = device_scalar(dt, self.dtype, tag="dt-upload")
         self._maybe_dump_save()
-        if self.adapt_enabled and (
-            self.step_idx < 10 or self.step_idx % ADAPT_EVERY == 0
-        ):
+        if self._adapt_due(self.step_idx):
             with self.profiler("AdaptMesh"):
                 # no flush: packs are immutable device vectors (still
                 # readable after re-layout) and the rigid chains are pure
@@ -2161,12 +1540,12 @@ class AMRSimulation:
         # band growth past the hysteresis window) retrace once
         from cup3d_tpu.ops.surface import obstacle_probe_budget
 
-        hf = float(self.grid.h0 / (1 << (len(self.grid._slot_maps) - 1)))
+        hf = self._h_finest()
         budgets = tuple(
             obstacle_probe_budget(ob, hf) for ob in self.obstacles
         )
         if budgets != self._megastep_budgets:
-            self._build_megastep(self._geom)
+            self._build_megastep()
         with self.profiler("Megastep"):
             n = len(self.obstacles)
             from cup3d_tpu.ops.surface import block_window_slots
@@ -2224,28 +1603,7 @@ class AMRSimulation:
                     "ang": row[3:6], "cm": row[12:15],
                 }
                 ob._ubody_cache = None
-            nxt = self.step_idx + 1
-            if self.adapt_enabled and (
-                nxt < 10 or nxt % ADAPT_EVERY == 0
-            ):
-                # dispatch next step's refinement decision now: the
-                # compute and transfer overlap this step's pack read +
-                # host work (staged through the stream so its bytes are
-                # counted).  Bucketed path ships (cap,) device tags;
-                # forest/legacy ships the raw score fields.
-                if self._device_tags is not None:
-                    t = self._device_tags(s["vel"], s["chi"])
-                    # -1/0/1 are exact in any float dtype
-                    packed = self._pack_reader.stage(t.astype(self.dtype))
-                    self._scores_prefetch = (packed, self.grid.nb, "tags")
-                else:
-                    vort, near = self._scores(s["vel"], s["chi"])
-                    packed = self._pack_reader.stage(jnp.concatenate(
-                        [vort.astype(self.dtype), near.astype(self.dtype)]
-                    ))
-                    self._scores_prefetch = (
-                        packed, self.grid.nb, "scores"
-                    )
+            self._prefetch_regrid_decision()
         self._log_diagnostics()
         with self.profiler("SyncQoI"):
             npairs = n * (n - 1) // 2
@@ -2289,9 +1647,7 @@ class AMRSimulation:
         s = self.state
         dt_j = device_scalar(dt, self.dtype, tag="dt-upload")
         self._maybe_dump_save()
-        if self.adapt_enabled and (
-            self.step_idx < 10 or self.step_idx % ADAPT_EVERY == 0
-        ):
+        if self._adapt_due(self.step_idx):
             with self.profiler("AdaptMesh"):
                 self.adapt_mesh()
         with self.profiler("Megastep"):
@@ -2304,20 +1660,7 @@ class AMRSimulation:
             s["vel"], s["p"] = vel, p
             # device dt chain: next step's CFL scale, never read back
             self._umax_dev = pack[-1]
-            nxt = self.step_idx + 1
-            if self.adapt_enabled and (nxt < 10 or nxt % ADAPT_EVERY == 0):
-                if self._device_tags is not None:
-                    t = self._device_tags(s["vel"], s["chi"])
-                    packed = self._pack_reader.stage(t.astype(self.dtype))
-                    self._scores_prefetch = (packed, self.grid.nb, "tags")
-                else:
-                    vort, near = self._scores(s["vel"], s["chi"])
-                    packed = self._pack_reader.stage(jnp.concatenate(
-                        [vort.astype(self.dtype), near.astype(self.dtype)]
-                    ))
-                    self._scores_prefetch = (
-                        packed, self.grid.nb, "scores"
-                    )
+            self._prefetch_regrid_decision()
         self._log_diagnostics()
         with self.profiler("SyncQoI"):
             self._pack_reader.emit(
@@ -2597,13 +1940,12 @@ def make_amr_tgv_step(sim: "AMRSimulation"):
     no operation reduces across lanes, so the PR 9 isolation contract
     (per-lane NaN containment, bitwise freeze) carries over unchanged.
     """
-    geo = sim._geo_args()
-    tab1, tab3, ftab, h, vol, _, mask, graph, slot0, _ = geo
+    view = sim._view_of()(sim._geo_args())
     cfg, nu, dtype = sim.cfg, sim.nu, sim.dtype
     g = sim.grid
-    g_ = _ArgGeom(g.bs, sim._cap, h, g.extent)
-    sol = partial(sim._solver_core, geom=g_, vol=vol, pmask=mask,
-                  graph=graph, slot0=slot0)
+    # the forest's own advdiff / project bodies, explicit RK3 whatever
+    # the configuration says (fleet/server.py admits no other)
+    bodies = make_step_bodies(nu=nu, bs=g.bs, dtype=dtype)
     so = cfg.step_2nd_start == 0
     h_fine = float(np.min(g.h))
     uinf = sim.uinf_device()
@@ -2614,11 +1956,9 @@ def make_amr_tgv_step(sim: "AMRSimulation"):
         cap_dt = (h_fine * h_fine / 6.0) / (nu + (h_fine / 6.0) * umax)
         dt = jnp.minimum(cfl_eff * h_fine / (umax + 1e-8), cap_dt)
         dt = jnp.where(dtprev > 0, jnp.minimum(dt, 1.03 * dtprev), dt)
-        vel = amr_ops.rk3_step_blocks(g_, vel, dt, nu, uinf, tab3, ftab)
-        vel, p, stats = amr_ops.project_blocks(
-            g_, vel, dt, sol, tab1, ftab, p_init=p, second_order=so,
-            with_stats=True,
-        )
+        vel = bodies.advdiff(vel, dt, uinf, view)
+        vel, p, stats = bodies.project(vel, dt, None, None, p, view,
+                                       second_order=so)
         umax_new = jnp.max(jnp.abs(vel + uinf))
         time_new = time + dt
         out = {"vel": vel, "p": p, "umax": umax_new, "time": time_new,

@@ -9,16 +9,14 @@ The contract under test (VALIDATION.md "Capacity bucketing"):
   bit-identically to the freshly-compiled first visit (stale topology
   baked into a reused executable would break this);
 - padding blocks stay exactly zero through stepping;
-- the bucketed and legacy (CUP3D_BUCKET=0) paths agree: bitwise for
-  reduction-free kernels, to f32 round-off for full trajectories (the
+- padding is neutral: one rung more of it changes reduction-free
+  kernels at the last ulp and full trajectories at f32 round-off (the
   Krylov global dots reduce over differently-shaped padded arrays whose
   XLA reduction trees round differently at the ulp, which legitimately
   perturbs the iteration path);
 - the block-graph coarse level cuts AMR BiCGSTAB outer iterations vs
   tile-only getZ at equal solution quality.
 """
-
-import os
 
 import jax.numpy as jnp
 import numpy as np
@@ -166,55 +164,52 @@ def test_table_memo_hits_on_pingpong(tmp_path):
     assert len(sim._table_memo) == 2
 
 
-def test_bucketed_matches_unbucketed(tmp_path):
-    """Cross-path equivalence vs the legacy CUP3D_BUCKET=0 driver on an
-    adapting TGV run.  Trajectories agree to f32 round-off; exact
-    bitwise equality is NOT expected through the Krylov solve (module
-    docstring: padded-shape reductions round differently at the ulp and
-    perturb the iteration path)."""
-    def run(bucket):
-        old = os.environ.get("CUP3D_BUCKET")
-        os.environ["CUP3D_BUCKET"] = bucket
-        try:
+def test_padding_is_neutral(tmp_path, monkeypatch):
+    """Padding rows change nothing beyond round-off: the same adapting
+    TGV run at the ladder's own capacity and again one rung up.
+    Trajectories agree to f32 round-off; exact bitwise equality is NOT
+    expected through the Krylov solve (module docstring: padded-shape
+    reductions round differently at the ulp and perturb the iteration
+    path)."""
+    ladder = bk.capacity
+
+    def run(name, capacity):
+        with monkeypatch.context() as m:
+            m.setattr(bk, "capacity", capacity)
             cfg = SimulationConfig(
                 bpdx=2, bpdy=2, bpdz=2, levelMax=2, levelStart=0,
                 extent=float(2 * np.pi), CFL=0.3, nu=0.02, nsteps=4,
                 rampup=0, Rtol=0.5, Ctol=0.01, initCond="taylorGreen",
                 poissonTol=1e-6, poissonTolRel=1e-5, verbose=False,
-                path4serialization=str(tmp_path / ("b" + bucket)),
+                path4serialization=str(tmp_path / name),
             )
             s = AMRSimulation(cfg)
             s.init()
             s.simulate()
             return s
-        finally:
-            if old is None:
-                os.environ.pop("CUP3D_BUCKET", None)
-            else:
-                os.environ["CUP3D_BUCKET"] = old
 
-    sb = run("1")
-    su = run("0")
-    assert sb._bucketing and not su._bucketing
-    assert sb.grid.nb == su.grid.nb
+    sb = run("own", ladder)
+    sw = run("next", lambda n: ladder(ladder(n)))
+    assert sb.grid.keys == sw.grid.keys
+    assert sb._cap == ladder(sb.grid.nb)
+    assert sw._cap == ladder(ladder(sw.grid.nb)) > sb._cap
     vb = np.asarray(sb._unpad(sb.state["vel"]))
-    vu = np.asarray(su.state["vel"])
-    # measured: trajectories agree to ~3e-8 (ulp-level) once the legacy
-    # builder squares h in f32 like the dynamic one; the 1e-5 gate
-    # leaves room for platform fusion differences without letting a
-    # real divergence (1e-4+) through
-    np.testing.assert_allclose(vb, vu, atol=1e-5)
+    vw = np.asarray(sw._unpad(sw.state["vel"]))
+    # measured: ulp-level; the 1e-5 gate leaves room for platform fusion
+    # differences without letting a real divergence (1e-4+) through
+    np.testing.assert_allclose(vb, vw, atol=1e-5)
+    assert float(jnp.max(jnp.abs(sw.state["vel"][sw.grid.nb:]))) == 0.0
     # one advdiff application on the shared state: reduction-free, so
-    # the paths agree to the last ulp of XLA's shape-dependent fusion
-    # (FMA contraction differs across padded/unpadded shapes — true
+    # the two capacities agree to the last ulp of XLA's shape-dependent
+    # fusion (FMA contraction differs across padded shapes — true
     # bitwise across SHAPES is not promised; the bitwise contract lives
     # in test_bucket_reuse_is_bitwise, where shapes match)
     dt = jnp.asarray(1e-3, jnp.float32)
     uinf = jnp.zeros(3, jnp.float32)
-    a_b = np.asarray(sb._advdiff(sb._pad(jnp.asarray(vu)), dt, uinf)
-                     )[: sb.grid.nb]
-    a_u = np.asarray(su._advdiff(jnp.asarray(vu), dt, uinf))
-    np.testing.assert_allclose(a_b, a_u, atol=1e-6)
+    shared = jnp.asarray(vb)
+    a_b = np.asarray(sb._unpad(sb._advdiff(sb._pad(shared), dt, uinf)))
+    a_w = np.asarray(sw._unpad(sw._advdiff(sw._pad(shared), dt, uinf)))
+    np.testing.assert_allclose(a_b, a_w, atol=1e-6)
 
 
 def test_two_level_cuts_amr_iterations():

@@ -244,7 +244,7 @@ def test_amr_driver_on_device_mesh_matches_single():
         np.testing.assert_allclose(a.transVel, b.transVel, atol=1e-5)
     np.testing.assert_allclose(
         np.asarray(sh._unpad(sh.state["vel"])),
-        np.asarray(ref.state["vel"]),
+        np.asarray(ref._unpad(ref.state["vel"])),  # bucket padding off
         atol=5e-4,
     )
     # mesh really is in play: fields are padded + sharded
