@@ -31,10 +31,13 @@ face-table lab assembly is data-dependent indexing — grid/faces.py
 keeps it as jnp gathers) and the coarse-fine flux scatter, which is
 precomputed per application as a DENSE per-cell increment
 (``flux_tab.apply`` on a zero field) so the kernel's Laplacian stage
-consumes only fixed-shape inputs.  The two-level coarse solve (a
-(capacity,)-sized graph CG, krylov._cg_graph) also runs between
-stages; its restriction input comes from the update/axpy stage
-partials, so no extra full-field reduction pass exists.
+consumes only fixed-shape inputs.  The two-level coarse solve also
+runs between stages, here still as the (capacity,)-sized graph CG
+(krylov._cg_graph over the graph's idx/w/deg; this driver ignores the
+dense ``BlockGraph.pinv`` that krylov.coarse_correct_blocks multiplies
+by on the stock path since PR 33); its restriction input comes from the
+update/axpy stage partials, so no extra full-field reduction pass
+exists.
 
 Padding-block invariants (the ``inv_hc = 0`` contract from PR 3): the
 padded face tables gather zeros into padding labs, padded flux rows
@@ -497,8 +500,9 @@ def fused_amr_bicgstab(
     lam = lam3.reshape(1, bs ** 3)
 
     if two_level:
-        # the coarse solve of coarse_correct_blocks with the restriction
-        # already computed by the update/axpy stage partials
+        # the looped coarse solve of coarse_correct_blocks (its arm
+        # without graph.pinv) with the restriction already computed by
+        # the update/axpy stage partials
         m = (graph.deg > 0).astype(graph.w.dtype)
         nreal = jnp.maximum(jnp.sum(m), 1.0)
 

@@ -548,6 +548,17 @@ def use_coarse_correction() -> bool:
 GRAPH_K = 24
 
 
+#: largest block count (the bucket's capacity where the graph is padded)
+#: for which block_graph_tables also builds the dense pseudo-inverse: the
+#: host inverts one (nb, nb) float64 matrix per new octree signature and
+#: the device holds cap^2 values.  Inversions measured on the chip
+#: machine's host (PERF.md section 6, PR 33): 215 rows 4 ms (185 KB on
+#: the device), 1613 rows 0.11 s (10 MB), 2048 rows 0.22 s (17 MB), 4096
+#: rows 1.6 s (67 MB); 8192 rows would take ten seconds and 268 MB, too
+#: much at every regrid.
+DENSE_COARSE_MAX = 2048
+
+
 class BlockGraph(NamedTuple):
     """Face-adjacency graph of one forest topology, the coarse space of
     the AMR two-level preconditioner (the multi-level counterpart of
@@ -559,12 +570,40 @@ class BlockGraph(NamedTuple):
     whose nullspace is the constant — consistent with the mean-removed
     pressure system, exactly like the uniform path's pseudo-inverse.
 
+    ``pinv``: the dense pseudo-inverse C^+ of that operator, (nb[, pad],
+    nb[, pad]) in the tables' dtype, rows and columns of padding blocks
+    exactly 0; block_graph_tables builds it on the host in float64 from
+    the idx/w/deg it has just built, for graphs of at most
+    DENSE_COARSE_MAX rows, and leaves it ``None`` above (the inversion is
+    O(nb^3) on the host at every new topology and the matrix cap^2 on the
+    device).  With it coarse_correct_blocks is one matrix-vector product;
+    without it, the CG loop over idx/w/deg.  The size of the forest alone
+    decides: no switch selects either.
+
     NamedTuple => pytree: travels as a traced jit ARGUMENT, so bucketed
-    drivers (sim/amr.py) reuse compiled executables across regrids."""
+    drivers (sim/amr.py) reuse compiled executables across regrids; a
+    ``None`` pinv is an empty subtree, one leaf fewer."""
 
     idx: jnp.ndarray
     w: jnp.ndarray
     deg: jnp.ndarray
+    pinv: Optional[jnp.ndarray] = None
+
+
+def _graph_pinv(idx: np.ndarray, w: np.ndarray, deg: np.ndarray) -> np.ndarray:
+    """Float64 pseudo-inverse of the graph Laplacian over the real blocks.
+    The face graph of a forest is connected, so the nullspace is the
+    constant alone and C^+ = (C + 11^T/n)^-1 - 11^T/n: one symmetric
+    positive definite inversion, no eigendecomposition."""
+    n = len(deg)
+    C = np.zeros((n, n), np.float64)
+    # a neighbor can fill several slots of a row (periodic wrap on a
+    # 1- or 2-block axis): add, never assign
+    np.add.at(C, (np.repeat(np.arange(n), idx.shape[1]), idx.ravel()),
+              -w.ravel())
+    C[np.arange(n), np.arange(n)] += deg
+    pinv = np.linalg.inv(C + 1.0 / n) - 1.0 / n
+    return 0.5 * (pinv + pinv.T)
 
 
 def block_graph_tables(grid, cap: Optional[int] = None,
@@ -580,7 +619,9 @@ def block_graph_tables(grid, cap: Optional[int] = None,
     over the 1.5 h_f center distance — which is an APPROXIMATION of the
     interpolated-ghost Galerkin rows there; a preconditioner-grade one
     (symmetric, positive semidefinite, constant nullspace), documented
-    in VALIDATION.md.  ``cap``: optional bucket capacity to pad to."""
+    in VALIDATION.md.  ``cap``: optional bucket capacity to pad to.
+    Graphs of at most DENSE_COARSE_MAX rows (``cap``, else ``nb``) also
+    carry the dense pseudo-inverse (BlockGraph.pinv)."""
     tree = grid.tree
     bs = grid.bs
     nb = grid.nb
@@ -626,6 +667,14 @@ def block_graph_tables(grid, cap: Optional[int] = None,
                                            "unbalanced tree")
                         add(s, int(fslot), bs * bs * hf / 1.5)
     deg = w.sum(axis=1)
+    rows = nb if cap is None else cap
+    pinv = None
+    if rows <= DENSE_COARSE_MAX:
+        pinv = np.zeros((rows, rows), np.float64)
+        pinv[:nb, :nb] = _graph_pinv(idx, w, deg)
+        # cast on the host: jnp.asarray(float64, float32) is an upload
+        # AND a convert program
+        pinv = jnp.asarray(pinv.astype(np.dtype(dtype)))
     if cap is not None:
         from cup3d_tpu.grid import bucket as bk_
 
@@ -636,6 +685,7 @@ def block_graph_tables(grid, cap: Optional[int] = None,
         idx=jnp.asarray(idx, jnp.int32),
         w=jnp.asarray(w, dtype),
         deg=jnp.asarray(deg, dtype),
+        pinv=pinv,
     )
 
 
@@ -676,15 +726,24 @@ def _cg_graph(Cfun: Callable, b: jnp.ndarray, iters: int,
 def coarse_correct_blocks(r: jnp.ndarray, vol: jnp.ndarray,
                           graph: BlockGraph, iters: int = 32) -> jnp.ndarray:
     """Coarse correction over the block graph: volume-weighted restrict
-    the residual to one value per block, solve the graph Laplacian with
-    fixed-iteration CG, return the (nb,) per-block correction (prolonged
-    by constant injection at the caller).
+    the residual to one value per block, solve the graph Laplacian,
+    return the (nb,) per-block correction (prolonged by constant
+    injection at the caller).
+
+    The solve is ONE dense product with ``graph.pinv`` where the graph
+    carries it (block_graph_tables: forests of at most DENSE_COARSE_MAX
+    blocks), at Precision.HIGHEST (the default rounds float32 operands
+    to bfloat16 on the TPU); above that size, ``iters`` sweeps of CG
+    whose operator gathers cap x GRAPH_K single elements a sweep (on the
+    v5e 44 us a sweep at cap 215, 30 of the 66 device ms of a
+    twofish_l4 step before PR 33).  Both are the same fixed linear
+    operator to the CG's own 1e-6 gate.
 
     ``vol`` is the per-cell volume column ((nb,1,1,1); 0 on padding
-    blocks, which keeps their rows exactly 0 through the CG).  The
+    blocks, which keeps their rows exactly 0 through either solve).  The
     restriction R r = h^3 sum_cells r makes the graph weights of
     block_graph_tables the exact uniform-limit Galerkin scaling (see
-    there).  CG on the singular-consistent system stays in range(C):
+    there).  The singular-consistent system stays in range(C):
     conservation of the refluxed Laplacian puts zero volume-weighted
     mean on every Krylov residual of the mean-removed solve."""
     rc = jnp.sum(r * vol, axis=(1, 2, 3)).astype(graph.w.dtype)
@@ -700,10 +759,13 @@ def coarse_correct_blocks(r: jnp.ndarray, vol: jnp.ndarray,
     def deflate(v):
         return (v - jnp.sum(v * m) / nreal) * m
 
-    def C(z):
-        return graph.deg * z - jnp.sum(z[graph.idx] * graph.w, axis=-1)
+    if graph.pinv is not None:
+        zc = jnp.matmul(graph.pinv, deflate(rc), precision=_HI)
+    else:
+        def C(z):
+            return graph.deg * z - jnp.sum(z[graph.idx] * graph.w, axis=-1)
 
-    zc = _cg_graph(C, deflate(rc), iters)
+        zc = _cg_graph(C, deflate(rc), iters)
     # the fine A is the NEGATIVE of the positive graph form (lap x =
     # sum(nb - c)/h^2), same sign flip as the uniform path's
     # `t = -t * inv3` (_make_coarse_solve_vec)

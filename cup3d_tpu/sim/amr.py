@@ -667,6 +667,11 @@ class AMRSimulation:
         ).inc()
         obs_metrics.gauge("bucket.capacity").set(self._cap)
         obs_metrics.gauge("amr.blocks").set(g.nb)
+        # 1: the bound graph carries the dense pseudo-inverse and the
+        # preconditioner's coarse solve is one product; 0: the CG loop
+        # (a forest above krylov.DENSE_COARSE_MAX, or no coarse level)
+        obs_metrics.gauge("poisson.coarse_dense").set(int(
+            self._graph is not None and self._graph.pinv is not None))
         if ex is None:
             ex = self._build_bucket_executables()
             self._exec_cache[key] = ex

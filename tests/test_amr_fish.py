@@ -22,9 +22,11 @@ from cup3d_tpu.config import SimulationConfig
 from cup3d_tpu.grid.octree import Octree, TreeConfig
 from cup3d_tpu.models.base import combine_obstacle_fields, quat_to_rot
 from cup3d_tpu.models.fish.rasterize import rasterize_points
+from cup3d_tpu.obs import metrics as obs_metrics
 from cup3d_tpu.ops.chi import towers_chi
 from cup3d_tpu.sim.amr import AMRSimulation
 from tests._dispatch import SpannedProfiler, dispatches, span
+from tests._grids import assert_dots_highest
 
 pytestmark = pytest.mark.filterwarnings("ignore::DeprecationWarning")
 
@@ -108,6 +110,26 @@ def test_two_fish_swim(fish_sim):
         assert np.all(np.isfinite(ob.position))
         assert np.all(np.isfinite(ob.force))
         assert np.linalg.norm(ob.transVel) > 0.0
+
+
+def test_coarse_solve_is_dense(fish_sim):
+    """A forest of this size binds the preconditioner's coarse solve as
+    one product with the host-built pseudo-inverse, and says so."""
+    sim = fish_sim
+    assert sim._graph.pinv.shape == (sim._cap, sim._cap)
+    assert obs_metrics.gauge("poisson.coarse_dense").value == 1
+
+
+def test_forest_moments_run_at_highest(fish_sim):
+    """The bodies' moments feed the rigid velocity the check holds to
+    5e-3 of a body's speed: with float32 operands rounded to bfloat16
+    (the TPU's default, which no CPU run sees) it read up to 2.6e-3."""
+    sim = fish_sim
+    cms = jnp.asarray(np.stack([ob.centerOfMass for ob in sim.obstacles]),
+                      sim.dtype)
+    jaxpr = jax.make_jaxpr(sim._moments)(
+        tuple(ob.chi for ob in sim.obstacles), sim.state["vel"], cms)
+    assert_dots_highest(jaxpr, at_least=5 * len(sim.obstacles))
 
 
 def test_fish_kinematics_stay_host_numpy(fish_sim):
