@@ -605,9 +605,13 @@ class AMRSimulation:
             # (padding slots carry level 0 -> device_tags emits 'L')
             level = np.zeros(cap, np.int32)
             level[: g.nb] = [k[0] for k in g.keys]
+            tab1 = g.face_tables(1)
             memo = dict(
                 cap=cap,
-                tab1=pad_face_tables(g.face_tables(1), g, cap),
+                # faces of leaves whose neighbour is coarser: the rows of
+                # the coarse-face halo tables, before the bucket's padding
+                cf_faces=sum(int(r.shape[0]) for r in tab1.cf_rows),
+                tab1=pad_face_tables(tab1, g, cap),
                 tab3=pad_face_tables(g.face_tables(3), g, cap),
                 ftab=pad_flux_tables(build_flux_tables(g), g.bs, cap),
                 graph=(krylov.block_graph_tables(g, cap=cap)
@@ -667,6 +671,7 @@ class AMRSimulation:
         ).inc()
         obs_metrics.gauge("bucket.capacity").set(self._cap)
         obs_metrics.gauge("amr.blocks").set(g.nb)
+        obs_metrics.gauge("amr.coarse_fine_faces").set(memo["cf_faces"])
         # 1: the bound graph carries the dense pseudo-inverse and the
         # preconditioner's coarse solve is one product; 0: the CG loop
         # (a forest above krylov.DENSE_COARSE_MAX, or no coarse level)
@@ -1725,14 +1730,30 @@ class AMRSimulation:
             elif name == "psolve":
                 # consumed up to ~2*read_every steps late: attribute the
                 # stats to the PRODUCING step carried in the entry
-                self._obs.note_solver(
-                    int(entry.get("step", self.step_idx)), seg[1], seg[0],
-                    cap=getattr(self._solver, "maxiter", None),
-                )
+                self._note_solve(int(entry.get("step", self.step_idx)), seg)
         # host frame velocity from the refreshed mirrors (logs/dumps)
         fixed = [ob for ob in self.obstacles if ob.bFixFrameOfRef]
         if fixed:
             self.uinf = -np.mean([ob.transVel for ob in fixed], axis=0)
+
+    def _note_solve(self, step: int, seg):
+        """One solve's ``[residual, iterations]`` as the packed read
+        brought it: obs gauges + step trace + flight residual history
+        (itercap trips a postmortem), and which coarse solve the bound
+        preconditioner ran (host side: the graph's arm, no device read;
+        a solver without a coarse level counts under neither)."""
+        from cup3d_tpu.obs import metrics as obs_metrics
+
+        self._obs.note_solver(
+            step, seg[1], seg[0],
+            cap=getattr(self._solver, "maxiter", None),
+        )
+        graph = getattr(self, "_graph", None)  # the sharded forest: none
+        if graph is not None:
+            obs_metrics.counter(
+                "poisson.coarse_dense_solves" if graph.pinv is not None
+                else "poisson.coarse_cg_solves"
+            ).inc()
 
     def _consume_step_pack(self):
         """ONE blocking host read for everything the step produced
@@ -1771,12 +1792,7 @@ class AMRSimulation:
             elif name == "umax":
                 self._umax_next = float(seg[0])
             elif name == "psolve":
-                # [residual, iterations]: obs gauges + step trace +
-                # flight residual history (itercap trips a postmortem)
-                self._obs.note_solver(
-                    self.step_idx, seg[1], seg[0],
-                    cap=getattr(self._solver, "maxiter", None),
-                )
+                self._note_solve(self.step_idx, seg)
 
     def _fix_mass_flux(self):
         u_target = 2.0 / 3.0 * self.cfg.uMax_forced
