@@ -56,16 +56,13 @@ from cup3d_tpu.models.base import (
     RIGID_PACK,
     combine_obstacle_fields,
     log_forces,
-    pack_forces,
     store_force_qoi,
     unpack_forces,
     unpack_moments,
-    update_penalization_forces,
     vel_unit,
 )
 from cup3d_tpu.ops import amr_ops
 from cup3d_tpu.ops.chi import towers_chi
-from cup3d_tpu.ops.penalization import penalize
 from cup3d_tpu.resilience import faults
 from cup3d_tpu.resilience.recovery import SimulationFailure
 from cup3d_tpu.sim.amr_step import GeomView, make_step_bodies
@@ -82,20 +79,23 @@ _EPS = 1e-6
 _FOREST_EXEC_ATTRS = (
     "forest", "_tab1", "_tab3", "_ftab", "_solver", "_vol", "_h_col",
     "_xc", "_real_mask", "_geom", "_view", "_advdiff", "_project",
-    "_project_2nd", "_penalize", "_penal_force", "_ubody", "_divnorms",
-    "_dissipation", "_gradchi", "_omega_mag", "_scores", "_moments",
-    "_maxu", "_megastep", "_megastep_free", "_fix_flux", "_device_tags",
+    "_project_2nd", "_penalize_bodies", "_ubody", "_divnorms",
+    "_dissipation", "_gradchi", "_omega_mag", "_scores", "_moments_read",
+    "_forces_ex", "_maxu", "_megastep", "_megastep_free", "_fix_flux",
+    "_device_tags",
 )
 
 #: the step kernels both binders bind: amr_step body (also the program's
 #: name and, after "_", the attribute) -> donated state argnums, the
 #: buffers the caller rebinds from the return value (JX002 burn-down).
-#: ``tags`` (bucketed only) and ``fix_flux`` (donated by the bucketed
-#: binder only) are bound beside this table.
+#: ``tags`` (bucketed only), ``fix_flux`` (donated by the bucketed
+#: binder only) and ``forces_bodies`` (static probe budgets:
+#: _forces_kernel) are bound beside this table.
 _STEP_KERNELS = {
     "advdiff": (0,), "project": (0, 4), "project_2nd": (0, 4),
-    "penal_force": (), "ubody": (), "divnorms": (), "dissipation": (),
-    "gradchi": (), "omega_mag": (), "scores": (), "moments": (),
+    "penalize_bodies": (0,), "ubody": (), "divnorms": (),
+    "dissipation": (), "gradchi": (), "omega_mag": (), "scores": (),
+    "moments_read": (),
 }
 
 
@@ -115,11 +115,6 @@ class _ArgGeom:
         self.nb = nb
         self.h = h
         self.extent = extent
-
-
-@jax.jit
-def _penalize_j(vel, chi, ubody, lam, dt):
-    return penalize(vel, chi, ubody, lam, dt)
 
 
 @jax.jit
@@ -212,11 +207,9 @@ class AMRSimulation:
         self._uinf_host_cache = None  # device mirror of self.uinf
         self.nu = cfg.nu
         self.lambda_penal = cfg.lambda_penalization
-        # cached device lambda mirrors (_lambda_device): the DLM constant
-        # uploads once and lambda = DLM/dt divides ON DEVICE from the
-        # step's dt scalar; a static lambda uploads once per value (the
-        # old per-step jnp.asarray(self.lambda_penal) was rule JX010)
-        self._dlm_dev_cache = None
+        # cached device mirror of the penalization coefficient
+        # (_lambda_device): uploaded once per value (the old per-step
+        # jnp.asarray(self.lambda_penal) was rule JX010)
         self._lambda_dev_cache = None
         self._lambda_dev_val = None
         self.logger = BufferedLogger(cfg.path4serialization)
@@ -378,25 +371,17 @@ class AMRSimulation:
             self._uinf_host_src = self.uinf
         return self._uinf_host_cache
 
-    def _lambda_device(self, dt_j):
-        """Device-resident penalization lambda for this step (same
-        contract as sim/data.lambda_device): DLM > 0 divides the cached
-        DLM constant by the step's device dt scalar — zero steady-state
-        host->device traffic; a static lambda uploads once per value.
-        The host ``lambda_penal`` mirror keeps feeding logs/checkpoints."""
-        if self.cfg.DLM > 0:
-            if self._dlm_dev_cache is None:
-                with sanctioned_transfer("scalar-upload"):
-                    self._dlm_dev_cache = jnp.asarray(
-                        self.cfg.DLM, self.dtype
-                    )
-            return self._dlm_dev_cache / dt_j
-        if self._lambda_dev_val != self.lambda_penal:
+    def _lambda_device(self):
+        """The penalization coefficient ``penalize_bodies`` is handed,
+        device resident and uploaded once per value: DLM where cfg.DLM >
+        0 (the kernel forms lambda = DLM / dt from the step's device dt
+        scalar), else the static lambda.  The host ``lambda_penal``
+        mirror keeps feeding logs/checkpoints."""
+        val = self.cfg.DLM if self.cfg.DLM > 0 else self.lambda_penal
+        if self._lambda_dev_val != val:
             with sanctioned_transfer("scalar-upload"):
-                self._lambda_dev_cache = jnp.asarray(
-                    self.lambda_penal, self.dtype
-                )
-            self._lambda_dev_val = self.lambda_penal
+                self._lambda_dev_cache = jnp.asarray(val, self.dtype)
+            self._lambda_dev_val = val
         return self._lambda_dev_cache
 
     # -- jitted kernels (rebuilt per layout) -------------------------------
@@ -433,9 +418,10 @@ class AMRSimulation:
         g = self.grid
         return float(g.h0 / (1 << (len(g._slot_maps) - 1)))
 
-    def _step_bodies(self, budgets=()):
+    def _step_bodies(self, budgets=(), windows=()):
         """sim/amr_step.py's bodies for this run's configuration (and,
-        for the megastep, the obstacles' static probe ``budgets``)."""
+        for the kernels that hold the surface probe, the obstacles'
+        static point ``budgets`` and the shapes of their ``windows``)."""
         cfg, g = self.cfg, self.grid
         helm = None
         if cfg.implicitDiffusion:
@@ -454,7 +440,8 @@ class AMRSimulation:
             fix_mass_flux=cfg.bFixMassFlux, umax_forced=cfg.uMax_forced,
             tag_rule=(cfg.Rtol, cfg.Ctol, cfg.levelMax,
                       cfg.levelMaxVorticity, bool(cfg.bAdaptChiGradient)),
-            h_fine=self._h_finest(), budgets=budgets,
+            h_fine=self._h_finest(), budgets=budgets, windows=windows,
+            dlm=cfg.DLM > 0,
         )
 
     def _rebuild(self):
@@ -529,6 +516,7 @@ class AMRSimulation:
         # reloads the serialized executable instead of retracing
         aot_sig = self._aot_content_sig(sig)
         bodies = self._step_bodies()
+        self._forces_ex = {}  # _forces_kernel fills it, the memo keeps it
 
         def bind(name, donate=()):
             # the jit construction site lives in parallel/forest.py
@@ -546,7 +534,6 @@ class AMRSimulation:
             setattr(self, "_" + name, bind(name, donate))
         if cfg.bFixMassFlux:
             self._fix_flux = bind("fix_flux")
-        self._penalize = _penalize_j
         self._maxu = _maxu_j
         if cfg.pipelined:
             self._build_megastep()
@@ -765,14 +752,37 @@ class AMRSimulation:
 
         for name in _STEP_KERNELS:
             setattr(self, "_" + name, bound(ex[name]))
+        self._forces_ex = ex.setdefault("forces_bodies", {})
         self._device_tags = (
             lambda vel, chi:
             ex["tags"](vel, chi, self._level_arr, *geo())
         )
         if self.cfg.bFixMassFlux:
             self._fix_flux = bound(ex["fix_flux"])
-        self._penalize = _penalize_j
         self._maxu = _maxu_j
+
+    def _forces_kernel(self, budgets, windows):
+        """``forces_bodies`` bound like the step kernels, for these static
+        point budgets and window shapes; kept with the kernels of its
+        bucket (of its octree signature on a mesh) and built when a
+        body's budget moves, where ``_probe_blocks_jit`` retraced."""
+        fn = self._forces_ex.get((budgets, windows))
+        if fn is None:
+            body = self._step_bodies(budgets, windows).forces_bodies
+            if self.forest is None:
+                jitted = self._geo_binder()(body, "forces_bodies")
+
+                def fn(*a):  # the bucket's geometry of the day, as bound()
+                    return jitted(*a, *self._geo_args())
+            else:
+                from cup3d_tpu.parallel.forest import bind_step_executable
+
+                fn = bind_step_executable(
+                    body, self._view, name="forces_bodies",
+                    store_sig=self._aot_content_sig(self.grid.signature)
+                    + (budgets, windows))
+            self._forces_ex[(budgets, windows)] = fn
+        return fn
 
     # -- pipelined megastep ------------------------------------------------
 
@@ -808,10 +818,11 @@ class AMRSimulation:
         self._megastep_budgets = tuple(
             obstacle_probe_budget(ob, hf0) for ob in self.obstacles
         )
+        windows = self._probe_windows()[0]
         if self.forest is not None:
             from cup3d_tpu.parallel.forest import bind_order_executables
 
-            bodies = self._step_bodies(self._megastep_budgets)
+            bodies = self._step_bodies(self._megastep_budgets, windows)
             # (first, second pressure order) per body; vel, p donated
             jits, jits_free = (
                 bind_order_executables(
@@ -824,10 +835,10 @@ class AMRSimulation:
                 return ()
         else:
             key = ("mega", self._bucket_key(), self._megastep_budgets,
-                   len(self.obstacles), bool(self.cfg.bFixMassFlux))
+                   windows, bool(self.cfg.bFixMassFlux))
             ex = self._exec_cache.get(key)
             if ex is None:
-                bodies = self._step_bodies(self._megastep_budgets)
+                bodies = self._step_bodies(self._megastep_budgets, windows)
                 jit_geo = self._geo_binder()
                 ex = tuple(
                     tuple(jit_geo(body, name + ("_2nd" if so else ""),
@@ -940,29 +951,45 @@ class AMRSimulation:
             self.state["udef"] = udef
 
     def _obstacle_ubody(self, ob):
-        # cached per (step, rigid state); penalization and the force pass
-        # both consume the same field each step
-        tag = (self.step_idx, tuple(ob.transVel), tuple(ob.angVel),
-               tuple(ob.centerOfMass))
-        cached = getattr(ob, "_ubody_cache", None)
-        if cached is not None and cached[0] == tag:
-            return cached[1]
-        field = self._ubody(
+        """One body's velocity field, for the contact branch alone
+        (``prevent_colliding_obstacles``): a step without contact builds
+        every body's field inside ``penalize_bodies``."""
+        return self._ubody(
             ob.udef,
             jnp.asarray(ob.centerOfMass, self.dtype),
             jnp.asarray(ob.transVel, self.dtype),
             jnp.asarray(ob.angVel, self.dtype),
         )
-        ob._ubody_cache = (tag, field)
-        return field
 
-    def _body_velocity(self):
-        chis = jnp.stack([ob.chi for ob in self.obstacles])
-        num = sum(
-            ob.chi[..., None] * self._obstacle_ubody(ob) for ob in self.obstacles
-        )
-        den = jnp.maximum(jnp.sum(chis, axis=0), _EPS)[..., None]
-        return num / den
+    def _rigid_rows(self):
+        """The bodies' host mirrors as the body kernels read them: one
+        upload of a row (transVel, angVel, centerOfMass) per body."""
+        rows = np.stack([
+            np.concatenate([ob.transVel, ob.angVel, ob.centerOfMass])
+            for ob in self.obstacles
+        ])
+        # cast on the host: jnp.asarray(float64, float32) is an upload
+        # AND a convert program
+        return jnp.asarray(rows.astype(self.dtype))
+
+    def _probe_windows(self):
+        """The surface probe's host half for all bodies: the static
+        shapes of their windows, in blocks of the finest level, and ONE
+        int32 vector to upload, every body's window origin and then every
+        body's block slots (amr_step.py ``forces_bodies``).  A shape
+        depends on the body's length and the finest spacing alone, never
+        on where the body is (ops/surface.py ``block_window_slots``)."""
+        from cup3d_tpu.ops.surface import block_window_slots
+
+        b0s, slots = [], []
+        for ob in self.obstacles:
+            slots_, b0, _ = block_window_slots(
+                self.grid, np.asarray(ob.position), ob.length
+            )
+            b0s.append(b0)
+            slots.append(slots_)
+        win = np.concatenate([np.ravel(b0s)] + [s_.ravel() for s_ in slots])
+        return tuple(s_.shape for s_ in slots), win.astype(np.int32)
 
     # -- adaptation --------------------------------------------------------
 
@@ -1414,50 +1441,36 @@ class AMRSimulation:
         if self.obstacles:
             with self.profiler("UpdateObstacles"):
                 n_obs = len(self.obstacles)
+                chis = tuple(ob.chi for ob in self.obstacles)
                 cms = jnp.asarray(
-                    np.stack([ob.centerOfMass for ob in self.obstacles]),
-                    self.dtype,
+                    np.stack([ob.centerOfMass for ob in self.obstacles])
+                    .astype(self.dtype)
                 )
-                M_dev = self._moments(
-                    tuple(ob.chi for ob in self.obstacles), s["vel"], cms
-                ).reshape(-1)
-                # piggyback the collision pre-check (overlap cell count per
-                # pair) on the moments read: one transfer serves both
+                # the collision pre-check (overlap cell count per pair)
+                # rides the moments read: one program, one transfer
                 pairs = [
                     (i, j) for i in range(n_obs) for j in range(i + 1, n_obs)
                 ]
-                if pairs:
-                    from cup3d_tpu.models.collisions import overlap_count
-
-                    cnts = jnp.stack(
-                        [
-                            overlap_count(
-                                self.obstacles[i].chi, self.obstacles[j].chi
-                            ).astype(self.dtype)
-                            for i, j in pairs
-                        ]
+                # the designed once-per-step moments sync of the
+                # non-pipelined obstacle path (the pipelined megastep
+                # streams these rows through the QoI pack instead)
+                with sanctioned_transfer("moments-read"):
+                    vals = np.asarray(
+                        self._moments_read(chis, s["vel"], cms), np.float64
                     )
-                    # the designed once-per-step moments sync of the
-                    # non-pipelined obstacle path (the pipelined megastep
-                    # streams these rows through the QoI pack instead)
-                    with sanctioned_transfer("moments-read"):
-                        vals = np.asarray(jnp.concatenate([M_dev, cnts]),
-                                          np.float64)
-                    precheck = {
-                        p: float(v)
-                        for p, v in zip(pairs, vals[n_obs * 19:])
-                    }
-                else:
-                    with sanctioned_transfer("moments-read"):
-                        vals = np.asarray(M_dev, np.float64)
-                    precheck = {}
+                precheck = dict(zip(pairs, vals[n_obs * 19:].tolist()))
                 self._overlap_now = any(v > 0 for v in precheck.values())
                 M = vals[: n_obs * 19].reshape(n_obs, 19)
                 for ob, row in zip(self.obstacles, M):
                     ob.compute_velocities(unpack_moments(row))
                     ob.update(dt)
             with self.profiler("Penalization"):
-                if len(self.obstacles) > 1:
+                from cup3d_tpu.obs import metrics as obs_metrics
+
+                if self._overlap_now:
+                    # contact work, op by op: the bodies' velocity
+                    # fields and chi gradients for the pairs that
+                    # overlap; an impulse latches the mirrors' velocities
                     from cup3d_tpu.models.collisions import (
                         prevent_colliding_obstacles,
                     )
@@ -1470,16 +1483,19 @@ class AMRSimulation:
                         dt,
                         precheck_counts=precheck,
                     )
-                vel_old = s["vel"]
-                s["vel"] = self._penalize(
-                    vel_old, s["chi"], self._body_velocity(),
-                    self._lambda_device(dt_j), dt_j,
+                obs_metrics.counter(
+                    "operators.body_steps_contact" if self._overlap_now
+                    else "operators.body_steps_fused"
+                ).inc()
+                # the mirrors as update() and the contact branch left
+                # them; ComputeForces reads the same upload
+                rigid = self._rigid_rows()
+                s["vel"], PF = self._penalize_bodies(
+                    s["vel"], chis,
+                    tuple(ob.udef for ob in self.obstacles), rigid, dt_j,
+                    self._lambda_device(),
                 )
-                PF = update_penalization_forces(
-                    self.obstacles, self._penal_force, s["vel"], vel_old,
-                    dt, self.dtype,
-                )
-                self._pending_parts.append(("penal", PF.reshape(-1)))
+                self._pending_parts.append(("penal", PF))
         if self.cfg.bFixMassFlux:
             with self.profiler("FixMassFlux"):
                 self._fix_mass_flux()
@@ -1511,7 +1527,7 @@ class AMRSimulation:
             self._pending_parts.append(("psolve", psolve))
         if self.obstacles:
             with self.profiler("ComputeForces"):
-                self._compute_forces()
+                self._compute_forces(rigid)
         self._log_diagnostics()
         with self.profiler("SyncQoI"):
             self._consume_step_pack()
@@ -1558,29 +1574,10 @@ class AMRSimulation:
             self._build_megastep()
         with self.profiler("Megastep"):
             n = len(self.obstacles)
-            from cup3d_tpu.ops.surface import block_window_slots
-
             chis = jnp.stack([ob.chi for ob in self.obstacles])
             udefs = jnp.stack([ob.udef for ob in self.obstacles])
             sdfs = jnp.stack([ob.sdf for ob in self.obstacles])
-            slots, b0s = [], []
-            for ob in self.obstacles:
-                s_, b0_, _ = block_window_slots(
-                    # jax-lint: allow(JX010, ob.position is the host
-                    # numpy mirror — a host-side copy for the window
-                    # table math, no device value crosses here)
-                    # jax-lint: allow(JX016, same: host numpy mirror in,
-                    # host table math out — nothing shard-resident is
-                    # gathered)
-                    self.grid, np.asarray(ob.position), ob.length
-                )
-                # jax-lint: allow(JX004, the window slot tables are host-
-                # computed from the body position each step; one small
-                # upload per obstacle (n_obs <= 2), batching is follow-up)
-                slots.append(jnp.asarray(s_))
-                # jax-lint: allow(JX004, same as the slots upload above)
-                b0s.append(jnp.asarray(b0_, jnp.int32))
-            slots, b0s = tuple(slots), tuple(b0s)
+            win = jnp.asarray(self._probe_windows()[1])
             rigid = jnp.stack(
                 [ob.rigid_state_dev(self.dtype) for ob in self.obstacles]
             )
@@ -1601,8 +1598,7 @@ class AMRSimulation:
             )
             vel, p, chi, udef, uinf_next, pack = self._megastep(
                 s["vel"], s["p"], chis, udefs, sdfs, rigid, forced,
-                blocked, fixmask, slots, b0s, uinf, dt_j,
-                self._lambda_device(dt_j),
+                blocked, fixmask, win, uinf, dt_j, self._lambda_device(),
             )
             s["vel"], s["p"], s["chi"], s["udef"] = vel, p, chi, udef
             self._uinf_dev = uinf_next
@@ -1612,7 +1608,6 @@ class AMRSimulation:
                     "step": self.step_idx, "pack": row, "trans": row[0:3],
                     "ang": row[3:6], "cm": row[12:15],
                 }
-                ob._ubody_cache = None
             self._prefetch_regrid_decision()
         self._log_diagnostics()
         with self.profiler("SyncQoI"):
@@ -1810,29 +1805,25 @@ class AMRSimulation:
             f" {u_target:.8e}\n",
         )
 
-    def _compute_forces(self):
+    def _compute_forces(self, rigid):
         """Per-obstacle force/torque/power QoI from the surface-point
         probe (ops/surface.py; reference ComputeForces,
-        main.cpp:12250-12503)."""
-        from cup3d_tpu.ops.surface import (
-            force_integrals_probe_blocks, obstacle_probe_budget,
-        )
+        main.cpp:12250-12503): one upload, the probe windows, and one
+        program for all bodies.  ``rigid``: the rows Penalization
+        uploaded (the mirrors have not moved since)."""
+        from cup3d_tpu.ops.surface import obstacle_probe_budget
 
-        s = self.state
+        s, obs = self.state, self.obstacles
         h_fine = float(self.grid.h.min())
-        rows = [
-            pack_forces(
-                force_integrals_probe_blocks(
-                    self.grid, {"vel": s["vel"], "p": s["p"]}, ob.chi,
-                    ob.sdf, ob.udef, self.nu, ob.position, ob.length,
-                    ob.centerOfMass, ob.transVel, ob.angVel,
-                    max_points=obstacle_probe_budget(ob, h_fine),
-                )
-            )
-            for ob in self.obstacles
-        ]
+        budgets = tuple(obstacle_probe_budget(ob, h_fine) for ob in obs)
+        windows, win = self._probe_windows()
+        rows = self._forces_kernel(budgets, windows)(
+            s["vel"], s["p"], tuple(ob.chi for ob in obs),
+            tuple(ob.sdf for ob in obs), tuple(ob.udef for ob in obs),
+            jnp.asarray(win), rigid,
+        )
         # joins the end-of-step packed read (_consume_step_pack)
-        self._pending_parts.append(("forces", jnp.stack(rows).reshape(-1)))
+        self._pending_parts.append(("forces", rows))
 
     # -- resilience hooks (resilience/recovery.py driver contract) ---------
 

@@ -82,12 +82,18 @@ def make_step_bodies(
     tag_rule: Optional[tuple] = None,
     h_fine: Optional[float] = None,
     budgets: Sequence[int] = (),
+    windows: Sequence[tuple] = (),
+    dlm: bool = False,
 ) -> SimpleNamespace:
     """The bodies for one configuration.  ``helm`` is the built
     Helmholtz solve of an implicitDiffusion run (None: explicit RK3);
     ``tag_rule`` = (Rtol, Ctol, levelMax, levelMaxVorticity,
-    bAdaptChiGradient) for ``tags``; ``h_fine`` and the per-obstacle
-    static point ``budgets`` feed the megastep's surface probe."""
+    bAdaptChiGradient) for ``tags``; ``h_fine``, the per-obstacle static
+    point ``budgets`` and the shapes of the probe ``windows`` (in blocks
+    of the finest level, ``ops/surface.block_window_slots``) feed the
+    surface probe of ``forces_bodies`` and the megastep; with ``dlm`` the
+    penalization coefficient a body is handed is DLM, and lambda = DLM /
+    dt is formed in the trace."""
 
     def advdiff(vel, dt, uinf, view):
         """Advection-diffusion honoring implicitDiffusion — the step
@@ -179,6 +185,67 @@ def make_step_bodies(
                 for i in range(len(chis))
             ])
 
+    def overlaps(chis):
+        """The collision pre-check: cells inside both bodies, per pair
+        (i < j), as the packed reads carry it."""
+        n = len(chis)
+        pairs = [(i, j) for i in range(n) for j in range(i + 1, n)]
+        if not pairs:
+            return jnp.zeros(0, dtype)
+        return jnp.stack([overlap_count(chis[i], chis[j]).astype(dtype)
+                          for i, j in pairs])
+
+    def moments_read(chis, vel, cms, view):
+        """UpdateObstacles' device half on the per-step path: the vector
+        its one blocking read fetches, every body's moments and then the
+        pairs' overlap counts."""
+        return jnp.concatenate(
+            [moments(chis, vel, cms, view).reshape(-1).astype(dtype),
+             overlaps(chis)])
+
+    def penalize_bodies(vel, chis, udefs, rigid, dt, lam, view):
+        """Penalization with every body's velocity field built in the
+        trace: ``rigid`` holds a row (transVel, angVel, centerOfMass) per
+        body, ``chis`` and ``udefs`` the bodies' fields (tuples on the
+        per-step path, stacks in the megastep), ``lam`` lambda or, under
+        ``dlm``, DLM.  Returns the penalized velocity and minus the
+        momentum it injected, (force, torque) per body, flat."""
+        chis, udefs = jnp.stack(chis), jnp.stack(udefs)
+        xc = view.xc
+        col = rigid[:, None, None, None, None]
+        ub = (
+            col[..., 0:3]
+            + jnp.cross(jnp.broadcast_to(col[..., 3:6], udefs.shape),
+                        xc[None] - col[..., 6:9])
+            + udefs
+        )  # (n_obs, nb, bs,bs,bs, 3)
+        den = jnp.maximum(jnp.sum(chis, axis=0), _EPS)[..., None]
+        ubody = jnp.sum(chis[..., None] * ub, axis=0) / den
+        if dlm:
+            lam = lam / dt
+        vel_new = penalize(vel, jnp.max(chis, axis=0), ubody, lam, dt)
+        PF = -penal_force(vel_new, vel, tuple(chis), dt, rigid[:, 6:9], view)
+        return vel_new, PF.reshape(-1).astype(dtype)
+
+    def forces_bodies(vel, p, chis, sdfs, udefs, win, rigid, view):
+        """ComputeForces: the surface-point probe of every body
+        (ops/surface.py: the production force measure, on the body's dense
+        window, compacted to its static point budget) and the packed rows.
+        ``win`` is one int32 vector: every body's window origin (3) and
+        then every body's block slots, flat, in the shapes ``windows``."""
+        rows, at = [], 3 * len(windows)
+        for i, shape in enumerate(windows):
+            size = shape[0] * shape[1] * shape[2]
+            rows.append(pack_forces(probe_blocks_core(
+                vel, p, chis[i], sdfs[i], udefs[i],
+                win[at:at + size].reshape(shape), win[3 * i:3 * i + 3],
+                jnp.asarray(h_fine, vel.dtype), nu,
+                rigid[i, 6:9], rigid[i, 0:3], rigid[i, 3:6],
+                max_points=budgets[i],
+            )))
+            at += size
+        return jnp.concatenate(rows)
+
     def fix_flux(vel, uinf_x, u_target, view):
         # FixMassFlux on the forest (reference avgUx_nonUniform +
         # parabolic add, main.cpp:12199-12249): volume-weighted mean of
@@ -214,12 +281,10 @@ def make_step_bodies(
     )
 
     def mega(vel, p, chis, udefs, sdfs, rigid, forced, blocked, fixmask,
-             slots, b0s, uinf, dt, lam, view, second_order=False):
+             win, uinf, dt, lam, view, second_order=False):
         """The whole obstacle step: advection -> vmapped device rigid
         update -> penalization -> forcing -> projection -> force QoI ->
         packed read vector."""
-        xc, vol = view.xc, view.vol
-        n_obs = chis.shape[0]
         chi, udef = combine_obstacle_fields(chis, udefs)
 
         vel = advdiff(vel, dt, uinf, view)
@@ -228,26 +293,10 @@ def make_step_bodies(
         cms = rigid[:, 12:15]
         M = moments(chis, vel, cms, view)
         out = rigid_vmapped(M, rigid, forced, blocked, uinf, dt)
-        cm_new = out[:, 12:15]
-        ub = (
-            out[:, None, None, None, None, 0:3]
-            + jnp.cross(
-                jnp.broadcast_to(
-                    out[:, None, None, None, None, 3:6], udefs.shape
-                ),
-                xc[None] - out[:, None, None, None, None, 12:15],
-            )
-            + udefs
-        )  # (n_obs, nb, bs,bs,bs, 3)
-        den = jnp.maximum(jnp.sum(chis, axis=0), _EPS)[..., None]
-        ubody = jnp.sum(chis[..., None] * ub, axis=0) / den
+        # the rows the body kernels read: transVel, angVel, centerOfMass
+        moved = jnp.concatenate([out[:, 0:6], out[:, 12:15]], axis=1)
 
-        vel_old = vel
-        vel = penalize(vel, chi, ubody, lam, dt)
-        PF = -penal_force(
-            vel, vel_old, tuple(chis[i] for i in range(n_obs)), dt,
-            cm_new, view,
-        )
+        vel, PF = penalize_bodies(vel, chis, udefs, moved, dt, lam, view)
 
         vel, flux_msr = forcing_stage(vel, uinf, dt, view)
 
@@ -256,37 +305,7 @@ def make_step_bodies(
             p_init=p, second_order=second_order,
         )
 
-        # surface-point probe per obstacle (ops/surface.py): the
-        # production force measure, on the obstacle's dense window,
-        # compacted to a static per-obstacle point budget
-        F = jnp.stack(
-            [
-                pack_forces(
-                    probe_blocks_core(
-                        vel, p, chis[i], sdfs[i], udefs[i],
-                        slots[i], b0s[i],
-                        jnp.asarray(h_fine, vel.dtype), nu,
-                        cm_new[i], out[i, 0:3], out[i, 3:6],
-                        max_points=budgets[i],
-                    )
-                )
-                for i in range(n_obs)
-            ]
-        )
-
-        pairs = [
-            (i, j) for i in range(n_obs) for j in range(i + 1, n_obs)
-        ]
-        overlaps = (
-            jnp.stack(
-                [
-                    overlap_count(chis[i], chis[j]).astype(dtype)
-                    for i, j in pairs
-                ]
-            )
-            if pairs
-            else jnp.zeros(0, dtype)
-        )
+        F = forces_bodies(vel, p, chis, sdfs, udefs, win, moved, view)
 
         # next step's frame velocity from the NEW rigid state, so the
         # device chain matches non-pipelined uinf semantics exactly
@@ -300,8 +319,7 @@ def make_step_bodies(
             jnp.max(jnp.abs(udef)),
         ).reshape(1)
         pack = jnp.concatenate(
-            [out.reshape(-1), PF.reshape(-1).astype(dtype),
-             F.reshape(-1), overlaps, flux_msr, umax]
+            [out.reshape(-1), PF, F, overlaps(chis), flux_msr, umax]
         )
         return vel, p, chi, udef, uinf_next, pack
 
@@ -321,7 +339,9 @@ def make_step_bodies(
 
     return SimpleNamespace(
         advdiff=advdiff, project=project, project_2nd=project_2nd,
-        penal_force=penal_force, ubody=ubody, divnorms=divnorms, dissipation=dissipation,
+        ubody=ubody, divnorms=divnorms, dissipation=dissipation,
         gradchi=gradchi, omega_mag=omega_mag, scores=scores, tags=tags,
-        moments=moments, fix_flux=fix_flux, mega=mega, mega_free=mega_free,
+        moments_read=moments_read, penalize_bodies=penalize_bodies,
+        forces_bodies=forces_bodies, fix_flux=fix_flux, mega=mega,
+        mega_free=mega_free,
     )

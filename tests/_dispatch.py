@@ -35,6 +35,25 @@ class SpannedProfiler:
         return getattr(self._profiler, name)
 
 
+def advance_dispatches(sim, directory):
+    """``dispatches`` of one more ``calc_max_timestep`` + ``advance`` of a
+    driver, the call a span ``advance`` and every profiler section of it
+    a span of its own name."""
+    profiler = sim.profiler
+    sim.profiler = SpannedProfiler(profiler)
+
+    def one_more_advance():
+        dt = sim.calc_max_timestep()
+        with span("advance"):
+            sim.advance(dt)
+        jax.block_until_ready(sim.state["vel"])
+
+    try:
+        return dispatches(one_more_advance, directory)
+    finally:
+        sim.profiler = profiler
+
+
 def dispatches(run, directory):
     """{span: (programs executed, uploads)} of one ``run()``, which ends
     with the device idle."""

@@ -10,7 +10,9 @@ CreateObstacles on the single-device forest (the last tests of the file,
 which drive the module's forest on): host NumPy kinematics, two uploads
 and one program for all bodies (sim/amr.py ``_create_blocks``), counted
 as ``tests/_dispatch.py`` says, and held to the chain the parent
-dispatched op by op, written out below as plain ``jnp`` calls.
+dispatched op by op, written out below as plain ``jnp`` calls.  The same
+for Penalization and ComputeForces (sim/amr_step.py ``penalize_bodies``,
+``forces_bodies``: one upload and one program each since PR 35).
 """
 
 import jax
@@ -20,23 +22,71 @@ import pytest
 
 from cup3d_tpu.config import SimulationConfig
 from cup3d_tpu.grid.octree import Octree, TreeConfig
-from cup3d_tpu.models.base import combine_obstacle_fields, quat_to_rot
+from cup3d_tpu.models.base import (
+    FORCE_PACK,
+    combine_obstacle_fields,
+    pack_forces,
+    quat_to_rot,
+)
 from cup3d_tpu.models.fish.rasterize import rasterize_points
 from cup3d_tpu.obs import metrics as obs_metrics
 from cup3d_tpu.ops.chi import towers_chi
+from cup3d_tpu.ops.penalization import (
+    penalize,
+    per_obstacle_penalization_force,
+)
+from cup3d_tpu.ops.surface import force_integrals_probe_blocks
 from cup3d_tpu.sim.amr import AMRSimulation
-from tests._dispatch import SpannedProfiler, dispatches, span
+from tests._dispatch import advance_dispatches, dispatches, span
 from tests._grids import assert_dots_highest
 
 pytestmark = pytest.mark.filterwarnings("ignore::DeprecationWarning")
 
 
-class _FrameSpy(AMRSimulation):
-    """The driver itself, keeping for every step the frame velocity that
+class _BodySpy(AMRSimulation):
+    """The driver itself, keeping with ``watch_bodies`` on what the fused
+    body operators of a step were handed and gave back (``bodies_seen``).
+    The step kernels are rebound at every regrid, so the spy sits on the
+    attribute."""
+
+    def __init__(self, cfg):
+        self.watch_bodies, self.bodies_seen = False, {}
+        super().__init__(cfg)
+
+    @property
+    def _penalize_bodies(self):
+        def spy(vel, *rest):
+            if not self.watch_bodies:
+                return self._penalize_bodies_bound(vel, *rest)
+            # copies: the kernel is given ``vel`` to keep, the projection
+            # its product
+            self.bodies_seen["vel_old"] = jnp.array(vel)
+            out = self._penalize_bodies_bound(vel, *rest)
+            self.bodies_seen.update(vel=jnp.array(out[0]), penal=out[1])
+            return out
+
+        return spy
+
+    @_penalize_bodies.setter
+    def _penalize_bodies(self, fn):
+        self._penalize_bodies_bound = fn
+
+    def _forces_kernel(self, budgets, windows):
+        kernel = super()._forces_kernel(budgets, windows)
+
+        def spy(*args):
+            rows = kernel(*args)
+            self.bodies_seen.update(budgets=budgets, forces=rows)
+            return rows
+
+        return spy
+
+
+class _FrameSpy(_BodySpy):
+    """Keeping besides, for every step, the frame velocity that
     AdvectionDiffusion was handed (``frame_seen``) beside the one upstream
     prescribes: minus the mean translational velocity, before the step,
-    of the bodies that fix the frame (``frame_due``).  ``_advdiff`` is
-    rebound at every regrid, so the spy sits on the attribute."""
+    of the bodies that fix the frame (``frame_due``)."""
 
     def __init__(self, cfg):
         self.frame_seen, self.frame_due = [], []
@@ -127,7 +177,7 @@ def test_forest_moments_run_at_highest(fish_sim):
     sim = fish_sim
     cms = jnp.asarray(np.stack([ob.centerOfMass for ob in sim.obstacles]),
                       sim.dtype)
-    jaxpr = jax.make_jaxpr(sim._moments)(
+    jaxpr = jax.make_jaxpr(sim._moments_read)(
         tuple(ob.chi for ob in sim.obstacles), sim.state["vel"], cms)
     assert_dots_highest(jaxpr, at_least=5 * len(sim.obstacles))
 
@@ -229,25 +279,21 @@ def test_forest_step_dispatch_counts(fish_sim, tmp_path):
     """One more ``advance()``, every profiler section a counted span."""
     sim = fish_sim
     blocks = sim.grid.nb
-    profiler = sim.profiler
-    sim.profiler = SpannedProfiler(profiler)
-
-    def one_more_advance():
-        dt = sim.calc_max_timestep()
-        with span("advance"):
-            sim.advance(dt)
-        jax.block_until_ready(sim.state["vel"])
-
-    try:
-        counts = dispatches(one_more_advance, str(tmp_path))
-    finally:
-        sim.profiler = profiler
+    counts = advance_dispatches(sim, str(tmp_path))
     assert sim.grid.nb == blocks, "a regrid retraces: count another step"
     # two fish: 1 program and 2 uploads (the midlines with their frames,
     # the candidate blocks); 55 and 66 before
     programs, uploads = counts["CreateObstacles"]
     assert programs <= 2 and uploads <= 2, counts
-    assert counts["advance"][0] <= 105, counts  # 151 before
+    # each body operator one program fed by one upload (PR 35); op by op
+    # they were 40 / 19, 38 / 14 and 7 / 1
+    for section, most in (("Penalization", (2, 2)),
+                          ("ComputeForces", (2, 2)),
+                          ("UpdateObstacles", (2, 1))):
+        programs, uploads = counts[section]
+        assert programs <= most[0] and uploads <= most[1], (section, counts)
+    programs, uploads = counts["advance"]
+    assert programs <= 25 and uploads <= 10, counts  # 97 / 38 before
 
 
 def parent_chain(sim, ob):
@@ -347,3 +393,90 @@ def test_sphere_goes_through_the_generic_tail(tmp_path):
     assert counts["CreateObstacles"] == (2, 2), counts
     assert sorted(np.bincount(np.asarray(sim.grid.level))) == [7, 8]
     assert_matches_parent_chain(sim, combine=True)
+
+
+# -- Penalization and ComputeForces: the parent's chain -----------------------
+
+
+def parent_penalization(sim, vel_old, dt):
+    """(vel, (n_obs, 6) rows) from the host mirrors as the parent
+    dispatched Penalization: three uploads and ``ubody`` per body, the
+    chi-weighted mean op by op, lambda = DLM / dt, ``penalize``, the
+    centres of mass and ``dt`` uploaded again, ``penal_force``, negated."""
+    dtype, xc, obs = sim.dtype, sim._xc, sim.obstacles
+    dt_j = jnp.asarray(dt, dtype)
+    num = 0.0
+    for ob in obs:
+        cm, ut, om = (jnp.asarray(v, dtype) for v in (
+            ob.centerOfMass, ob.transVel, ob.angVel))
+        ubody = ut + jnp.cross(jnp.broadcast_to(om, xc.shape), xc - cm) \
+            + ob.udef
+        num = num + ob.chi[..., None] * ubody
+    chis = jnp.stack([ob.chi for ob in obs])
+    den = jnp.maximum(jnp.sum(chis, axis=0), 1e-6)[..., None]
+    assert sim.cfg.DLM > 0
+    lam = jnp.asarray(sim.cfg.DLM, dtype) / dt_j
+    vel = penalize(vel_old, sim.state["chi"], num / den, lam, dt_j)
+    cms = jnp.stack([jnp.asarray(ob.centerOfMass, dtype) for ob in obs])
+    rows = -per_obstacle_penalization_force(
+        vel, vel_old, tuple(ob.chi for ob in obs), jnp.asarray(dt, dtype),
+        sim._vol, xc, cms)
+    return vel, rows
+
+
+def parent_forces(sim, budgets):
+    """The FORCE_PACK rows as the parent dispatched ComputeForces: per
+    body the jitted probe behind its six uploads, and ``pack_forces``."""
+    fields = {"vel": sim.state["vel"], "p": sim.state["p"]}
+    return jnp.stack([
+        pack_forces(force_integrals_probe_blocks(
+            sim.grid, fields, ob.chi, ob.sdf, ob.udef, sim.nu, ob.position,
+            ob.length, ob.centerOfMass, ob.transVel, ob.angVel,
+            max_points=budget))
+        for ob, budget in zip(sim.obstacles, budgets)
+    ])
+
+
+def assert_bodies_match_parent_chain(sim, dt, tol=1e-6):
+    """What the fused body operators of the step just taken gave, against
+    the parent's chain on the same mirrors and fields: the mirrors stand
+    as Penalization read them until the next step's update, and nothing
+    writes the velocity or the pressure after the projection."""
+    seen, n_obs = sim.bodies_seen, len(sim.obstacles)
+    vel, rows = parent_penalization(sim, seen["vel_old"], dt)
+    forces = parent_forces(sim, seen["budgets"])
+    gaps = {}
+    for what, got, ref in (
+            ("vel", seen["vel"], vel),
+            ("penal", seen["penal"].reshape(n_obs, 6), rows),
+            ("forces", seen["forces"].reshape(n_obs, FORCE_PACK), forces)):
+        assert got.shape == ref.shape, what
+        scale = float(jnp.max(jnp.abs(ref)))
+        assert scale > 0.0, what
+        gaps[what] = float(jnp.max(jnp.abs(got - ref))) / scale
+    assert all(gap <= tol for gap in gaps.values()), gaps
+    assert float(jnp.max(jnp.abs(vel - seen["vel_old"]))) > 1e-4, \
+        "the penalization moved the fluid"
+
+
+def test_fused_body_operators_match_the_parents_chain(fish_sim):
+    """One more step with the spy on.  Measured on the CPU, of each
+    quantity's largest entry: vel 6.7e-8, penal 2.2e-7, forces 0 (one
+    program fuses what op by op rounded in between); held to 1e-6."""
+    sim = fish_sim
+    before = obs_metrics.snapshot()
+    sim.watch_bodies = True
+    try:
+        dt = sim.calc_max_timestep()
+        sim.advance(dt)
+    finally:
+        sim.watch_bodies = False
+    assert_bodies_match_parent_chain(sim, dt)
+    # the rows reach the bodies in the same step, through the packed read
+    rows = np.asarray(sim.bodies_seen["penal"], np.float64)
+    for i, ob in enumerate(sim.obstacles):
+        np.testing.assert_array_equal(ob.penal_force, rows[6 * i:6 * i + 3])
+    # two fish apart: the step ran with no contact work
+    moved = obs_metrics.delta(before)
+    assert moved["operators.body_steps_fused"] == 1
+    assert moved.get("operators.body_steps_contact", 0) == 0

@@ -25,6 +25,7 @@ import pytest
 from benchmarks.lib import compare, drive, spec
 from cup3d_tpu.__main__ import build_driver
 from cup3d_tpu.obs import metrics as obs_metrics
+from tests._dispatch import advance_dispatches
 from tests.test_bucketing import _states
 
 #: The cell's limits (benchmarks/workloads/amr_tgv.step.json), and why they
@@ -265,3 +266,17 @@ def test_the_dense_coarse_solve_takes_the_iterations_of_the_loop(rows):
         iterations[arm] = int(np.asarray(stats)[1])
     assert iterations["dense"] > 2
     assert abs(iterations["dense"] - iterations["loop"]) <= 1, iterations
+
+
+def test_a_step_with_no_body_dispatches_what_it_always_did(rows, tmp_path):
+    """One more ``advance()`` with every profiler section a counted span
+    (``tests/_dispatch.py``): the body operators of the forest's per-step
+    path are programs of their own since PR 35, and a flow with no body
+    runs none of them.  The counts are the parent's."""
+    sim = rows["driver"].sim
+    assert not sim.obstacles and not sim._adapt_due(sim.step_idx)
+    counts = advance_dispatches(sim, str(tmp_path))
+    assert counts == {
+        "advance": (6, 1), "CreateObstacles": (0, 0),
+        "AdvectionDiffusion": (1, 0), "PressureProjection": (1, 0),
+        "SyncQoI": (3, 0)}, counts

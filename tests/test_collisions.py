@@ -198,3 +198,51 @@ def test_penalization_force_conservation_and_attribution():
         vn, vo, (chi1, jnp.zeros_like(chi2)), dt, vol, xc, cms
     ))
     np.testing.assert_allclose(PF0[1], 0.0, atol=1e-12)
+
+
+def test_contact_step_takes_the_slow_branch_then_the_fused_penalization():
+    """Two forced spheres spawned overlapping and approaching on a small
+    forest, per-step path: the pre-check counts cells in both, the step
+    builds the bodies' velocity fields op by op for the impulse, the
+    velocities exchange and latch, and the fused Penalization then runs
+    with the latched velocities (held to the parent's chain, which reads
+    the same mirrors)."""
+    from cup3d_tpu.config import SimulationConfig
+    from cup3d_tpu.obs import metrics as obs_metrics
+    from tests.test_amr_fish import (
+        _BodySpy,
+        assert_bodies_match_parent_chain,
+    )
+
+    sim = _BodySpy(SimulationConfig(
+        bpdx=1, bpdy=1, bpdz=1, levelMax=2, levelStart=1, extent=1.0,
+        CFL=0.4, Ctol=0.1, Rtol=5.0, nu=1e-3, tend=0.0, nsteps=2,
+        rampup=0, dt=2e-3, poissonSolver="iterative", poissonTol=1e-6,
+        poissonTolRel=1e-4, verbose=False, freqDiagnostics=0,
+        factory_content=(
+            "Sphere radius=0.12 xpos=0.45 ypos=0.5 zpos=0.5 xvel=0.5"
+            " bForcedInSimFrame=1\n"
+            "Sphere radius=0.12 xpos=0.55 ypos=0.5 zpos=0.5 xvel=-0.5"
+            " bForcedInSimFrame=1"),
+    ))
+    sim.init()
+    sim.adapt_enabled = False
+    sim.watch_bodies = True
+    a, b = sim.obstacles
+    before = obs_metrics.snapshot()
+    dt = sim.calc_max_timestep()
+    sim.advance(dt)
+    moved = obs_metrics.delta(before)
+    assert moved["operators.body_steps_contact"] == 1
+    assert moved.get("operators.body_steps_fused", 0) == 0
+    assert sim._overlap_now
+    # the impulse fired: the approach is reversed, and latched for the
+    # next step's update
+    assert a.transVel[0] < 0.0 < b.transVel[0]
+    for ob in (a, b):
+        assert ob.collision_counter > 0
+        np.testing.assert_array_equal(ob.transVel, ob.collision_vel)
+    assert_bodies_match_parent_chain(sim, dt)
+    # inside the first sphere the fluid was pushed the latched way
+    inside = np.asarray(a.chi) > 0.99
+    assert np.asarray(sim.bodies_seen["vel"])[inside][:, 0].mean() < 0.0
