@@ -53,6 +53,11 @@ BASELINE_CELLS_PER_SEC = 64 * 5.24e5
 # values (fish128 ~0.017, fish256 ~0.034, two_fish_amr ~0.0017; VERDICT
 # r5 weak #9) — a 4x divergence regression now FAILS the bench, where the
 # old flat 0.15 gate let up to ~9x through.  Keyed by (config, n).
+# fish256 on one v5e through the benchmark's harness, K=8 scan (the cell
+# fish256.scan, PR 36; PERF.md section 4):
+# 0.009-0.028 after the 104-step ramp on 20 seeds and 0.010-0.021 at
+# step 176 on 14, so round 5's ~0.034 is not what the chip reads now and
+# 0.07 holds with 2.5x of room.
 DIV_FLUID_GATES = {
     ("fish", 128): 0.04,
     ("fish", 256): 0.07,

@@ -594,18 +594,25 @@ def momentum_integrals_core(x: jnp.ndarray, vol, chi: jnp.ndarray,
     """Layout-generic chi-weighted moments (KernelIntegrateFluidMomenta,
     main.cpp:13625-13735).  x: (..., 3) cell centers; vol: scalar or array
     broadcastable to chi (per-cell volume); works for the dense uniform
-    layout and the (nb, bs, bs, bs) AMR block layout alike."""
+    layout and the (nb, bs, bs, bs) AMR block layout alike.
+
+    The products run at HIGHEST: the default rounds their float32
+    operands to bfloat16 on the TPU, and the rigid velocity built from
+    these sums then misses a float64 step by up to 2.6e-3 of a body's
+    speed on the forest and 1.2e-3 on the uniform 256^3 grid (PERF.md
+    section 7, fault 4)."""
     w = (chi * vol).reshape(-1)
     xf = x.reshape(-1, 3)
     vf = vel.reshape(-1, 3)
     mass = jnp.sum(w)
-    center = w @ xf
-    lin = w @ vf
     r = xf - cm_guess
-    ang = w @ jnp.cross(r, vf)
     r2 = jnp.sum(r * r, axis=-1)
     eye = jnp.eye(3, dtype=vel.dtype)
-    inertia = jnp.sum(w * r2) * eye - jnp.einsum("n,na,nb->ab", w, r, r)
+    with jax.default_matmul_precision("highest"):
+        center = w @ xf
+        lin = w @ vf
+        ang = w @ jnp.cross(r, vf)
+        inertia = jnp.sum(w * r2) * eye - jnp.einsum("n,na,nb->ab", w, r, r)
     return {"mass": mass, "center": center, "lin_mom": lin, "ang_mom": ang,
             "inertia": inertia}
 

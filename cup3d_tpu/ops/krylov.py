@@ -383,8 +383,11 @@ def make_twolevel_preconditioner_lanes(grid: UniformGrid, h2: float,
 
     Measured on the 128^3 pressure system this converges in 12 outer
     BiCGSTAB iterations vs 51 for the tile solve alone, and the count is
-    resolution-independent (11-12 at 64^3/128^3/256^3) — the coarse level
-    carries the smooth modes the block-local getZ cannot see.
+    resolution-independent (11-12 at 64^3/128^3/256^3; on one v5e the
+    benchmark's harness reads 11.3 a solve on the 128^3 fish and
+    10.4-10.6 on the 256^3 fish, warm-started, 11-12 in the solve probe:
+    PERF.md, PR 36) — the coarse
+    level carries the smooth modes the block-local getZ cannot see.
 
     Coarse-first ordering makes the multiplicative coupling nearly free:
     zc is CONSTANT per tile, so A zc is nonzero only on the 6 tile-face

@@ -151,6 +151,11 @@ class RecoveryEngine:
             self.driver._resilience = None
         if self.flight.recovery_intercept is self._hook:
             self.flight.recovery_intercept = None
+        # the engine refers to itself (``_hook``), so only the cyclic
+        # collector frees it: an engine taken off would keep its device
+        # copy of every field until then, one more for each
+        # ``simulate()`` call (0.54 GB each at 256^3)
+        self._snap = None
 
     # -- flight-recorder interception --------------------------------------
 

@@ -172,18 +172,13 @@ def make_step_bodies(
 
     def moments(chis, vel, cms, view):
         # ``chis``: a tuple of fields (host path) or their stack (megastep).
-        # The moments' matmuls at HIGHEST: the default rounds their
-        # float32 operands to bfloat16 on the TPU, and the rigid velocity
-        # built from them then misses the float64 reference by up to
-        # 2.6e-3 of a body's speed (PERF.md section 7, fault 4)
-        with jax.default_matmul_precision("highest"):
-            return jnp.stack([
-                pack_moments(
-                    momentum_integrals_core(view.xc, view.vol, chis[i], vel,
-                                            cms[i])
-                )
-                for i in range(len(chis))
-            ])
+        return jnp.stack([
+            pack_moments(
+                momentum_integrals_core(view.xc, view.vol, chis[i], vel,
+                                        cms[i])
+            )
+            for i in range(len(chis))
+        ])
 
     def overlaps(chis):
         """The collision pre-check: cells inside both bodies, per pair

@@ -482,6 +482,7 @@ class Simulation:
         steps late."""
         import jax.numpy as jnp
 
+        from cup3d_tpu.obs import metrics as obs_metrics
         from cup3d_tpu.sim import dtpolicy
         from cup3d_tpu.sim import megaloop as ml
 
@@ -517,6 +518,7 @@ class Simulation:
                 cfl_dev = jnp.asarray(cfl)
             with s.profiler("Megaloop"):
                 carry, rows = fn(self._scan_carry, cfl_dev)
+            obs_metrics.counter("megaloop.dispatches").inc()
             self._scan_carry = carry
             # the megaloop donates its carry: rebind the field state to
             # the carried arrays so dumps/snapshots/fallback see live
