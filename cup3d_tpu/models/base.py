@@ -31,6 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from cup3d_tpu.grid.uniform import UniformGrid
+from cup3d_tpu.obs import metrics as obs_metrics
 from cup3d_tpu.ops.chi import grad_chi, heaviside
 from cup3d_tpu.ops.diagnostics import swim_split
 
@@ -209,6 +210,10 @@ _fields_from_sdf = jax.jit(fields_from_sdf,
 class Obstacle:
     """One immersed body.  Subclasses implement ``rasterize()`` (and
     optionally ``update_shape()`` for deforming bodies)."""
+
+    # slot budget of the force probe built for this body (ops/surface.
+    # obstacle_probe_budget); 0 until a probe is built
+    probe_slots = 0
 
     def __init__(self, sim, spec: Dict[str, str]):
         self.sim = sim
@@ -523,9 +528,8 @@ _FORCE_KEYS = ("pres_force", "visc_force", "torque", "power", "pout_bnd",
 # packed force-vector width (3+3+3 vectors + 7 scalars + n_surf): the full
 # 19-QoI reduction set of the reference's ComputeForces
 # (main.cpp:13089-13108 — surfForce there is presForce+viscForce, derived
-# on unpack here) plus the probe's surface-cell count (drives the
-# compacted probe's adaptive slot budget, ops/surface.py
-# obstacle_probe_budget)
+# on unpack here) plus the probe's surface-cell count (held against the
+# probe's slot budget where the row is stored, store_force_qoi)
 FORCE_PACK = 17
 
 
@@ -697,11 +701,15 @@ def store_force_qoi(ob, f: Dict[str, np.ndarray]) -> None:
     ob.def_power = f["def_power"]
     ob.def_power_bnd = f.get("def_power_bnd", 0.0)
     ob.p_locom = f.get("p_locom", 0.0)
-    # measured surface-band size: feeds the compacted probe's adaptive
-    # slot budget (ops/surface.obstacle_probe_budget)
+    # measured surface-band size against the probe's slot budget: a band
+    # over it was cut to its largest cells (ops/surface.
+    # surface_force_window), and the forces then miss the tail
     n_surf = f.get("n_surf", 0.0)
-    if n_surf > 0:
-        ob.n_surf_points = n_surf
+    if n_surf > 0 and ob.probe_slots:
+        obs_metrics.counter(
+            "operators.probe_compacted" if n_surf <= ob.probe_slots
+            else "operators.probe_truncated"
+        ).inc()
     d = derived_force_qoi(f, ob.transVel)
     ob.Pthrust, ob.Pdrag, ob.EffPDef = d["Pthrust"], d["Pdrag"], d["EffPDef"]
     ob.EffPDefBnd = d["EffPDefBnd"]

@@ -81,6 +81,27 @@ def _gather(fflat, ix, iy, iz, shape):
     return fflat[_flat_index(ix, iy, iz, shape)]
 
 
+def _surface_band(sdf, chi, valid, h):
+    """Surface measure dS, outward unit normal and band mask of a window
+    (KernelCharacteristicFunction): dense over the window, but all static
+    shifts — cheap VPU passes."""
+    gphi = jnp.stack([_central(sdf, a) for a in range(3)], -1)  # undivided*h
+    gH = jnp.stack([_central(chi, a) for a in range(3)], -1)
+    gphi2 = jnp.sum(gphi * gphi, -1) + _EPS
+    # (gH.gphi)/|gphi|^2 with BOTH gradients undivided equals the physical
+    # Towers surface density delta(x) [1/length]; dS = delta * h^3
+    # (reference Delta = fac1*numD/gradUSq with its 2h/inv2h bookkeeping)
+    dS = jnp.sum(gH * gphi, -1) / gphi2 * (h * h * h)
+    nhat = -gphi / jnp.sqrt(gphi2)[..., None]  # outward unit normal
+    return dS, nhat, (dS > 1e-12) & valid
+
+
+# slots the probe evaluates per trip of its loop over the occupied ones:
+# a trip costs ~1 us a slot and little else on a v5e, so a small chunk
+# wastes least on the last trip (a forest window's band is ~700 cells)
+_PROBE_CHUNK = 1024
+
+
 def surface_force_window(
     vel: jnp.ndarray,  # (Wx, Wy, Wz, 3) window velocity
     p: jnp.ndarray,  # (Wx, Wy, Wz)
@@ -102,63 +123,57 @@ def surface_force_window(
     force, torque, power, thrust/drag/def_power) measured at probed
     surface points.
 
-    ``max_points`` (static) compacts the surface band to at most that many
-    points before the probe math runs.  The band is SPARSE — measured 2674
-    surface cells in an 88^3-cell (~680k) window for the 128^3 fish —
-    while the marching/one-sided/mixed stencils cost ~60 gathered samples
-    per evaluation point; run dense over the window they made ComputeForces
-    0.41 s/step of device time (the whole step is ~0.06 s without it,
-    profiled r4).  ``jnp.nonzero(size=K)`` is the static-shape compaction
-    (the TPU analogue of the reference's ragged per-block surface lists,
-    main.cpp:7256-7478); overflow is detectable via the returned
-    ``n_surf`` (callers size K generously from probe_max_points)."""
+    ``max_points`` (static) is the budget of slots, K = min(max_points,
+    cells of the window); ``None`` makes every cell a slot.  The band is
+    SPARSE — measured 2674 surface cells in an 88^3-cell (~680k) window
+    for the 128^3 fish — while the marching/one-sided/mixed stencils cost
+    ~60 gathered samples per evaluation point, ~1 us a slot on a v5e, so
+    the probe costs what the slots it evaluates cost and little else:
+
+    - ``lax.top_k`` of the measure fills the K slots, largest ``dS``
+      first (the static-shape analogue of the reference's ragged
+      per-block surface lists, main.cpp:7256-7478): 4.8 ms over a 144^3
+      window on a v5e, whatever K.  The band's ``min(n_surf, K)`` cells
+      are the first slots.  A band over the budget loses its
+      smallest-measure tail; ``n_surf`` still returns the true count and
+      the callers' sink counts such rows (models/base.store_force_qoi).
+    - The slots are then evaluated ``_PROBE_CHUNK`` at a time in a loop
+      over the OCCUPIED ones only, the sums of the force pack added up
+      chunk by chunk: a budget of 20x the band (probe_max_points) costs
+      what the band costs.  ``per_point`` evaluates all K slots at once
+      and returns them.
+
+    The evaluated cells and their order are the same whatever the budget,
+    as long as the band fits; the last digits of the float32 sums depend
+    on the chunking."""
     shape = vel.shape[:3]
     dtype = vel.dtype
 
-    # -- surface measure + outward normal (KernelCharacteristicFunction) --
-    # dense over the window, but all static shifts — cheap VPU passes
-    gphi = jnp.stack([_central(sdf, a) for a in range(3)], -1)  # undivided*h
-    gH = jnp.stack([_central(chi, a) for a in range(3)], -1)
-    gphi2 = jnp.sum(gphi * gphi, -1) + _EPS
-    # (gH.gphi)/|gphi|^2 with BOTH gradients undivided equals the physical
-    # Towers surface density delta(x) [1/length]; dS = delta * h^3
-    # (reference Delta = fac1*numD/gradUSq with its 2h/inv2h bookkeeping)
-    dS_w = jnp.sum(gH * gphi, -1) / gphi2 * (h * h * h)
-    nhat_w = -gphi / jnp.sqrt(gphi2)[..., None]  # outward unit normal
-    surf_w = (dS_w > 1e-12) & valid
+    dS_w, nhat_w, surf_w = _surface_band(sdf, chi, valid, h)
+
+    # flat once, here: a reshape inside probe() is a copy of the window
+    # in every trip of the loop below
+    def flat(fw):
+        return fw.reshape((-1,) + fw.shape[3:])
+
+    chif, validf, velf = flat(chi), flat(valid), flat(vel)
+    dSf, nhatf, xcf, pf, udeff = (flat(f) for f in (dS_w, nhat_w, xc, p, udef))
 
     # -- compact the band to K static slots --------------------------------
+    ncells = int(np.prod(shape))
+    K = ncells if max_points is None else min(int(max_points), ncells)
+    surf_flat = flat(surf_w)
+    n_surf = jnp.sum(surf_flat.astype(jnp.int32))
     # top-K by dS (not first-K): if the band exceeds the budget, the
     # dropped cells are the SMALLEST-measure tail (graceful truncation
     # bounded by the tail's dS sum), not a spatially-biased trailing set
-    ncells = int(np.prod(shape))
-    K = ncells if max_points is None else min(int(max_points), ncells)
-    surf_flat = surf_w.reshape(-1)
-    n_surf = jnp.sum(surf_flat.astype(jnp.int32))
-    dS_flat = jnp.where(surf_flat, dS_w.reshape(-1), 0.0)
-    top_dS, iflat0 = jax.lax.top_k(dS_flat, K)
-    pt_ok = top_dS > 0
+    iflat = jax.lax.top_k(jnp.where(surf_flat, dSf, 0.0), K)[1]
+    # sorted descending, so the occupied slots are the first n_pts
+    n_pts = jnp.minimum(n_surf, K)
 
-    def take_s(fw):
-        return fw.reshape(-1)[iflat0]
-
-    def take_v(fw):
-        return fw.reshape((-1,) + fw.shape[3:])[iflat0]
-
-    dS = jnp.where(pt_ok, take_s(dS_w), 0.0)
-    surf = pt_ok & (dS > 0)
-    nhat = take_v(nhat_w)
-    xc = take_v(xc)
-    P = take_s(p)
-    v_base = take_v(vel)
-    u_base = take_v(udef)
-    base = (
-        (iflat0 // (shape[1] * shape[2])).astype(jnp.int32),
-        ((iflat0 // shape[2]) % shape[1]).astype(jnp.int32),
-        (iflat0 % shape[2]).astype(jnp.int32),
-    )
-    chif = chi.reshape(-1)
-    validf = valid.reshape(-1)
+    vel_norm = jnp.linalg.norm(u_trans)
+    vel_unit = jnp.where(vel_norm > 1e-9, u_trans / jnp.where(
+        vel_norm > 0, vel_norm, 1.0), 0.0)
 
     def inwin(ix, iy, iz):
         geo = (
@@ -181,186 +196,195 @@ def surface_force_window(
                 ok = ok & inwin(*o)
         return ok
 
-    # -- probe point: march outward to the first chi < 0.01 cell ----------
-    px, py, pz = base
-    found = jnp.zeros_like(pt_ok)
-    for k in range(5):
-        cx = base[0] + jnp.round(k * nhat[..., 0]).astype(jnp.int32)
-        cy = base[1] + jnp.round(k * nhat[..., 1]).astype(jnp.int32)
-        cz = base[2] + jnp.round(k * nhat[..., 2]).astype(jnp.int32)
-        ok = nbhd_ok(cx, cy, cz) & ~found
-        px = jnp.where(ok, cx, px)
-        py = jnp.where(ok, cy, py)
-        pz = jnp.where(ok, cz, pz)
-        found = found | (ok & (_gather(chif, cx, cy, cz, shape) < 0.01))
-
-    sx = jnp.where(nhat[..., 0] > 0, 1, -1).astype(jnp.int32)
-    sy = jnp.where(nhat[..., 1] > 0, 1, -1).astype(jnp.int32)
-    sz = jnp.where(nhat[..., 2] > 0, 1, -1).astype(jnp.int32)
-
-    velf = vel.reshape(-1, 3)
-
-    def vat(ix, iy, iz):
-        return _gather(velf, ix, iy, iz, shape)
-
-    def axis_pts(axis, s):
-        """Probe-relative sample positions k*s along one axis."""
-        def at(k):
-            o = [px, py, pz]
-            o[axis] = o[axis] + k * s
-            return o
-        return at
-
-    def one_sided(axis, s):
-        """Undivided one-sided first derivative at the probe point:
-        6-pt 5th order -> 3-pt 2nd order -> 2-pt 1st order, by range
-        (reference inrange cascade)."""
-        at = axis_pts(axis, s)
-        v = [vat(*at(k)) for k in range(6)]
-        d6 = s[..., None] * sum(c * vk for c, vk in zip(_C6, v))
-        d3 = s[..., None] * (-1.5 * v[0] + 2.0 * v[1] - 0.5 * v[2])
-        d2 = s[..., None] * (v[1] - v[0])
-        # every intermediate sample must be valid, not just the endpoint:
-        # an AMR-window hole (slot=-1) between probe and endpoint would be
-        # zero-filled while the endpoint check passes (ADVICE r3)
-        oks = [inwin(*at(k)) for k in range(6)]
-        ok5 = (oks[1] & oks[2] & oks[3] & oks[4] & oks[5])[..., None]
-        ok2 = (oks[1] & oks[2])[..., None]
-        # final 2-pt fallback still reads at(1): zero the derivative when
-        # even that neighbor is a hole (code-review r4)
-        d2 = jnp.where(oks[1][..., None], d2, 0.0)
-        return jnp.where(ok5, d6, jnp.where(ok2, d3, d2))
-
-    dvdx = one_sided(0, sx)
-    dvdy = one_sided(1, sy)
-    dvdz = one_sided(2, sz)
-
-    # when no marching candidate passed nbhd_ok the probe stays at base
-    # with NO neighborhood guarantee: gate every centered/compact stencil
-    # below so holes demote to a zero (lower-order) contribution instead of
-    # reading clamped/zero-filled cells (code-review r4)
-    probe_ok = nbhd_ok(px, py, pz)
-
-    def second(axis):
-        o = [px, py, pz]
-        o2 = [px, py, pz]
-        o = list(o)
-        o[axis] = o[axis] + 1
-        o2[axis] = o2[axis] - 1
-        d2 = vat(*o) - 2.0 * vat(px, py, pz) + vat(*o2)
-        return jnp.where(probe_ok[..., None], d2, 0.0)
-
-    d2x, d2y, d2z = second(0), second(1), second(2)
-
-    def mixed(a1, s1, a2, s2):
-        """Nested one-sided mixed derivative (reference dveldxdy form),
-        falling back to the compact 2x2 form when out of range."""
-        def at(k1, k2):
-            o = [px, py, pz]
-            o[a1] = o[a1] + k1 * s1
-            o[a2] = o[a2] + k2 * s2
-            return o
-
-        def row(k1):  # 3-pt one-sided along a2 at offset k1 along a1
-            return (-1.5 * vat(*at(k1, 0)) + 2.0 * vat(*at(k1, 1))
-                    - 0.5 * vat(*at(k1, 2)))
-
-        full = (s1 * s2)[..., None] * (
-            -0.5 * row(2) + 2.0 * row(1) - 1.5 * row(0)
+    def probe(iflat0, pt_ok):
+        """The probe at the window cells ``iflat0`` (flat indices, one per
+        slot; a slot where ``pt_ok`` is False counts for nothing): the ten
+        sums of the force pack and the per-point record."""
+        dS = jnp.where(pt_ok, dSf[iflat0], 0.0)
+        surf = pt_ok & (dS > 0)
+        nhat = nhatf[iflat0]
+        x_base = xcf[iflat0]
+        P = pf[iflat0]
+        v_base = velf[iflat0]
+        u_base = udeff[iflat0]
+        base = (
+            (iflat0 // (shape[1] * shape[2])).astype(jnp.int32),
+            ((iflat0 // shape[2]) % shape[1]).astype(jnp.int32),
+            (iflat0 % shape[2]).astype(jnp.int32),
         )
-        # deliberate divergence: the reference's compact fallback applies
-        # the sign product to only the first difference
-        # (main.cpp:12399-12401), inverting one term whenever the two
-        # normal signs differ; we use the mathematically consistent form
-        compact = (s1 * s2)[..., None] * (
-            (vat(*at(1, 1)) - vat(*at(1, 0)))
-            - (vat(*at(0, 1)) - vat(*at(0, 0)))
+
+        # -- probe point: march outward to the first chi < 0.01 cell ------
+        px, py, pz = base
+        found = jnp.zeros_like(pt_ok)
+        for k in range(5):
+            cx = base[0] + jnp.round(k * nhat[..., 0]).astype(jnp.int32)
+            cy = base[1] + jnp.round(k * nhat[..., 1]).astype(jnp.int32)
+            cz = base[2] + jnp.round(k * nhat[..., 2]).astype(jnp.int32)
+            ok = nbhd_ok(cx, cy, cz) & ~found
+            px = jnp.where(ok, cx, px)
+            py = jnp.where(ok, cy, py)
+            pz = jnp.where(ok, cz, pz)
+            found = found | (ok & (_gather(chif, cx, cy, cz, shape) < 0.01))
+
+        sx = jnp.where(nhat[..., 0] > 0, 1, -1).astype(jnp.int32)
+        sy = jnp.where(nhat[..., 1] > 0, 1, -1).astype(jnp.int32)
+        sz = jnp.where(nhat[..., 2] > 0, 1, -1).astype(jnp.int32)
+
+        def vat(ix, iy, iz):
+            return _gather(velf, ix, iy, iz, shape)
+
+        def axis_pts(axis, s):
+            """Probe-relative sample positions k*s along one axis."""
+            def at(k):
+                o = [px, py, pz]
+                o[axis] = o[axis] + k * s
+                return o
+            return at
+
+        def one_sided(axis, s):
+            """Undivided one-sided first derivative at the probe point:
+            6-pt 5th order -> 3-pt 2nd order -> 2-pt 1st order, by range
+            (reference inrange cascade)."""
+            at = axis_pts(axis, s)
+            v = [vat(*at(k)) for k in range(6)]
+            d6 = s[..., None] * sum(c * vk for c, vk in zip(_C6, v))
+            d3 = s[..., None] * (-1.5 * v[0] + 2.0 * v[1] - 0.5 * v[2])
+            d2 = s[..., None] * (v[1] - v[0])
+            # every intermediate sample must be valid, not just the endpoint:
+            # an AMR-window hole (slot=-1) between probe and endpoint would be
+            # zero-filled while the endpoint check passes (ADVICE r3)
+            oks = [inwin(*at(k)) for k in range(6)]
+            ok5 = (oks[1] & oks[2] & oks[3] & oks[4] & oks[5])[..., None]
+            ok2 = (oks[1] & oks[2])[..., None]
+            # final 2-pt fallback still reads at(1): zero the derivative when
+            # even that neighbor is a hole (code-review r4)
+            d2 = jnp.where(oks[1][..., None], d2, 0.0)
+            return jnp.where(ok5, d6, jnp.where(ok2, d3, d2))
+
+        dvdx = one_sided(0, sx)
+        dvdy = one_sided(1, sy)
+        dvdz = one_sided(2, sz)
+
+        # when no marching candidate passed nbhd_ok the probe stays at base
+        # with NO neighborhood guarantee: gate every centered/compact stencil
+        # below so holes demote to a zero (lower-order) contribution instead of
+        # reading clamped/zero-filled cells (code-review r4)
+        probe_ok = nbhd_ok(px, py, pz)
+
+        def second(axis):
+            o = [px, py, pz]
+            o2 = [px, py, pz]
+            o = list(o)
+            o[axis] = o[axis] + 1
+            o2[axis] = o2[axis] - 1
+            d2 = vat(*o) - 2.0 * vat(px, py, pz) + vat(*o2)
+            return jnp.where(probe_ok[..., None], d2, 0.0)
+
+        d2x, d2y, d2z = second(0), second(1), second(2)
+
+        def mixed(a1, s1, a2, s2):
+            """Nested one-sided mixed derivative (reference dveldxdy form),
+            falling back to the compact 2x2 form when out of range."""
+            def at(k1, k2):
+                o = [px, py, pz]
+                o[a1] = o[a1] + k1 * s1
+                o[a2] = o[a2] + k2 * s2
+                return o
+
+            def row(k1):  # 3-pt one-sided along a2 at offset k1 along a1
+                return (-1.5 * vat(*at(k1, 0)) + 2.0 * vat(*at(k1, 1))
+                        - 0.5 * vat(*at(k1, 2)))
+
+            full = (s1 * s2)[..., None] * (
+                -0.5 * row(2) + 2.0 * row(1) - 1.5 * row(0)
+            )
+            # deliberate divergence: the reference's compact fallback applies
+            # the sign product to only the first difference
+            # (main.cpp:12399-12401), inverting one term whenever the two
+            # normal signs differ; we use the mathematically consistent form
+            compact = (s1 * s2)[..., None] * (
+                (vat(*at(1, 1)) - vat(*at(1, 0)))
+                - (vat(*at(0, 1)) - vat(*at(0, 0)))
+            )
+            # all nine samples of the nested form must be valid (ADVICE r3:
+            # intermediate AMR-window holes must demote to the compact form);
+            # the compact 2x2 form's own samples (incl. the diagonal, which
+            # nbhd_ok never covers) must be valid too, else the mixed term
+            # drops to zero (code-review r4)
+            ok = jnp.ones_like(pt_ok)
+            for k1 in range(3):
+                for k2 in range(3):
+                    ok = ok & inwin(*at(k1, k2))
+            okc = (inwin(*at(0, 0)) & inwin(*at(0, 1)) & inwin(*at(1, 0))
+                   & inwin(*at(1, 1)))
+            compact = jnp.where(okc[..., None], compact, 0.0)
+            return jnp.where(ok[..., None], full, compact)
+
+        dxy = mixed(0, sx, 1, sy)
+        dxz = mixed(0, sx, 2, sz)
+        dyz = mixed(1, sy, 2, sz)
+
+        # Taylor-correct the gradient from the probe point back to the
+        # surface cell (integer offsets; undivided derivatives throughout)
+        ox = (base[0] - px)[..., None].astype(dtype)
+        oy = (base[1] - py)[..., None].astype(dtype)
+        oz = (base[2] - pz)[..., None].astype(dtype)
+        # (..., 3): du/dx, dv/dx, dw/dx
+        gx = dvdx + d2x * ox + dxy * oy + dxz * oz
+        gy = dvdy + d2y * oy + dyz * oz + dxy * ox
+        gz = dvdz + d2z * oz + dxz * ox + dyz * oy
+
+        # -- tractions -----------------------------------------------------
+        n_meas = nhat * dS[..., None]  # outward normal * dS
+        inv_h = nu / h
+        fV = inv_h * (
+            gx * n_meas[..., 0:1] + gy * n_meas[..., 1:2]
+            + gz * n_meas[..., 2:3]
         )
-        # all nine samples of the nested form must be valid (ADVICE r3:
-        # intermediate AMR-window holes must demote to the compact form);
-        # the compact 2x2 form's own samples (incl. the diagonal, which
-        # nbhd_ok never covers) must be valid too, else the mixed term
-        # drops to zero (code-review r4)
-        ok = jnp.ones_like(pt_ok)
-        for k1 in range(3):
-            for k2 in range(3):
-                ok = ok & inwin(*at(k1, k2))
-        okc = (inwin(*at(0, 0)) & inwin(*at(0, 1)) & inwin(*at(1, 0))
-               & inwin(*at(1, 1)))
-        compact = jnp.where(okc[..., None], compact, 0.0)
-        return jnp.where(ok[..., None], full, compact)
+        fP = -P[..., None] * n_meas
+        fT = fV + fP
 
-    dxy = mixed(0, sx, 1, sy)
-    dxz = mixed(0, sx, 2, sz)
-    dyz = mixed(1, sy, 2, sz)
-
-    # Taylor-correct the gradient from the probe point back to the
-    # surface cell (integer offsets; undivided derivatives throughout)
-    ox = (base[0] - px)[..., None].astype(dtype)
-    oy = (base[1] - py)[..., None].astype(dtype)
-    oz = (base[2] - pz)[..., None].astype(dtype)
-    gx = dvdx + d2x * ox + dxy * oy + dxz * oz  # (..., 3): du/dx, dv/dx, dw/dx
-    gy = dvdy + d2y * oy + dyz * oz + dxy * ox
-    gz = dvdz + d2z * oz + dxz * ox + dyz * oy
-
-    # -- tractions ---------------------------------------------------------
-    n_meas = nhat * dS[..., None]  # outward normal * dS
-    inv_h = nu / h
-    fV = inv_h * (
-        gx * n_meas[..., 0:1] + gy * n_meas[..., 1:2] + gz * n_meas[..., 2:3]
-    )
-    fP = -P[..., None] * n_meas
-    fT = fV + fP
-
-    vel_norm = jnp.linalg.norm(u_trans)
-    vel_unit = jnp.where(vel_norm > 1e-9, u_trans / jnp.where(
-        vel_norm > 0, vel_norm, 1.0), 0.0)
-
-    r = xc - cm
-    pres_force = jnp.sum(fP, axis=0)
-    visc_force = jnp.sum(fV, axis=0)
-    torque = jnp.sum(jnp.cross(r, fT), axis=0)
-    force_par = jnp.sum(fT * vel_unit, -1)
-    thrust = jnp.sum(0.5 * (force_par + jnp.abs(force_par)))
-    drag = -jnp.sum(0.5 * (force_par - jnp.abs(force_par)))
-    # power = traction . FLUID velocity at the surface cell — the
-    # reference's Pout (main.cpp:12461); the old band measure used
-    # u_body here, a divergence this kernel removes.  p_locom is the
-    # reference's traction . u_solid work (main.cpp:12470-2476).  The
-    # *Bnd variants clip each point's power to its negative part before
-    # summing (reference PoutBnd/defPowerBnd, main.cpp:12483-12485) —
-    # the "useful work only" bound the swimming-efficiency outputs use.
-    pow_pt = jnp.sum(fT * v_base, -1)
-    defp_pt = jnp.sum(fT * u_base, -1)
-    pow_out = jnp.sum(pow_pt)
-    pout_bnd = jnp.sum(jnp.minimum(pow_pt, 0.0))
-    def_power = jnp.sum(defp_pt)
-    def_power_bnd = jnp.sum(jnp.minimum(defp_pt, 0.0))
-    u_solid = u_trans + jnp.cross(jnp.broadcast_to(omega, r.shape), r)
-    p_locom = jnp.sum(fT * u_solid)
-    out = {
-        "pres_force": pres_force,
-        "visc_force": visc_force,
-        "torque": torque,
-        "power": pow_out,
-        "pout_bnd": pout_bnd,
-        "thrust": thrust,
-        "drag": drag,
-        "def_power": def_power,
-        "def_power_bnd": def_power_bnd,
-        "p_locom": p_locom,
-        # diagnostics: real surface-cell count vs the K slots (overflow
-        # check for max_points; tests/bench assert n_surf <= K)
-        "n_surf": n_surf,
-    }
-    if per_point:
+        r = x_base - cm
+        pres_force = jnp.sum(fP, axis=0)
+        visc_force = jnp.sum(fV, axis=0)
+        torque = jnp.sum(jnp.cross(r, fT), axis=0)
+        force_par = jnp.sum(fT * vel_unit, -1)
+        thrust = jnp.sum(0.5 * (force_par + jnp.abs(force_par)))
+        drag = -jnp.sum(0.5 * (force_par - jnp.abs(force_par)))
+        # power = traction . FLUID velocity at the surface cell — the
+        # reference's Pout (main.cpp:12461); the old band measure used
+        # u_body here, a divergence this kernel removes.  p_locom is the
+        # reference's traction . u_solid work (main.cpp:12470-2476).  The
+        # *Bnd variants clip each point's power to its negative part before
+        # summing (reference PoutBnd/defPowerBnd, main.cpp:12483-12485) —
+        # the "useful work only" bound the swimming-efficiency outputs use.
+        pow_pt = jnp.sum(fT * v_base, -1)
+        defp_pt = jnp.sum(fT * u_base, -1)
+        pow_out = jnp.sum(pow_pt)
+        pout_bnd = jnp.sum(jnp.minimum(pow_pt, 0.0))
+        def_power = jnp.sum(defp_pt)
+        def_power_bnd = jnp.sum(jnp.minimum(defp_pt, 0.0))
+        u_solid = u_trans + jnp.cross(jnp.broadcast_to(omega, r.shape), r)
+        p_locom = jnp.sum(fT * u_solid)
+        sums = {
+            "pres_force": pres_force,
+            "visc_force": visc_force,
+            "torque": torque,
+            "power": pow_out,
+            "pout_bnd": pout_bnd,
+            "thrust": thrust,
+            "drag": drag,
+            "def_power": def_power,
+            "def_power_bnd": def_power_bnd,
+            "p_locom": p_locom,
+        }
         # per-surface-point record (the reference's ObstacleBlock
         # per-point arrays pX..pZ / P / fxP..fzV / vX..vzDef,
         # main.cpp:12300-12330 fill): (K, ...) slot arrays — host
         # consumers compact on the surf mask (compact_surface_points)
-        out["points"] = {
+        points = {
             "surf": surf,
-            "x": xc,
+            "x": x_base,
             "n_dS": n_meas,
             "dS": dS,
             "p": P,
@@ -369,6 +393,29 @@ def surface_force_window(
             "v": v_base,
             "vdef": u_base,
         }
+        return sums, points
+
+    slot = jnp.arange(K, dtype=jnp.int32)
+    if per_point:
+        out, points = probe(iflat, slot < n_pts)
+        out["points"] = points
+    else:
+        # the occupied slots alone, a chunk at a time
+        C = min(K, _PROBE_CHUNK)
+        iflat = jnp.pad(iflat, (0, -K % C))
+
+        def chunk(i, acc):
+            at = i * C
+            part, _ = probe(jax.lax.dynamic_slice(iflat, (at,), (C,)),
+                            at + slot[:C] < n_pts)
+            return jax.tree.map(jnp.add, acc, part)
+
+        sums = jax.eval_shape(lambda: probe(iflat[:C], slot[:C] < 0)[0])
+        zero = jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype), sums)
+        out = jax.lax.fori_loop(0, -(-n_pts // C), chunk, zero)
+    # diagnostics: real surface-cell count vs the K slots (overflow check
+    # for max_points; store_force_qoi counts the rows that overflowed)
+    out["n_surf"] = n_surf
     return out
 
 
@@ -396,38 +443,24 @@ def window_size_cells(length: float, h: float, bs: int = 8) -> int:
 
 
 def probe_max_points(length: float, h) -> int:
-    """Static surface-point slot budget for the compacted probe, with no
-    prior measurement.  The Towers band holds ~(L/h)^2 cells for a fish
-    (measured 1.02x at 128^3) and ~pi (L/h)^2 for a sphere of diameter L,
-    but the wide sine-mollifier chi (ops/chi.heaviside, tests/diagnostics)
-    carries ~18 (L/h)^2 — 20x covers every construction.  Rounded to 1024
-    so jit retraces only on resolution buckets.  Steady-state consumers
-    tighten this to ~4x the MEASURED band via obstacle_probe_budget
-    (n_surf rides the packed force QoI)."""
+    """Static surface-point slot budget of the probe.  The Towers band
+    holds ~(L/h)^2 cells for a fish (measured 1.02x at 128^3) and
+    ~pi (L/h)^2 for a sphere of diameter L, but the wide sine-mollifier
+    chi (ops/chi.heaviside, tests/diagnostics) carries ~18 (L/h)^2 — 20x
+    covers every construction.  Rounded to 1024 so jit retraces only on
+    resolution buckets.  Generous costs nothing: the probe's top_k costs
+    the same at every K and its loop runs over the occupied slots only
+    (surface_force_window)."""
     n = 20.0 * (float(length) / float(h)) ** 2
     return int(max(4096, -(-n // 1024) * 1024))
 
 
 def obstacle_probe_budget(ob, h) -> int:
-    """Per-obstacle slot budget: once a measured band size is available
-    (ob.n_surf_points, refreshed by every packed force read), budget 4x
-    the measurement; hysteresis keeps the previous budget while it stays
-    within [2x, 8x] measured, so steady swimming never retraces.  Safe
-    either way: surface_force_window truncates top-K by dS (smallest-
-    measure tail dropped first) and n_surf keeps reporting the true
-    count."""
-    n = float(getattr(ob, "n_surf_points", 0) or 0)
-    prev = int(getattr(ob, "_probe_budget", 0) or 0)
-    if n > 0 and np.isfinite(n):
-        if prev and 2.0 * n <= prev <= 8.0 * n:
-            return prev
-        b = int(max(4096, -(-4.0 * n // 1024) * 1024))
-    elif prev:
-        return prev
-    else:
-        b = probe_max_points(ob.length, h)
-    ob._probe_budget = b
-    return b
+    """The body's slot budget on a grid of spacing ``h``, noted on the
+    body (``ob.probe_slots``) for the sink that counts the rows whose
+    band overflowed it (models/base.store_force_qoi)."""
+    ob.probe_slots = probe_max_points(ob.length, h)
+    return ob.probe_slots
 
 
 @partial(jax.jit, static_argnames=("wcells", "per_point", "max_points"))

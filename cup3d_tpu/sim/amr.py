@@ -764,8 +764,7 @@ class AMRSimulation:
     def _forces_kernel(self, budgets, windows):
         """``forces_bodies`` bound like the step kernels, for these static
         point budgets and window shapes; kept with the kernels of its
-        bucket (of its octree signature on a mesh) and built when a
-        body's budget moves, where ``_probe_blocks_jit`` retraced."""
+        bucket (of its octree signature on a mesh)."""
         fn = self._forces_ex.get((budgets, windows))
         if fn is None:
             body = self._step_bodies(budgets, windows).forces_bodies
@@ -804,25 +803,21 @@ class AMRSimulation:
 
         Single device: the jits live in the compiled-step cache keyed by
         (bucket, probe budgets, n_obs), with all topology data as traced
-        args — regrids within a bucket AND ping-pong probe-budget moves
-        reuse compiled executables.  Forest: bound to this signature's
+        args — regrids within a bucket reuse compiled executables.  Forest: bound to this signature's
         view through parallel/forest.py, once per pressure order."""
         from cup3d_tpu.ops.surface import obstacle_probe_budget
 
         g = self.grid
-        # probe slot budgets are STATIC inside the trace: snapshot them at
-        # build time and let advance_pipelined trigger a rebuild when the
-        # adaptive budget moves (code-review r4 — without this, a
-        # static-mesh run freezes the generous pre-measurement prior)
+        # probe slot budgets are STATIC inside the trace
         hf0 = self._h_finest()
-        self._megastep_budgets = tuple(
+        budgets = tuple(
             obstacle_probe_budget(ob, hf0) for ob in self.obstacles
         )
         windows = self._probe_windows()[0]
         if self.forest is not None:
             from cup3d_tpu.parallel.forest import bind_order_executables
 
-            bodies = self._step_bodies(self._megastep_budgets, windows)
+            bodies = self._step_bodies(budgets, windows)
             # (first, second pressure order) per body; vel, p donated
             jits, jits_free = (
                 bind_order_executables(
@@ -834,11 +829,11 @@ class AMRSimulation:
             def geo():  # the view is closed over: no trailing args
                 return ()
         else:
-            key = ("mega", self._bucket_key(), self._megastep_budgets,
+            key = ("mega", self._bucket_key(), budgets,
                    windows, bool(self.cfg.bFixMassFlux))
             ex = self._exec_cache.get(key)
             if ex is None:
-                bodies = self._step_bodies(self._megastep_budgets, windows)
+                bodies = self._step_bodies(budgets, windows)
                 jit_geo = self._geo_binder()
                 ex = tuple(
                     tuple(jit_geo(body, name + ("_2nd" if so else ""),
@@ -1561,17 +1556,6 @@ class AMRSimulation:
                 self.adapt_mesh()
         with self.profiler("CreateObstacles"):
             self.create_obstacles(dt, combine=False)
-        # the probe slot budgets are baked into the megastep trace; when
-        # the adaptive budget moves (first n_surf measurement landing, or
-        # band growth past the hysteresis window) retrace once
-        from cup3d_tpu.ops.surface import obstacle_probe_budget
-
-        hf = self._h_finest()
-        budgets = tuple(
-            obstacle_probe_budget(ob, hf) for ob in self.obstacles
-        )
-        if budgets != self._megastep_budgets:
-            self._build_megastep()
         with self.profiler("Megastep"):
             n = len(self.obstacles)
             chis = jnp.stack([ob.chi for ob in self.obstacles])

@@ -186,9 +186,8 @@ def make_fish_step(s, ob):
     ``one_step(gait, carry, cfl_eff) -> (carry', row (FISH_ROW,))``.
 
     Everything geometric is frozen static at build time: the rasterization
-    window, the probe window + slot budget (obstacle_probe_budget
-    hysteresis is deliberately frozen for the megaloop's lifetime so
-    steady swimming never retraces), and the forced/blocked masks.  The
+    window, the probe window + slot budget (obstacle_probe_budget), and
+    the forced/blocked masks.  The
     frozen-gait parameters are an ARGUMENT pytree rather than a closure,
     so the solo megaloop can bake one gait in as trace-time constants
     while fleet/batch.py stacks per-lane gaits and vmaps over them."""

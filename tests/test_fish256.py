@@ -22,13 +22,15 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from benchmarks.lib import compare, drive, seeding, spec
 from cup3d_tpu.__main__ import build_driver
 from cup3d_tpu.grid.uniform import UniformGrid
-from cup3d_tpu.models.base import momentum_integrals
+from cup3d_tpu.models.base import momentum_integrals, store_force_qoi
 from cup3d_tpu.obs import metrics as obs
+from cup3d_tpu.ops.surface import obstacle_probe_budget
 from tests._grids import assert_dots_highest
 
 SEEDS = (3600000011, 17)
@@ -181,6 +183,49 @@ def test_each_dispatch_is_counted_once(cell, paths):
 
 def test_the_per_step_path_counts_no_dispatch(paths):
     assert not paths("step")["obs"].get("megaloop.dispatches")
+
+
+def test_each_force_row_is_counted_against_its_probes_budget(cell, paths):
+    """``store_force_qoi`` raises ``operators.probe_compacted`` once per
+    stored row whose band fitted the slot budget of its probe (every row
+    here: one a step on the per-step path, one a scanned step on the
+    scan), ``operators.probe_truncated`` never; the reader makes 100 of
+    that, and nothing of a program without the counters."""
+    step, scan = paths("step")["obs"], paths("scan")["unit"]
+    assert step.get("operators.probe_compacted") == 4  # 3 warm-up + 1
+    assert scan.get("operators.probe_compacted") == 2 * K
+    for moved in (step, scan):
+        assert not moved.get("operators.probe_truncated")
+    read = spec.load_reader(cell["bench"],
+                            "operators.probe_compact_share").read
+    assert read({"obs": step}) == 100.0 and read({"obs": scan}) == 100.0
+    assert read({"obs": {}}) is None
+    assert read({"obs": {"megaloop.dispatches": 2}}) is None
+
+
+def test_an_overflowing_row_is_counted_as_truncated(cell):
+    """The sink alone: a row whose n_surf is over the slot budget of the
+    body's probe counts as truncated, one at most the budget as
+    compacted, a row of no probe (n_surf 0: the chi-band integrals) as
+    neither."""
+    class Body:
+        length = 0.4
+        transVel = np.array([0.1, 0.0, 0.0])
+
+    row = {k: 0.0 for k in ("power", "thrust", "drag", "def_power")}
+    row.update(pres_force=jnp.zeros(3), visc_force=jnp.zeros(3),
+               torque=jnp.zeros(3))
+    ob = Body()
+    assert obstacle_probe_budget(ob, 1 / 64) == 13312 == ob.probe_slots
+    obs0 = obs.snapshot()
+    for n_surf in (13312.0, 700.0, 13313.0, 0.0):
+        store_force_qoi(ob, {**row, "n_surf": n_surf})
+    moved = obs.delta(obs0)
+    assert moved["operators.probe_compacted"] == 2
+    assert moved["operators.probe_truncated"] == 1
+    read = spec.load_reader(cell["bench"],
+                            "operators.probe_compact_share").read
+    assert read({"obs": moved}) == pytest.approx(200 / 3)
 
 
 def _differing(a, b, at=""):

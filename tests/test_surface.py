@@ -4,6 +4,7 @@ pressure field must produce the exact buoyancy force (divergence theorem),
 and a constant-gradient velocity field must produce zero net viscous force
 on a closed surface."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -24,14 +25,20 @@ def _sphere_window(n=48, r=0.3):
     return h, xc, jnp.asarray(sdf), jnp.asarray(chi), c
 
 
+# one compile per (shapes, h, nu, per_point, max_points): eager, the
+# probe's loop over its chunks compiles its body anew at every call
+_probe_jit = jax.jit(sf.surface_force_window,
+                     static_argnames=("h", "nu", "per_point", "max_points"))
+
+
 def _probe(vel, p, h, xc, sdf, chi, nu=1e-2, cm=(0.5, 0.5, 0.5)):
     shape = sdf.shape
     valid = jnp.ones(shape, bool)
     udef = jnp.zeros(shape + (3,), jnp.float32)
-    return sf.surface_force_window(
-        vel, p, chi, sdf, udef, valid, jnp.asarray(xc), h, nu,
-        jnp.asarray(cm, jnp.float32), jnp.zeros(3, jnp.float32),
-        jnp.zeros(3, jnp.float32),
+    return _probe_jit(
+        vel, p, chi, sdf, udef, valid, jnp.asarray(xc), h=h, nu=nu,
+        cm=jnp.asarray(cm, jnp.float32), u_trans=jnp.zeros(3, jnp.float32),
+        omega=jnp.zeros(3, jnp.float32),
     )
 
 
@@ -126,7 +133,7 @@ def test_bnd_qoi_and_p_locom():
     ut = jnp.asarray([0.3, -0.1, 0.2], jnp.float32)
     vel = jnp.broadcast_to(ut, sdf.shape + (3,))
     p = jnp.asarray(xc[..., 0] ** 2 - xc[..., 1])
-    out = sf.surface_force_window(
+    out = _probe_jit(
         vel, p, chi, sdf, jnp.zeros(sdf.shape + (3,), jnp.float32),
         jnp.ones(sdf.shape, bool), jnp.asarray(xc), h, 1e-2,
         jnp.asarray(c, jnp.float32), ut, jnp.zeros(3, jnp.float32),
@@ -152,7 +159,7 @@ def test_force_pack_roundtrip_19_qoi():
     vel = jnp.asarray(np.random.default_rng(0).standard_normal(
         sdf.shape + (3,)).astype(np.float32) * 0.1)
     p = jnp.asarray(xc[..., 2])
-    out = sf.surface_force_window(
+    out = _probe_jit(
         vel, p, chi, sdf, 0.05 * vel, jnp.ones(sdf.shape, bool),
         jnp.asarray(xc), h, 1e-2, jnp.asarray(c, jnp.float32),
         jnp.asarray([0.1, 0.0, 0.0], jnp.float32),
@@ -177,7 +184,7 @@ def test_per_point_export_consistent_with_reductions():
     vel = jnp.asarray(np.random.default_rng(1).standard_normal(
         sdf.shape + (3,)).astype(np.float32) * 0.1)
     p = jnp.asarray(xc[..., 0])
-    out = sf.surface_force_window(
+    out = _probe_jit(
         vel, p, chi, sdf, jnp.zeros(sdf.shape + (3,), jnp.float32),
         jnp.ones(sdf.shape, bool), jnp.asarray(xc), h, 1e-2,
         jnp.asarray(c, jnp.float32), jnp.zeros(3, jnp.float32),
@@ -197,24 +204,28 @@ def test_per_point_export_consistent_with_reductions():
     assert abs(rows[:, cols["dS"]].sum() - area) / area < 0.06
 
 
-def test_probe_budget_adaptation():
-    """obstacle_probe_budget: generous prior without a measurement, ~4x
-    the measured band once n_surf lands, hysteresis in [2x, 8x]."""
-    class Ob:
-        length = 0.4
+def test_probe_budget_is_static_and_noted_on_the_body():
+    """obstacle_probe_budget: the generous 20 (L/h)^2 of probe_max_points
+    for the body's length on this grid, noted on the body for the sink's
+    overflow count, and the same whatever band was measured since."""
+    from cup3d_tpu.models.base import Obstacle, store_force_qoi
+
+    class Ob(Obstacle):
+        def __init__(self):
+            self.length = 0.4
+            self.transVel = np.zeros(3)
 
     ob = Ob()
+    assert ob.probe_slots == 0
     k0 = sf.obstacle_probe_budget(ob, 1.0 / 128)
-    assert k0 == sf.probe_max_points(0.4, 1.0 / 128)
-    ob.n_surf_points = 2674.0
-    k1 = sf.obstacle_probe_budget(ob, 1.0 / 128)
-    assert 4 * 2674 <= k1 <= 4 * 2674 + 1024
-    # hysteresis: small drift keeps the budget (no retrace)
-    ob.n_surf_points = 3000.0
-    assert sf.obstacle_probe_budget(ob, 1.0 / 128) == k1
-    # large growth re-budgets
-    ob.n_surf_points = 10 * 2674.0
-    assert sf.obstacle_probe_budget(ob, 1.0 / 128) > k1
+    assert k0 == sf.probe_max_points(0.4, 1.0 / 128) == 53248
+    assert ob.probe_slots == k0
+    row = dict(pres_force=np.zeros(3), visc_force=np.zeros(3),
+               torque=np.zeros(3), power=0.0, thrust=0.0, drag=0.0,
+               def_power=0.0, n_surf=2674.0)
+    store_force_qoi(ob, row)
+    assert sf.obstacle_probe_budget(ob, 1.0 / 128) == k0
+    assert sf.obstacle_probe_budget(ob, 1.0 / 256) == 209920
 
 
 def test_truncation_keeps_largest_measure():
@@ -242,6 +253,157 @@ def test_truncation_keeps_largest_measure():
     F_cut = np.asarray(cut["pres_force"])
     rel = np.linalg.norm(F_cut - F_full) / max(np.linalg.norm(F_full), 1e-12)
     assert rel < 0.15
+
+
+# -- the loop over the band's occupied slots (PR 37) -----------------------
+
+
+def _window(case):
+    """(h, xc, sdf, chi, valid, centre) of a probe window: a sphere, a
+    slender fish-shaped body, and the sphere with holes in ``valid`` as
+    a forest window has where a block slot is -1."""
+    if case == "fish":
+        n = 48
+        h = 1.0 / n
+        loc = (np.arange(n) + 0.5) * h
+        x, y, z = np.meshgrid(loc, loc, loc, indexing="ij")
+        xc = np.stack([x, y, z], axis=-1).astype(np.float32)
+        c = np.array([0.5, 0.5, 0.5])
+        q = np.sqrt(((x - .5) / .4) ** 2 + ((y - .5) / .06) ** 2
+                    + ((z - .5) / .1) ** 2)
+        sdf = jnp.asarray(((1.0 - q) * .06).astype(np.float32))
+        chi = heaviside(sdf, h)
+    else:
+        h, xc, sdf, chi, c = _sphere_window()
+    valid = np.ones(sdf.shape, bool)
+    if case == "holes":
+        valid[:8, 16:24, :] = False
+        valid[32:40, 40:, 8:16] = False
+    return h, xc, sdf, chi, jnp.asarray(valid), c
+
+
+def _band_of(h, sdf, chi, valid):
+    """The window's surface mask and measure, flat, on the host."""
+    dS, _, surf = sf._surface_band(sdf, chi, valid, h)
+    surf = np.asarray(surf).reshape(-1)
+    return surf, np.where(surf, np.asarray(dS).reshape(-1), 0.0)
+
+
+def _probe_args(case, seed=0):
+    h, xc, sdf, chi, valid, c = _window(case)
+    rng = np.random.default_rng(seed)
+    vel = jnp.asarray(rng.normal(size=sdf.shape + (3,)), jnp.float32)
+    p = jnp.asarray(rng.normal(size=sdf.shape), jnp.float32)
+    udef = 0.1 * jnp.asarray(rng.normal(size=sdf.shape + (3,)), jnp.float32)
+    cm, ut, om = (jnp.asarray(v, jnp.float32)
+                  for v in (c, [.1, .2, .3], [.3, .1, .2]))
+    return (vel, p, chi, sdf, udef, valid, jnp.asarray(xc), h, 1e-2,
+            cm, ut, om)
+
+
+def _probe_window(case, **kw):
+    a = _probe_args(case)
+    return _probe_jit(*a[:7], h=a[7], nu=a[8], cm=a[9], u_trans=a[10],
+                      omega=a[11], **kw)
+
+
+def _cells(out, xc):
+    """Flat window indices of the evaluated points of a per-point run,
+    in the order of their slots."""
+    surf = np.asarray(out["points"]["surf"])
+    x = np.asarray(out["points"]["x"])[surf]
+    n = xc.shape[0]
+    ijk = np.floor(x * n).astype(np.int64)  # the windows span the unit box
+    return (ijk[:, 0] * n + ijk[:, 1]) * n + ijk[:, 2]
+
+
+def _pack_gap(a, b):
+    """Largest gap of any entry of two force packs, relative to the
+    entry's own size."""
+    worst = 0.0
+    for k in a:
+        if k == "points":
+            continue
+        x, y = np.asarray(a[k], np.float64), np.asarray(b[k], np.float64)
+        worst = max(worst, np.max(np.abs(x - y)) / max(np.max(np.abs(x)),
+                                                       1e-30))
+    return worst
+
+
+@pytest.mark.parametrize("case", ["sphere", "fish", "holes"])
+def test_loop_over_the_occupied_slots_sums_the_whole_band(case):
+    """A band that fits its budget by one slot: the slots hold exactly
+    the band's cells (NumPy's, of the mask), largest measure first, and
+    every entry of the force pack, summed chunk by chunk over the
+    occupied slots alone, agrees to 1e-5 of itself with the sums over
+    all slots at once; a budget of 20x the band, as the drivers give it,
+    changes no bit of them."""
+    h, xc, sdf, chi, valid, c = _window(case)
+    band = np.flatnonzero(_band_of(h, sdf, chi, valid)[0])
+    assert band.size > 1000
+    K = band.size + 1
+    looped = _probe_window(case, max_points=K)
+    at_once = _probe_window(case, max_points=K, per_point=True)
+    for out in (looped, at_once):
+        assert int(out["n_surf"]) == band.size
+    assert (np.sort(_cells(at_once, xc)) == band).all()
+    pts = at_once["points"]
+    assert not np.asarray(pts["surf"])[band.size:].any()
+    assert (np.diff(np.asarray(pts["dS"])) <= 0).all()  # not by index
+    assert _pack_gap(looped, at_once) < 1e-5
+    if case == "sphere":
+        roomy = _probe_window(case, max_points=20 * band.size)
+        assert _pack_gap(looped, roomy) == 0.0
+
+
+def test_overflowing_band_keeps_its_largest_cells():
+    """One slot too few: the one cell of the smallest measure is dropped,
+    the true count is reported, and the loop sums the K slots it has."""
+    h, xc, sdf, chi, valid, c = _window("sphere")
+    surf, dS = _band_of(h, sdf, chi, valid)
+    band = np.flatnonzero(surf)
+    K = band.size - 1
+    cut = _probe_window("sphere", max_points=K, per_point=True)
+    assert int(cut["n_surf"]) == band.size
+    kept = _cells(cut, xc)
+    assert kept.size == K
+    dropped = np.setdiff1d(band, kept)
+    assert dropped.size == 1 and dS[dropped[0]] == dS[band].min()
+    assert _pack_gap(_probe_window("sphere", max_points=K), cut) < 1e-5
+
+
+def _primitives(jaxpr, inside=()):
+    """(primitive name, names of the enclosing eqns) of every eqn."""
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name, inside
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _primitives(sub, inside + (eqn.primitive.name,))
+
+
+def _traced(**kw):
+    a = _probe_args("sphere")
+
+    def fn(*arrays):
+        return sf.surface_force_window(*arrays[:7], a[7], a[8],
+                                       *arrays[7:], **kw)
+
+    return jax.make_jaxpr(fn)(*a[:7], *a[9:]).jaxpr
+
+
+def test_the_samples_are_gathered_inside_the_loop():
+    """One top_k fills the slots, outside the loop and with no second
+    arm beside it; the ~60 samples a slot are gathered inside the loop
+    over the occupied chunks, unless the per-point record asks for every
+    slot."""
+    eqns = list(_primitives(_traced(max_points=20000)))
+    assert [at for p, at in eqns if p in ("sort", "top_k")] == [()]
+    assert "cond" not in {p for p, _ in eqns}
+    looped = [at for p, at in eqns if p == "gather"]
+    assert sum("while" in at for at in looped) > 50
+    assert sum("while" not in at for at in looped) == 0
+    record = {p for p, _ in _primitives(_traced(max_points=20000,
+                                                per_point=True))}
+    assert "while" not in record and "gather" in record
 
 
 @pytest.mark.slow
