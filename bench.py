@@ -238,7 +238,7 @@ def _trace_overhead(sim_advance, calc_dt, sync_state, baseline_wall: float,
     other_sink = obs_trace.TraceSink(
         enabled=not main_traced,
         directory=tempfile.mkdtemp(prefix="cup3d-obsgate-"),
-        max_steps=10_000, xla_annotate=False,
+        max_steps=10_000,
     )
     profiler.set_sink(other_sink)
     try:

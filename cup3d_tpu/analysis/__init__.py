@@ -16,6 +16,7 @@ budgets are; tests/test_analysis.py enforces it.
 from cup3d_tpu.analysis.rules import RULES, Rule, Violation  # noqa: F401
 from cup3d_tpu.analysis.runtime import (  # noqa: F401
     RecompileCounter,
+    blocking_read,
     debug_nans,
     device_scalar,
     no_implicit_transfers,

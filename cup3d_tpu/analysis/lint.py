@@ -1206,9 +1206,10 @@ class FileLint:
                 "JX012", first, qualname,
                 "direct jax.profiler use outside cup3d_tpu/obs/: use obs "
                 "profile windows (obs.profile.CONTROLLER / "
-                "CaptureController.capture()) and obs spans "
-                "(CUP3D_TRACE_XLA=1) so captures coordinate and land on "
-                "the merged host+device timeline",
+                "CaptureController.capture()) and obs.trace.annotate() "
+                "(profiler sections and blocking reads are annotations "
+                "already) so captures coordinate and land on the merged "
+                "host+device timeline",
             )
 
     # -- JX011 -------------------------------------------------------------

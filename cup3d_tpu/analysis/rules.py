@@ -230,12 +230,14 @@ RULES: Dict[str, Rule] = {
             "outside cup3d_tpu/obs/ opens a second, uncoordinated "
             "profiling channel: the profiler session is process-global, "
             "so an ad-hoc capture colliding with an obs window aborts "
-            "one of them; ad-hoc annotations bypass the sink's cached "
-            "class and fast no-op path; and the resulting trace never "
-            "reaches the device-time attribution parser or the merged "
-            "host+device timeline.  Use obs profile windows "
-            "(obs.profile.CONTROLLER / CaptureController.capture()) and "
-            "obs spans under CUP3D_TRACE_XLA=1 instead.",
+            "one of them; ad-hoc annotations fall outside the cup3d: "
+            "names the device-time attribution parser puts the device's "
+            "idle gaps down to; and the resulting trace never reaches "
+            "that parser or the merged host+device timeline.  Use obs "
+            "profile windows (obs.profile.CONTROLLER / "
+            "CaptureController.capture()) and obs.trace.annotate(): every "
+            "profiler section, step and blocking read is an annotation "
+            "already, with no switch.",
         ),
         Rule(
             "JX018",

@@ -16,6 +16,12 @@ placement is known exactly:
   hook that names the designed sync points (``umax-read``,
   ``qoi-read``, ``scalar-upload``, ...).  Sanctioned sites are recorded
   in :data:`TRANSFER_SITES` so tests can assert the allowlist is closed.
+- :func:`blocking_read` — the one seam the designed blocking
+  device-to-host reads go through (``qoi-read``, ``moments-read``,
+  ``umax-read``, ``tags-read``, ``stream-read``): a sanctioned site that
+  also keeps
+  time (``transfers.wait_s`` / ``transfers.copy_s``) and writes itself
+  into the profiler's trace.
 - :func:`debug_nans` / :func:`tracer_leak_checks` — opt-in wrappers over
   the jax debug flags, scoped instead of global.
 
@@ -97,6 +103,45 @@ def sanctioned_transfer(tag: str):
     ctx = jax.transfer_guard("allow") if jax is not None else nullcontext()
     with ctx:
         yield
+
+
+def blocking_read(site: str, x, dtype=None):
+    """THE seam of every designed blocking device-to-host read: starts
+    the host copy of ``x`` (an array, or a tuple of arrays read at one
+    visit), waits for ``x``, then takes it as NumPy
+    (``np.asarray(leaf, dtype)``), inside
+    :func:`sanctioned_transfer` and under the profiler annotation
+    ``cup3d:read:<site>``.  Beside the visit count it raises
+    ``transfers.wait_s{site=…}`` by the wait — the device's unfinished
+    work in front of the value, the host's idle time — and
+    ``transfers.copy_s{site=…}`` by the copy.  What ``x`` is computed
+    from is dispatched before the call, so the wait holds no dispatch."""
+    import jax
+    import numpy as np
+
+    from cup3d_tpu.obs import metrics as obs_metrics
+    from cup3d_tpu.obs import trace as obs_trace
+
+    with sanctioned_transfer(site), \
+            obs_trace.annotate(obs_trace.ANNOTATION_PREFIX + "read:" + site):
+        # jax-lint: allow(JX008, the wait/copy split of a blocking read
+        # is this seam's own counter pair: the spans it opens are the
+        # annotation above and the caller's profiler section)
+        t0 = obs_trace.now()
+        # the copy queued behind the compute, as a bare ``np.asarray``
+        # queues it: waiting first and asking for the copy after costs
+        # one more round trip between host and device a read
+        for leaf in jax.tree_util.tree_leaves(x):
+            start = getattr(leaf, "copy_to_host_async", None)
+            if start is not None:
+                start()
+        jax.block_until_ready(x)
+        t1 = obs_trace.now()
+        out = jax.tree_util.tree_map(lambda a: np.asarray(a, dtype), x)
+        t2 = obs_trace.now()
+    obs_metrics.counter("transfers.wait_s", site=site).inc(t1 - t0)
+    obs_metrics.counter("transfers.copy_s", site=site).inc(t2 - t1)
+    return out
 
 
 class RecompileCounter:

@@ -63,6 +63,7 @@ def tag_states(
     return states
 
 
+@jax.named_scope("AdaptMesh")
 def device_tags(
     vort: jnp.ndarray,
     near: jnp.ndarray,

@@ -519,6 +519,7 @@ def _upsample(scratch: jnp.ndarray, W: jnp.ndarray) -> jnp.ndarray:
     return out
 
 
+@jax.named_scope("Halo")
 def assemble_scalar_lab(
     field: jnp.ndarray, tables: LabTables, bs: int
 ) -> jnp.ndarray:
@@ -543,6 +544,7 @@ def assemble_scalar_lab(
     return lab.at[:, gx, gy, gz].set(ghosts.astype(field.dtype))
 
 
+@jax.named_scope("Halo")
 def assemble_vector_lab(
     field: jnp.ndarray, tables: LabTables, bs: int
 ) -> jnp.ndarray:

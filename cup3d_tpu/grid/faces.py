@@ -588,6 +588,7 @@ def _coarse_halo(t: FaceTables, ext: jnp.ndarray, f: int) -> jnp.ndarray:
     return out  # (ncf, C, w, bs, bs)
 
 
+@jax.named_scope("Halo")
 def _assemble_multi(
     t: FaceTables, fields: jnp.ndarray, sign_comps: Optional[Tuple[int, ...]]
 ) -> jnp.ndarray:

@@ -179,6 +179,7 @@ def pad_flux_tables(t: FluxTables, bs: int, cap: int) -> FluxTables:
     )
 
 
+@jax.named_scope("FluxCorrection")
 def apply_flux_correction(
     out: jnp.ndarray, fluxes: jnp.ndarray, tab: FluxTables
 ) -> jnp.ndarray:

@@ -27,6 +27,7 @@ import enum
 from dataclasses import dataclass
 from typing import Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -79,10 +80,12 @@ class UniformGrid:
 
     # -- ghost-cell padding ------------------------------------------------
 
+    @jax.named_scope("Halo")
     def pad_scalar(self, f: jnp.ndarray, width: int) -> jnp.ndarray:
         """Pad a (nx,ny,nz) scalar with `width` ghost cells on every face."""
         return _pad(f, width, self.bc)
 
+    @jax.named_scope("Halo")
     def pad_vector(self, u: jnp.ndarray, width: int) -> jnp.ndarray:
         """Pad a (nx,ny,nz,3) velocity with BC-correct ghosts per component."""
         comps = []

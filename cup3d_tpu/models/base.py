@@ -117,6 +117,7 @@ def quat_integrate_dev(q, omega, dt):
     return jnp.where(th < 1e-14, q, qn)
 
 
+@jax.named_scope("UpdateObstacles")
 def rigid_update_device(mom, state, forced_mask, block_mask, uinf, dt):
     """Moments (19,) + rigid state (RIGID_STATE,) -> updated (RIGID_PACK,).
 
@@ -171,6 +172,7 @@ def pos_rot_traced(frame):
 _pos_rot = jax.jit(pos_rot_traced)
 
 
+@jax.named_scope("CreateObstacles")
 def combine_obstacle_fields(chis, udefs):
     """(n_obs, ...) stacks of per-body chi and masked udef -> the combined
     fields the operators consume: chi the maximum over the bodies, udef
@@ -183,6 +185,7 @@ def combine_obstacle_fields(chis, udefs):
     return chi, udef
 
 
+@jax.named_scope("CreateObstacles")
 def fields_from_sdf(grid: UniformGrid, sdf, udef, combine: bool):
     """Dense SDF -> (chi, udef, combined), the tail every body shares:
     Towers chi (reference Obstacle::create + chi kernel); the deformation
@@ -593,6 +596,7 @@ def derived_force_qoi(f: Dict[str, np.ndarray], trans_vel: np.ndarray,
             "EffPDefBnd": eff_bnd}
 
 
+@jax.named_scope("UpdateObstacles")
 def momentum_integrals_core(x: jnp.ndarray, vol, chi: jnp.ndarray,
                             vel: jnp.ndarray, cm_guess: jnp.ndarray):
     """Layout-generic chi-weighted moments (KernelIntegrateFluidMomenta,

@@ -358,6 +358,7 @@ def _remove_angular_momentum_dev(si, dt, qint, r, v, nor, vnor, bin_, vbin):
     return r, v, nor, vnor, bin_, vbin, q
 
 
+@jax.named_scope("CreateObstacles")
 def midline_state_device(gait, t, dt, qint):
     """Evaluate the full midline state at traced time ``t``: gait wave ->
     Frenet integration -> pitching wrap -> normal re-orthonormalization ->

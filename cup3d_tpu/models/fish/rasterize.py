@@ -100,6 +100,7 @@ def _segment_distance(p, seg):
 
 
 @jax.jit
+@jax.named_scope("CreateObstacles")
 def rasterize_points(points, midline, position, rot):
     """Rasterize a midline tube at arbitrary cell centers.
 
@@ -150,6 +151,7 @@ def rasterize_points(points, midline, position, rot):
 
 
 @partial(jax.jit, static_argnames=("window_shape",))
+@jax.named_scope("CreateObstacles")
 def rasterize_midline(
     origin,
     h,

@@ -46,6 +46,7 @@ def _split_midline(pack):
     }
 
 
+@jax.named_scope("CreateObstacles")
 def raster_blocks(xc, real, slots, pack, frame):
     """Block-layout rasterization, traced: gather the candidate blocks'
     centers from ``xc`` (rows, bs, bs, bs, 3) -> midline distance over
@@ -75,6 +76,7 @@ def raster_blocks(xc, real, slots, pack, frame):
 _raster_blocks = jax.jit(raster_blocks)
 
 
+@jax.named_scope("CreateObstacles")
 def _raster_window(pack, frame, grid, window_shape):
     """Window snap + midline rasterization + dense placement, traced.
     ``frame`` None: the pack's last row carries the host mirrors' frame

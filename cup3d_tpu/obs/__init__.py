@@ -9,8 +9,9 @@ recorder — the repo's cross-cutting nervous system.
 - :mod:`cup3d_tpu.obs.trace` — nested span timing (the engine behind
   ``io/logging.py``'s Profiler shim), per-step structured JSONL records
   (``CUP3D_TRACE=1`` -> ``trace.jsonl``), Chrome trace-event export
-  (``trace.pfto.json``, Perfetto-loadable), optional
-  ``jax.profiler.TraceAnnotation`` passthrough (``CUP3D_TRACE_XLA=1``).
+  (``trace.pfto.json``, Perfetto-loadable); every section, step and
+  blocking read is also a ``jax.profiler`` annotation ``cup3d:…``,
+  always (``annotate``).
 - :mod:`cup3d_tpu.obs.flight` — fixed-size ring of recent step records
   + solver residual history; dumps a self-contained postmortem JSON on
   NaN/Inf velocity, dt collapse, or a Poisson solve at its iteration
@@ -20,9 +21,9 @@ Observability v2 (ISSUE 9) — the device half:
 
 - :mod:`cup3d_tpu.obs.profile` — programmatic ``jax.profiler`` capture
   windows (``CUP3D_PROFILE=every:N``) + the trace-event parser that
-  attributes device-stream op time to logical sections (fused BiCGSTAB
-  stages, ring halos, megaloop body) and merges it into the step-trace
-  JSONL and Perfetto export.
+  puts device time down to the operator scopes in each op's ``op_name``
+  and the device's idle gaps to the host's ``cup3d:`` annotations, and
+  merges both into the step-trace JSONL and Perfetto export.
 - :mod:`cup3d_tpu.obs.export` — zero-dependency background HTTP
   exporter: ``/metrics`` (Prometheus text from the registry snapshot)
   and ``/health`` (flight-recorder arm state, last-known-good step,

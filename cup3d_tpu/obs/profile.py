@@ -16,20 +16,25 @@ block:
 
 2. The trace-event parser — loads the captured ``*.trace.json.gz``
    (gzipped Chrome trace-event JSON, the same format the sink's
-   Perfetto export uses) and attributes every device-stream op to a
-   logical section: first by the fused-kernel name table below
-   (``_k_update``/``_k_getz``/``_k_lap``/``_k_finish`` -> the three
-   BiCGSTAB stages, ``ring_shift``/remote-copy -> halo exchange,
-   scan/while bodies -> the megaloop), then by the ``TraceAnnotation``
-   names ``obs/trace.py`` injects under ``CUP3D_TRACE_XLA=1`` (name
-   match, then temporal containment), else the ``other`` bucket — so
-   attributed section time always sums to total device time.
+   Perfetto export uses) and reads BOTH timelines by the one vocabulary
+   the program writes into them.  Device: every executed operation
+   carries the ``jax.named_scope`` path of the code that traced it (in
+   the event's own arguments on a TPU; joined from the program's
+   optimised HLO text, :func:`hlo_op_names`, where the backend leaves
+   it out), and its SELF time goes to that path — by outermost operator
+   scope (:data:`OPERATOR_SCOPES`), one level below for
+   :data:`SPLIT_SCOPES`, ``other`` for what carries none — so the
+   sections and ``other`` sum to the device's busy time.  Host: every
+   instant the device idles goes to the innermost ``cup3d:`` annotation
+   open at that instant (``obs/trace.py``: the profiler's sections,
+   steps and blocking reads, always on).
 
 3. The merge — each closed window lands (a) per-section gauges in the
-   metrics registry (``profile.device_ms{section=...}``), (b) a
-   ``kind="device"`` auxiliary record in the step-trace JSONL, and (c)
-   the device ops as pid-:data:`DEVICE_PID` events in the sink's
-   Perfetto export, so host spans and device ops read off ONE timeline.
+   metrics registry (``profile.device_ms{section=...}``,
+   ``profile.idle_ms{span=...}``), (b) a ``kind="device"`` auxiliary
+   record in the step-trace JSONL, and (c) the device ops as
+   pid-:data:`DEVICE_PID` events in the sink's Perfetto export, so host
+   spans and device ops read off ONE timeline.
 
 Everything here runs at window close on the host — never inside the
 step loop — and every failure is counted, never raised (a profiler
@@ -47,6 +52,7 @@ from __future__ import annotations
 
 import glob
 import gzip
+from bisect import bisect_right
 import json
 import os
 import re
@@ -66,27 +72,37 @@ _DEVICE_NAME_RE = re.compile(
     r"device|tpu|gpu|accelerator|/stream", re.IGNORECASE
 )
 
-#: thread names marking a DEVICE/executor stream inside a host-named
-#: process: the CPU backend runs XLA ops on tf_XLA* threads of the one
-#: ``/host:CPU`` track, so a CPU capture still attributes real op time
-_DEVICE_THREAD_RE = re.compile(r"tf_xla|xla:|/stream", re.IGNORECASE)
+#: the lines of a device process that hold its executed operations and
+#: its executed programs (``benchmarks/lib/trace_reduce.py`` reads the same)
+_OPS_LINE = "XLA Ops"
+_MODULES_LINE = "XLA Modules"
 
-#: kernel-name fragments -> logical section, checked in order (first
-#: hit wins).  The fused BiCGSTAB stages (ops/fused_bicgstab.py), the
-#: ring-halo DMA kernels (parallel/ring.py) and the megaloop scan body
-#: (sim/megaloop.py) are the sections the round-13 acceptance criterion
-#: requires nonzero device time for.
-KERNEL_SECTIONS: Tuple[Tuple[str, Tuple[str, ...]], ...] = (
-    ("bicgstab.update", ("_k_update", "fused_update", "bicgstab_update")),
-    ("bicgstab.getz_lap", ("_k_getz", "_k_lap", "fused_getz", "fused_lap",
-                           "getz")),
-    ("bicgstab.finish", ("_k_finish", "_k_axpy", "fused_finish",
-                         "fused_axpy", "bicgstab_finish")),
-    ("halo.ring", ("ring_shift", "remote_copy", "all_to_all", "ppermute",
-                   "collective-permute", "collective_permute", "halo")),
-    ("megaloop.body", ("megaloop", "scan_body", "while", "fori_loop",
-                       "scan")),
+#: the operator vocabulary: the drivers' profiler sections, which are
+#: also the outermost ``jax.named_scope`` of the device code they issue
+OPERATOR_SCOPES: Tuple[str, ...] = (
+    "CreateObstacles", "AdvectionDiffusion", "UpdateObstacles",
+    "Penalization", "PressureProjection", "ComputeForces", "AdaptMesh",
+    "DtPolicy",
 )
+
+#: scopes that only ever sit below an operator (or alone, in a program
+#: that is no step: a solve probe)
+CHILD_SCOPES: Tuple[str, ...] = (
+    "PoissonRHS", "PoissonSolve", "Gradient", "Laplacian",
+    "Preconditioner", "TileSolve", "CoarseSolve", "Dots", "Halo",
+    "FluxCorrection",
+)
+
+#: operators whose device time the summary also gives one level below
+SPLIT_SCOPES: Tuple[str, ...] = ("PressureProjection", "AdvectionDiffusion")
+
+_SCOPES = frozenset(OPERATOR_SCOPES + CHILD_SCOPES)
+
+#: where a device event says which code traced it, in the order tried
+_OP_NAME_ARGS: Tuple[str, ...] = ("tf_op", "op_name")
+
+#: the gap of a device that no ``cup3d:`` annotation covers
+NO_SPAN = "outside cup3d spans"
 
 
 # -- capture plan ------------------------------------------------------------
@@ -120,9 +136,16 @@ def parse_plan(spec: Optional[str]) -> Optional[dict]:
 
 
 def _default_start(logdir: str) -> None:
+    """Device and host tracers on, Python's own off: it writes an event
+    per Python call, which slows the very host whose spans the window is
+    there to read and buries them (the benchmark's traced window starts
+    its session the same way)."""
     import jax.profiler
 
-    jax.profiler.start_trace(logdir)
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 2
+    jax.profiler.start_trace(logdir, profiler_options=options)
 
 
 def _default_stop() -> None:
@@ -296,6 +319,8 @@ class CaptureController:
             _metrics.gauge("profile.device_ms", section=name).set(ms)
         _metrics.gauge("profile.device_ms", section="other").set(attr.other_ms)
         _metrics.gauge("profile.device_total_ms").set(attr.total_ms)
+        for name, ms in attr.gaps.items():
+            _metrics.gauge("profile.idle_ms", span=name).set(ms)
         sink = self.sink
         if sink.enabled:
             merge_into_sink(sink, attr, window=window)
@@ -342,33 +367,92 @@ def load_chrome_trace(path: str) -> dict:
 
 @dataclass
 class DeviceAttribution:
-    """Per-section device time for one capture window.  Invariant:
-    ``sum(sections.values()) + other_ms == total_ms`` (the parser
-    buckets every device op exactly once)."""
+    """One capture window read by scope.  ``paths`` holds the device's
+    self time by full scope path (``"PressureProjection/PoissonSolve/
+    Laplacian"``), ``sections`` the same time by outermost scope.
+    Invariant: ``sum(sections.values()) + other_ms == total_ms`` — every
+    device op's self time lands exactly once, and ``total_ms`` is the
+    device's busy time.  ``gaps`` holds the device's idle time inside
+    the window by host annotation, ``host`` the annotations' own self
+    time (where the host thread was, device busy or not), ``programs``
+    each executed program's (total, other) ms."""
 
     total_ms: float = 0.0
     sections: Dict[str, float] = field(default_factory=dict)
+    paths: Dict[str, float] = field(default_factory=dict)
     other_ms: float = 0.0
+    window_ms: float = 0.0
+    gaps: Dict[str, float] = field(default_factory=dict)
+    host: Dict[str, float] = field(default_factory=dict)
+    programs: Dict[str, List[float]] = field(default_factory=dict)
+    other_ops: Dict[str, float] = field(default_factory=dict)
     events: List[dict] = field(default_factory=list)
     source: str = ""
 
+    def children(self, scope: str) -> Dict[str, float]:
+        """``scope``'s time one level below; its own under ``self``."""
+        out: Dict[str, float] = {}
+        for path, ms in self.paths.items():
+            parts = path.split("/")
+            if parts[0] == scope:
+                key = parts[1] if len(parts) > 1 else "self"
+                out[key] = out.get(key, 0.0) + ms
+        return out
+
     def summary(self) -> dict:
+        r6 = lambda d: {k: round(v, 6) for k, v in sorted(d.items())}
         return {
             "total_device_ms": round(self.total_ms, 6),
-            "device_sections": {k: round(v, 6)
-                                for k, v in sorted(self.sections.items())},
+            "device_sections": r6(self.sections),
             "other_ms": round(self.other_ms, 6),
+            "device_children": {sc: r6(self.children(sc))
+                                for sc in SPLIT_SCOPES
+                                if sc in self.sections},
+            "device_paths": r6(self.paths),
+            "window_ms": round(self.window_ms, 6),
+            "idle_gaps_ms": r6(self.gaps),
+            "host_spans_ms": r6(self.host),
+            "programs_total_other_ms": {
+                k: [round(v[0], 6), round(v[1], 6)]
+                for k, v in sorted(self.programs.items())},
+            "other_top_ops_ms": r6(dict(sorted(
+                self.other_ops.items(), key=lambda kv: -kv[1])[:10])),
             "source": self.source,
         }
 
 
-def _kernel_section(name: str) -> Optional[str]:
-    low = name.lower()
-    for section, frags in KERNEL_SECTIONS:
-        for frag in frags:
-            if frag in low:
-                return section
-    return None
+def scope_path(op_name: str) -> Tuple[str, ...]:
+    """The named scopes of the vocabulary in an HLO ``op_name``
+    (``jit(megaloop)/while/body/closed_call/PressureProjection/
+    PoissonSolve/while/body/Dots/reduce_sum``), outermost first; a scope
+    entered again right inside itself counts once, and where the
+    compiler writes a nested computation's whole path again behind its
+    caller's the path starts over at the outermost scope."""
+    out: List[str] = []
+    for part in op_name.rstrip(":").split("/"):
+        if part not in _SCOPES:
+            continue
+        if out and part == out[0]:
+            out = [part]
+        elif not out or out[-1] != part:
+            out.append(part)
+    return tuple(out)
+
+
+_HLO_MODULE_RE = re.compile(r"^HloModule ([\w.\-]+)", re.MULTILINE)
+_HLO_INSTR_RE = re.compile(
+    r"^\s*(?:ROOT )?%?([\w.\-]+) = .*?op_name=\"([^\"]*)\"", re.MULTILINE)
+
+
+def hlo_op_names(hlo_text: str) -> Dict[Tuple[str, str], str]:
+    """``(module, instruction) -> op_name`` from one program's optimised
+    HLO text (``jitted.lower(...).compile().as_text()``): what
+    :func:`attribute` joins a device event to where the backend's trace
+    names the instruction and leaves its ``op_name`` out (the CPU's)."""
+    m = _HLO_MODULE_RE.search(hlo_text)
+    module = m.group(1) if m else ""
+    return {(module, instr): name
+            for instr, name in _HLO_INSTR_RE.findall(hlo_text)}
 
 
 def _track_names(events: List[dict]) -> Dict[int, str]:
@@ -396,78 +480,150 @@ def _thread_names(events: List[dict]) -> Dict[Tuple[int, int], str]:
     return names
 
 
-def attribute(trace: dict, sections=None, source: str = ""
-              ) -> DeviceAttribution:
-    """Attribute every device-stream op in a Chrome trace to a logical
-    section.
+def _self_times(rows: List[dict]) -> None:
+    """Set ``self`` on each event of ONE line: its duration less the
+    events nested in it (a ``while`` holds its body's operations)."""
+    stack: List[dict] = []
+    for e in sorted(rows, key=lambda e: (e["ts"], -e["dur"])):
+        while stack and stack[-1]["ts"] + stack[-1]["dur"] <= e["ts"]:
+            stack.pop()
+        e["self"] = e["dur"]
+        if stack:
+            stack[-1]["self"] -= e["dur"]
+        stack.append(e)
 
-    Device tracks are processes whose metadata name matches
-    :data:`_DEVICE_NAME_RE` (plus pid :data:`DEVICE_PID`, our own merged
-    convention) — and, within host-named processes, threads matching
-    :data:`_DEVICE_THREAD_RE` (the CPU backend's tf_XLA* executor
-    threads).  Per op, in order: the fused-kernel table, a name match
-    against the annotation section names (``sections`` arg, default =
-    every host span name — the ``TraceAnnotation`` names obs/trace.py
-    injects; ``$``-prefixed python profiler frames are never section
-    candidates), temporal containment in the innermost host span, else
-    ``other``."""
+
+def _union(intervals: List[Tuple[float, float]]) -> List[List[float]]:
+    out: List[List[float]] = []
+    for a, b in sorted(intervals):
+        if out and a <= out[-1][1]:
+            out[-1][1] = max(out[-1][1], b)
+        elif b > a:
+            out.append([a, b])
+    return out
+
+
+def attribute(trace: dict, op_names: Optional[Dict[Tuple[str, str], str]]
+              = None, source: str = "") -> DeviceAttribution:
+    """Read one Chrome trace by scope (module docstring, point 2).
+
+    Device operations are the events of a device process's ``XLA Ops``
+    line (a TPU's) and, on any process, the events that name their HLO
+    instruction (``args.hlo_op``: the CPU backend runs them on its
+    executor threads).  The scope path of one comes from its own
+    arguments (:data:`_OP_NAME_ARGS`) or, failing that, from
+    ``op_names`` (:func:`hlo_op_names`) by module and instruction.
+    Host spans are the ``cup3d:`` annotations on every other line; the
+    window runs from the first to the last event of either kind."""
     events = trace["traceEvents"] if isinstance(trace, dict) else trace
     pnames = _track_names(events)
     tnames = _thread_names(events)
     device_pids = {pid for pid, name in pnames.items()
                    if _DEVICE_NAME_RE.search(name)}
     device_pids.add(DEVICE_PID)
+    prefix = obs_trace.ANNOTATION_PREFIX
 
-    def _is_device(e: dict) -> bool:
-        if e.get("pid") in device_pids:
-            return True
-        return bool(_DEVICE_THREAD_RE.search(
-            tnames.get((e.get("pid"), e.get("tid")), "")))
-
-    host_spans = []
+    lines: Dict[Tuple[int, int], List[dict]] = {}
+    modules: List[dict] = []
+    spans: List[dict] = []
     for e in events:
-        if (e.get("ph") == "X" and not _is_device(e)
-                and isinstance(e.get("dur"), (int, float))
-                and isinstance(e.get("name"), str)
-                and e["name"] != "step"
-                and not e["name"].startswith("$")):
-            host_spans.append(e)
-    names = (set(sections) if sections is not None
-             else {e["name"] for e in host_spans})
-    # innermost-first for the temporal fallback
-    host_spans.sort(key=lambda e: e["dur"])
-    attr = DeviceAttribution(source=source)
-    for e in events:
-        if e.get("ph") != "X" or not _is_device(e):
-            continue
         dur = e.get("dur")
-        if not isinstance(dur, (int, float)) or dur < 0:
+        if (e.get("ph") != "X" or not isinstance(dur, (int, float))
+                or dur < 0 or not isinstance(e.get("name"), str)):
             continue
-        name = str(e.get("name", ""))
-        section = _kernel_section(name)
-        if section is None:
-            low = name.lower()
-            hits = [s for s in names if s.lower() in low]
-            if hits:
-                section = max(hits, key=len)
-        if section is None:
-            mid = e.get("ts", 0.0) + dur / 2.0
-            for span in host_spans:
-                if (span["name"] in names
-                        and span["ts"] <= mid <= span["ts"] + span["dur"]):
-                    section = span["name"]
-                    break
-        ms = dur / 1000.0
-        attr.total_ms += ms
-        if section is None:
-            attr.other_ms += ms
+        key = (e.get("pid"), e.get("tid"))
+        args = e.get("args") if isinstance(e.get("args"), dict) else {}
+        tname = tnames.get(key, "")
+        row = {"name": e["name"], "ts": float(e.get("ts", 0.0)),
+               "dur": float(dur), "tid": int(e.get("tid", 0) or 0),
+               "args": args, "line": key}
+        if key[0] in device_pids:
+            if tname == _MODULES_LINE:
+                modules.append(row)
+            elif tname in ("", _OPS_LINE):
+                lines.setdefault(key, []).append(row)
+        elif "hlo_op" in args:
+            lines.setdefault(key, []).append(row)
         else:
-            attr.sections[section] = attr.sections.get(section, 0.0) + ms
-        attr.events.append({
-            "name": name, "section": section,
-            "ts": float(e.get("ts", 0.0)), "dur": float(dur),
-            "tid": int(e.get("tid", 0)),
-        })
+            # the profiler's Chrome export cuts an annotation's name at
+            # its first colon and keeps the whole under ``long_name``
+            full = args.get("long_name", e["name"])
+            if isinstance(full, str) and full.startswith(prefix):
+                row["name"] = full
+                spans.append(row)
+
+    attr = DeviceAttribution(source=source)
+    busy: List[Tuple[float, float]] = []
+    modules.sort(key=lambda m: m["ts"])
+    module_starts = [m["ts"] for m in modules]
+    for rows in lines.values():
+        _self_times(rows)
+        for e in rows:
+            args = e.pop("args")
+            op_name = next((args[k] for k in _OP_NAME_ARGS
+                            if isinstance(args.get(k), str)
+                            and "/" in args[k]), "")
+            module = str(args.get("hlo_module", ""))
+            if not module and modules:
+                # the program whose execution holds the op's midpoint
+                mid = e["ts"] + e["dur"] / 2.0
+                m = modules[max(bisect_right(module_starts, mid) - 1, 0)]
+                if m["ts"] <= mid <= m["ts"] + m["dur"]:
+                    module = m["name"]
+            if not op_name and op_names:
+                op_name = op_names.get(
+                    (module, str(args.get("hlo_op", e["name"]))), "")
+            path = "/".join(scope_path(op_name))
+            ms = e["self"] / 1000.0
+            attr.total_ms += ms
+            prog = attr.programs.setdefault(module, [0.0, 0.0])
+            prog[0] += ms
+            if path:
+                top = path.split("/", 1)[0]
+                attr.paths[path] = attr.paths.get(path, 0.0) + ms
+                attr.sections[top] = attr.sections.get(top, 0.0) + ms
+            else:
+                attr.other_ms += ms
+                prog[1] += ms
+                attr.other_ops[e["name"]] = (
+                    attr.other_ops.get(e["name"], 0.0) + ms)
+            e["section"], e["module"] = path or None, module
+            attr.events.append(e)
+            busy.append((e["ts"], e["ts"] + e["dur"]))
+
+    # the device's idle time, every instant of it to the innermost
+    # annotation open at that instant: a gap is cut where spans begin
+    # and end
+    merged = _union(busy)
+    edges = [(r["ts"], r["ts"] + r["dur"]) for r in spans] + busy
+    if not edges:
+        return attr
+    lo, hi = min(a for a, _ in edges), max(b for _, b in edges)
+    attr.window_ms = (hi - lo) / 1000.0
+    by_line: Dict[Tuple[int, int], List[dict]] = {}
+    for r in spans:
+        by_line.setdefault(r["line"], []).append(r)
+    for rows in by_line.values():
+        _self_times(rows)
+        for r in rows:
+            attr.host[r["name"]] = (attr.host.get(r["name"], 0.0)
+                                    + r["self"] / 1000.0)
+    spans.sort(key=lambda r: r["dur"])  # innermost first
+    cur = lo
+    for a, b in merged + [[hi, hi]]:
+        if a > cur:
+            over = [r for r in spans
+                    if r["ts"] < a and r["ts"] + r["dur"] > cur]
+            cuts = sorted({cur, a} | {t for r in over
+                                      for t in (r["ts"], r["ts"] + r["dur"])
+                                      if cur < t < a})
+            for c0, c1 in zip(cuts, cuts[1:]):
+                mid = 0.5 * (c0 + c1)
+                name = next((r["name"] for r in over
+                             if r["ts"] <= mid <= r["ts"] + r["dur"]),
+                            NO_SPAN)
+                attr.gaps[name] = attr.gaps.get(name, 0.0) + (c1 - c0) / 1000.0
+        cur = max(cur, b)
     return attr
 
 
@@ -506,36 +662,62 @@ def merge_into_sink(sink: obs_trace.TraceSink, attr: DeviceAttribution,
 
 
 def synthetic_trace() -> dict:
-    """A deterministic Chrome trace with host annotation spans + device
-    ops covering every attribution path: the three fused BiCGSTAB
-    stages, ring halo, megaloop body, an annotation-named op, a
-    temporally-contained op, and an unknown op (-> other)."""
+    """A deterministic Chrome trace shaped like a TPU capture of two
+    steps: the program's ``cup3d:`` annotations on the host, and on the
+    device's ``XLA Ops`` line operations that carry their scope path —
+    a solve's ``while`` with its body nested in it, an operation with no
+    scope (-> other) — with idle gaps under a blocking read, under a
+    section, and between the steps."""
     ev = [
         {"name": "process_name", "ph": "M", "pid": 1, "ts": 0,
-         "args": {"name": "python (host)"}},
+         "args": {"name": "/host:CPU"}},
+        {"name": "thread_name", "ph": "M", "pid": 1, "tid": 1, "ts": 0,
+         "args": {"name": "python"}},
         {"name": "process_name", "ph": "M", "pid": 7, "ts": 0,
-         "args": {"name": "/device:TPU:0 (stream: 1)"}},
-        # host annotation spans (what CUP3D_TRACE_XLA=1 injects)
-        {"name": "PoissonSolve", "ph": "X", "pid": 1, "tid": 1,
-         "ts": 0.0, "dur": 5000.0},
-        {"name": "AdvectionDiffusion", "ph": "X", "pid": 1, "tid": 1,
-         "ts": 5000.0, "dur": 2000.0},
+         "args": {"name": "/device:TPU:0"}},
+        {"name": "thread_name", "ph": "M", "pid": 7, "tid": 2, "ts": 0,
+         "args": {"name": "XLA Ops"}},
+        {"name": "thread_name", "ph": "M", "pid": 7, "tid": 3, "ts": 0,
+         "args": {"name": "XLA Modules"}},
     ]
-    device = [
-        ("fused_bicgstab._k_update.fusion", 100.0, 800.0),
-        ("_k_getz_two.kernel.1", 950.0, 700.0),
-        ("_k_lap", 1700.0, 300.0),
-        ("_k_finish.kernel", 2100.0, 500.0),
-        ("fused_axpy", 2650.0, 150.0),
-        ("ring_shift_dma.copy-start", 2900.0, 400.0),
-        ("megaloop_scan.while.body", 3400.0, 1200.0),
-        ("PoissonSolve.custom-call.42", 4700.0, 250.0),   # name match
-        ("fusion.clone.7", 5200.0, 300.0),                # temporal
-        ("unknown_op_xyz", 7200.0, 300.0),                # -> other
-    ]
-    for name, ts, dur in device:
-        ev.append({"name": name, "ph": "X", "pid": 7, "tid": 2,
-                   "ts": ts, "dur": dur})
+    def ann(full, ts, dur, **args):
+        # as the profiler's Chrome export writes an annotation: the name
+        # cut at its first colon, the whole under ``long_name``
+        return {"name": full.split(":", 1)[1], "ph": "X", "pid": 1,
+                "tid": 1, "ts": ts, "dur": dur,
+                "args": {"long_name": full, **args}}
+
+    for k, t0 in enumerate((0.0, 10000.0)):
+        ev += [
+            ann("cup3d:step", t0, 9000.0, step_num=str(k)),
+            ann("cup3d:CreateObstacles", t0 + 100.0, 1900.0),
+            ann("cup3d:AdvectionDiffusion", t0 + 2000.0, 200.0),
+            ann("cup3d:SyncQoI", t0 + 2500.0, 6400.0),
+            ann("cup3d:read:qoi-read", t0 + 2600.0, 6200.0),
+            {"name": "jit_step(1)", "ph": "X", "pid": 7, "tid": 3,
+             "ts": t0 + 2100.0, "dur": 6500.0},
+        ]
+        j = "jit(step)/"
+        for name, ts, dur, op in (
+            ("fusion.1", 2100.0, 900.0, j + "AdvectionDiffusion/add"),
+            ("fusion.2", 3000.0, 400.0, j + "AdvectionDiffusion/Halo/pad"),
+            ("fusion.3", 3500.0, 300.0,
+             j + "PressureProjection/PoissonRHS/div"),
+            ("while.4", 3900.0, 3000.0,
+             j + "PressureProjection/PoissonSolve/while"),
+            ("fusion.5", 4000.0, 1000.0, j + "PressureProjection/"
+             "PoissonSolve/while/body/Laplacian/add"),
+            ("fusion.6", 5000.0, 1200.0, j + "PressureProjection/"
+             "PoissonSolve/while/body/Preconditioner/TileSolve/dot_general"),
+            ("reduce.7", 6300.0, 500.0, j + "PressureProjection/"
+             "PoissonSolve/while/body/Dots/reduce_sum"),
+            ("fusion.8", 7000.0, 600.0,
+             j + "PressureProjection/Gradient/sub"),
+            ("copy.9", 8000.0, 600.0, ""),
+        ):
+            ev.append({"name": name, "ph": "X", "pid": 7, "tid": 2,
+                       "ts": t0 + ts, "dur": dur,
+                       "args": {"tf_op": op} if op else {}})
     return {"traceEvents": ev, "displayTimeUnit": "ms"}
 
 
@@ -565,14 +747,17 @@ def selftest() -> None:
         found = find_trace_files(td)
         assert found == [cap], found
         attr = attribute(load_chrome_trace(cap), source=cap)
-        want = {"bicgstab.update", "bicgstab.getz_lap", "bicgstab.finish",
-                "halo.ring", "megaloop.body", "PoissonSolve",
-                "AdvectionDiffusion"}
-        assert set(attr.sections) == want, attr.sections
-        assert all(v > 0 for v in attr.sections.values()), attr.sections
-        assert attr.other_ms > 0, "unknown op must bucket to other"
+        assert set(attr.sections) == {"AdvectionDiffusion",
+                                      "PressureProjection"}, attr.sections
+        assert attr.other_ms > 0, "an op with no scope must go to other"
         total = sum(attr.sections.values()) + attr.other_ms
         assert abs(total - attr.total_ms) < 1e-9, (total, attr.total_ms)
+        # busy + idle = the window; the wait under the read is the read's
+        idle = sum(attr.gaps.values())
+        assert abs(attr.total_ms + idle - attr.window_ms) < 1e-9
+        assert attr.gaps["cup3d:CreateObstacles"] > 0, attr.gaps
+        assert attr.gaps["cup3d:read:qoi-read"] > 0, attr.gaps
+        assert attr.gaps[NO_SPAN] > 0, attr.gaps
         # capture-window cadence on injected start/stop
         calls: List[str] = []
         sink = obs_trace.TraceSink(enabled=True, directory=td)
@@ -606,7 +791,14 @@ if __name__ == "__main__":
     if "--selftest" in sys.argv:
         selftest()
     elif len(sys.argv) > 1:
-        a = attribute(load_chrome_trace(sys.argv[1]), source=sys.argv[1])
+        # a capture, then the optimised HLO texts of the programs it ran
+        # (only where the backend's trace leaves the op_name out)
+        joined: Dict[Tuple[str, str], str] = {}
+        for path in sys.argv[2:]:
+            with open(path) as f:
+                joined.update(hlo_op_names(f.read()))
+        a = attribute(load_chrome_trace(sys.argv[1]), op_names=joined,
+                      source=sys.argv[1])
         print(json.dumps(a.summary(), indent=1))
     else:
         print(__doc__)

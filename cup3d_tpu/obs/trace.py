@@ -21,9 +21,14 @@ Three layers, cheapest first:
    the step loop never blocks on disk — the stream data-plane's
    writer-thread pattern), and exports everything as Chrome trace-event
    format (``trace.pfto.json``) loadable in Perfetto (chrome://tracing
-   works too).  ``CUP3D_TRACE_XLA=1`` additionally wraps every span in
-   ``jax.profiler.TraceAnnotation`` so host spans line up with XLA
-   device timelines in xprof captures.
+   works too).
+
+   Apart from the sink, and always: every section is also a
+   ``jax.profiler.TraceAnnotation`` named ``cup3d:<section>`` and every
+   step a ``StepTraceAnnotation`` ``cup3d:step`` (:func:`annotate`), so
+   whoever holds a profiler session — ``CUP3D_PROFILE``, the benchmark's
+   traced window, a notebook — finds the program's spans on the device's
+   clock.  With no session open an annotation is a flag test.
 
 3. :class:`StepObserver` — the driver-facing glue: wraps one ``advance``
    into a step span, computes the per-step section self-time deltas,
@@ -216,6 +221,31 @@ SHARD_PID = 4
 #: one X span per CompileService build, flow-linked (ph "s"/"f") to the
 #: pid-3 lane spans of the jobs that waited on it.
 COMPILE_PID = 5
+
+
+#: prefix of every annotation the program writes into a profiler trace
+ANNOTATION_PREFIX = "cup3d:"
+
+#: (TraceAnnotation, StepTraceAnnotation), resolved at first use so that
+#: importing ``obs`` imports no jax
+_ANNOTATIONS = None
+
+
+def annotate(name: str, step_num: Optional[int] = None, **args):
+    """The profiler annotation ``name`` as a context manager: a host
+    span in whatever ``jax.profiler`` session is open, a flag test when
+    none is.  With ``step_num`` it is a step annotation (the profiler's
+    own notion of a step); ``args`` land beside the event.  The one
+    place the package writes into the profiler's trace (lint rule
+    JX012)."""
+    global _ANNOTATIONS
+    if _ANNOTATIONS is None:
+        from jax.profiler import StepTraceAnnotation, TraceAnnotation
+
+        _ANNOTATIONS = (TraceAnnotation, StepTraceAnnotation)
+    if step_num is None:
+        return _ANNOTATIONS[0](name, **args)
+    return _ANNOTATIONS[1](name, step_num=int(step_num), **args)
 
 
 def now() -> float:
@@ -519,16 +549,13 @@ class TraceSink:
     def __init__(self, enabled: Optional[bool] = None,
                  directory: Optional[str] = None,
                  max_steps: Optional[int] = None,
-                 max_events: int = 500_000,
-                 xla_annotate: Optional[bool] = None):
+                 max_events: int = 500_000):
         env = os.environ
         self.enabled = (env.get("CUP3D_TRACE", "0") not in ("0", "")
                         if enabled is None else enabled)
         self.directory = directory or env.get("CUP3D_TRACE_DIR") or "."
         self.max_steps = (int(env.get("CUP3D_TRACE_MAX", "100000"))
                           if max_steps is None else max_steps)
-        self.xla_annotate = (env.get("CUP3D_TRACE_XLA", "0") != "0"
-                             if xla_annotate is None else xla_annotate)
         self.epoch = time.perf_counter()
         self.events: deque = deque(maxlen=max_events)
         self.steps_recorded = 0
@@ -538,18 +565,12 @@ class TraceSink:
         self._shard_meta_emitted = False
         self._compile_meta_emitted = False
         self._lock = threading.Lock()
-        # round-13 satellite: the TraceAnnotation class resolves ONCE at
-        # construction/configure time, so the span hot path is a single
-        # attribute load + None test instead of an import-machinery trip
-        # (None = passthrough off or jax unavailable)
-        self._annotation_cls = self._resolve_annotation()
 
     # -- configuration -----------------------------------------------------
 
     def configure(self, enabled: Optional[bool] = None,
                   directory: Optional[str] = None,
-                  max_steps: Optional[int] = None,
-                  xla_annotate: Optional[bool] = None) -> "TraceSink":
+                  max_steps: Optional[int] = None) -> "TraceSink":
         """Explicit (re)configuration; closes any open writer so the next
         record lands in the new location."""
         self.close()
@@ -559,15 +580,12 @@ class TraceSink:
             self.directory = directory
         if max_steps is not None:
             self.max_steps = max_steps
-        if xla_annotate is not None:
-            self.xla_annotate = xla_annotate
         self.events.clear()
         self.steps_recorded = 0
         self.steps_dropped = 0
         self._lane_meta_emitted = False
         self._shard_meta_emitted = False
         self._compile_meta_emitted = False
-        self._annotation_cls = self._resolve_annotation()
         return self
 
     def default_directory(self, directory: str) -> None:
@@ -759,31 +777,6 @@ class TraceSink:
             self._writer.write(json.dumps(record) + "\n")
         _metrics.counter("trace.aux_records").inc()
 
-    # -- XLA passthrough ---------------------------------------------------
-
-    def _resolve_annotation(self):
-        """The ``jax.profiler.TraceAnnotation`` class when the XLA
-        passthrough is armed (enabled + xla_annotate) and jax imports,
-        else None.  Called once per construction/configure — NOT on the
-        span path (the round-13 satellite fix: the old lazy resolution
-        paid an import-machinery round trip under the hot span)."""
-        if not (self.enabled and self.xla_annotate):
-            return None
-        try:
-            from jax.profiler import TraceAnnotation
-
-            return TraceAnnotation
-        except Exception:  # pragma: no cover - jax-less envs
-            _metrics.counter("trace.annotation_unavailable").inc()
-            return None
-
-    def annotation(self, name: str):
-        """A ``jax.profiler.TraceAnnotation`` for ``name`` when the XLA
-        passthrough is on, else None — the fast no-op path is one
-        attribute load + None test (class cached at construction)."""
-        cls = self._annotation_cls
-        return cls(name) if cls is not None else None
-
     # -- export ------------------------------------------------------------
 
     def chrome_trace(self) -> dict:
@@ -867,38 +860,34 @@ class SpanTimer:
 
     @contextmanager
     def __call__(self, name: str):
-        ann = self.sink.annotation(name)
-        if ann is not None:
-            ann.__enter__()
-        # jax-lint: allow(JX006, span open: the annotation setup above
-        # dispatches nothing; spans label WALL phases by design)
-        t0 = time.perf_counter()
-        self._stack.append(0.0)
-        self._active[name] += 1
-        try:
-            yield
-        finally:
-            # jax-lint: allow(JX006, spans label WALL phases by design —
-            # SyncQoI/StreamWait exist precisely to attribute dispatch vs
-            # sync time; forcing a device sync per span would serialize
-            # the pipeline being instrumented)
-            # jax-lint: allow(JX008, this IS the obs span primitive the
-            # rule points everyone else at)
-            elapsed = time.perf_counter() - t0
-            if ann is not None:
-                ann.__exit__(None, None, None)
-            child = self._stack.pop()
-            self.totals[name] += elapsed - child
-            self._active[name] -= 1
-            if self._active[name] == 0:
-                # outermost entry only: recursive re-entries are part of
-                # the same logical call (the round-9 recursion fix)
-                self.counts[name] += 1
-            if self._stack:
-                self._stack[-1] += elapsed
-            sink = self.sink
-            if sink.enabled:
-                sink.span(name, t0, elapsed, depth=len(self._stack))
+        with annotate(ANNOTATION_PREFIX + name):
+            # jax-lint: allow(JX006, span open: the annotation above
+            # dispatches nothing; spans label WALL phases by design)
+            t0 = time.perf_counter()
+            self._stack.append(0.0)
+            self._active[name] += 1
+            try:
+                yield
+            finally:
+                # jax-lint: allow(JX006, spans label WALL phases by design
+                # — SyncQoI/StreamWait exist precisely to attribute
+                # dispatch vs sync time; forcing a device sync per span
+                # would serialize the pipeline being instrumented)
+                # jax-lint: allow(JX008, this IS the obs span primitive the
+                # rule points everyone else at)
+                elapsed = time.perf_counter() - t0
+                child = self._stack.pop()
+                self.totals[name] += elapsed - child
+                self._active[name] -= 1
+                if self._active[name] == 0:
+                    # outermost entry only: recursive re-entries are part
+                    # of the same logical call (the round-9 recursion fix)
+                    self.counts[name] += 1
+                if self._stack:
+                    self._stack[-1] += elapsed
+                sink = self.sink
+                if sink.enabled:
+                    sink.span(name, t0, elapsed, depth=len(self._stack))
 
     def section_totals(self) -> Dict[str, float]:
         """Plain-dict copy (StepObserver delta bookkeeping)."""
@@ -917,8 +906,9 @@ class SpanTimer:
 class StepObserver:
     """Driver glue: one instance per driver, wrapping each ``advance``.
 
-    Always (tracing on or off): appends a compact step record to the
-    flight recorder's ring and bumps the step counter — that is the
+    Always (tracing on or off): opens the profiler's step annotation
+    ``cup3d:step`` (:func:`annotate`), appends a compact step record to
+    the flight recorder's ring and bumps the step counter — that is the
     whole point of a flight recorder, postmortems need history from
     BEFORE anyone decided to trace.  When the sink is enabled it
     additionally computes per-section self-time deltas and emits the
@@ -975,6 +965,11 @@ class StepObserver:
         stall0 = (self.stream.stats.get("stall_s", 0.0)
                   if self.stream is not None else 0.0)
         late: dict = {}
+        # the profiler's step: the base step of a scan dispatch, with
+        # its length beside it
+        ann = annotate(ANNOTATION_PREFIX + "step", step_num=step,
+                       **{k: extra[k] for k in ("scan_k",) if k in extra})
+        ann.__enter__()
         # jax-lint: allow(JX006, the pre-step reads above are host dict
         # bookkeeping; wall_s is the HOST wall of advance by definition)
         t0 = time.perf_counter()
@@ -988,6 +983,7 @@ class StepObserver:
             # jax-lint: allow(JX008, StepObserver IS the obs layer's
             # step-span implementation)
             wall = time.perf_counter() - t0
+            ann.__exit__(None, None, None)  # the advance, not its record
             self._steps.inc()
             rec = {"step": int(step), "t": float(t), "dt": float(dt),
                    "wall_s": wall}

@@ -8,6 +8,7 @@ Laplacian, advanced by low-storage RK3 (main.cpp:9640-9728).
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from cup3d_tpu.grid.uniform import UniformGrid
@@ -46,6 +47,7 @@ def advection_diffusion_rhs(grid: UniformGrid, u: jnp.ndarray, nu: float,
     return jnp.stack(out, axis=-1)
 
 
+@jax.named_scope("AdvectionDiffusion")
 def rk3_step(grid: UniformGrid, u: jnp.ndarray, dt, nu: float,
              uinf: jnp.ndarray, pad=None) -> jnp.ndarray:
     """One explicit low-storage RK3 advection-diffusion step."""

@@ -46,6 +46,7 @@ def _hcol(grid: BlockGrid, dtype=jnp.float32, extra: int = 0) -> jnp.ndarray:
     return jnp.asarray(grid.h.reshape(shape), dtype)
 
 
+@jax.named_scope("FluxCorrection")
 def face_fluxes(lab: jnp.ndarray, w: int, bs: int, inv_h: jnp.ndarray):
     """Outward per-unit-area gradient fluxes (lab_nb - c)/h on the 6 faces:
     (nb, 6, bs, bs) in the grid/flux.py convention."""
@@ -187,6 +188,7 @@ def advdiff_rhs_blocks(
     return jnp.stack(rhs, axis=-1)
 
 
+@jax.named_scope("AdvectionDiffusion")
 def rk3_step_blocks(
     grid: BlockGrid,
     vel: jnp.ndarray,
@@ -334,6 +336,7 @@ def build_amr_poisson_solver(
             ].set(x_[slot0, 0, 0, 0])
         return lambda x_: laplacian_blocks(grid, x_, t, ft)
 
+    @jax.named_scope("PoissonSolve")
     def solve(rhs, x0=None, tab_arg=None, flux_arg=None, rnorm_ref=None,
               with_stats=False):
         # callers under jit pass the tables as traced ARGUMENTS so they
@@ -415,6 +418,7 @@ def build_amr_poisson_solver_dynamic(
     # would compile natively) — never a quiet downgrade
     _precision.check_policy(mean_constraint, forest_fused=fused_on)
 
+    @jax.named_scope("PoissonSolve")
     def solve(rhs, x0=None, tab_arg=None, flux_arg=None, rnorm_ref=None,
               geom=None, vol=None, pmask=None, graph=None, slot0=None,
               with_stats=False):
@@ -494,6 +498,7 @@ def build_amr_poisson_solver_dynamic(
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("FluxCorrection")
 def div_fluxes(vlab: jnp.ndarray, w: int, bs: int) -> jnp.ndarray:
     """Outward per-unit-area *velocity* fluxes of the centered divergence:
     F(+a) = +(u_c + u_hi)/2 . e_a, F(-a) = -(u_c + u_lo)/2 . e_a, so that
@@ -513,6 +518,7 @@ def div_fluxes(vlab: jnp.ndarray, w: int, bs: int) -> jnp.ndarray:
     return jnp.stack(fl, axis=1)
 
 
+@jax.named_scope("PoissonRHS")
 def pressure_rhs_blocks(
     grid: BlockGrid,
     vel: jnp.ndarray,
@@ -546,6 +552,7 @@ def solver_supports_stats(solver) -> bool:
                         "supports_stats", False))
 
 
+@jax.named_scope("PressureProjection")
 def project_blocks(
     grid: BlockGrid,
     vel: jnp.ndarray,
@@ -590,8 +597,9 @@ def project_blocks(
         out = solver(rhs, p_init, tab_arg=tab, flux_arg=flux_tab,
                      rnorm_ref=ref, **stats_kw)
         p, stats = out if stats_kw else (out, None)
-    plab = tab.assemble_scalar(p, bs)
-    gp = grad_blocks(grid, plab, tab.width)
+    with jax.named_scope("Gradient"):
+        plab = tab.assemble_scalar(p, bs)
+        gp = grad_blocks(grid, plab, tab.width)
     if with_stats:
         if stats is None:
             stats = jnp.zeros(2, jnp.float32)
@@ -605,6 +613,7 @@ def project_blocks(
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("AdaptMesh")
 def vorticity_score(grid: BlockGrid, vel: jnp.ndarray, tab: LabTables):
     """(nb,) max |curl u| per block — the reference's tag magnitude."""
     vlab = tab.assemble_vector(vel, grid.bs)
@@ -613,6 +622,7 @@ def vorticity_score(grid: BlockGrid, vel: jnp.ndarray, tab: LabTables):
     return jnp.max(mag.reshape(grid.nb, -1), axis=-1)
 
 
+@jax.named_scope("AdaptMesh")
 def gradchi_mask(grid: BlockGrid, chi: jnp.ndarray, tab: LabTables):
     """(nb,) bool: block touches the body interface (0 < chi < 1 anywhere
     or grad chi != 0) -> force max refinement (GradChiOnTmp)."""

@@ -13,6 +13,7 @@ Convention: sdf > 0 inside the body (matching the reference's rasterizer).
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from cup3d_tpu.grid.uniform import UniformGrid
@@ -31,6 +32,7 @@ def heaviside(sdf: jnp.ndarray, h: float) -> jnp.ndarray:
     return 0.5 * (1.0 + t + jnp.sin(jnp.pi * t) / jnp.pi)
 
 
+@jax.named_scope("CreateObstacles")
 def towers_chi(sdf_lab: jnp.ndarray, h) -> jnp.ndarray:
     """The reference's discrete Heaviside (Towers construction;
     KernelCharacteristicFunction, main.cpp:13312-13346): outside the

@@ -95,6 +95,7 @@ def fluid_divergence_max_blocks(grid, vel, chi, tab):
     )
 
 
+@jax.named_scope("DtPolicy")
 def max_velocity(u: jnp.ndarray, uinf: jnp.ndarray) -> jnp.ndarray:
     """max over cells of max-norm of lab-frame velocity (findMaxU)."""
     return jnp.max(jnp.abs(u + uinf))

@@ -247,6 +247,7 @@ def use_exact_getz() -> bool:
     return os.environ.get("CUP3D_GETZ", "") != "cg"
 
 
+@jax.named_scope("TileSolve")
 def getz_blocks(b_scaled: jnp.ndarray, shift=None,
                 cg_iters: int = 24) -> jnp.ndarray:
     """getZ preconditioner application in the (..., bs, bs, bs) blocks
@@ -260,6 +261,7 @@ def getz_blocks(b_scaled: jnp.ndarray, shift=None,
                           shift=0.0 if shift is None else shift)
 
 
+@jax.named_scope("TileSolve")
 def getz_lanes(bt_scaled: jnp.ndarray, shift=None,
                cg_iters: int = 24) -> jnp.ndarray:
     """getZ in the lane-resident (bs, bs, bs, T) layout (see getz_blocks)."""
@@ -471,6 +473,7 @@ def _make_coarse_solve_vec(grid: UniformGrid, bs: int = 8) -> Callable:
     core of make_coarse_correction_lanes / make_twolevel_preconditioner)."""
     core = _make_coarse_core(grid, bs)
 
+    @jax.named_scope("CoarseSolve")
     def solve_vec(rt: jnp.ndarray) -> jnp.ndarray:
         return core(jnp.sum(rt, axis=(0, 1, 2)).reshape(-1))
 
@@ -726,6 +729,7 @@ def _cg_graph(Cfun: Callable, b: jnp.ndarray, iters: int,
     return z
 
 
+@jax.named_scope("CoarseSolve")
 def coarse_correct_blocks(r: jnp.ndarray, vol: jnp.ndarray,
                           graph: BlockGraph, iters: int = 32) -> jnp.ndarray:
     """Coarse correction over the block graph: volume-weighted restrict
@@ -824,6 +828,10 @@ def bicgstab(
         M = lambda r: r
     if x0 is None:
         x0 = jnp.zeros_like(b)
+    # the iteration's three kinds of work, by name in a device trace
+    apply_A = jax.named_scope("Laplacian")(apply_A)
+    M = jax.named_scope("Preconditioner")(M)
+    dot = jax.named_scope("Dots")(_dot)
 
     # breakdown threshold in the ACCUMULATION dtype, not b.dtype: 1e-30
     # underflows to 0 in bf16/f16 storage, which would silently disable
@@ -831,7 +839,7 @@ def bicgstab(
     eps = jnp.asarray(1e-30, jnp.promote_types(b.dtype, jnp.float32))
 
     r0 = b - apply_A(x0)
-    rnorm0 = jnp.sqrt(_dot(r0, r0))
+    rnorm0 = jnp.sqrt(dot(r0, r0))
     ref = rnorm0 if rnorm_ref is None else rnorm_ref
     target = jnp.maximum(tol_abs, tol_rel * ref)
     one = jnp.asarray(1.0, b.dtype)
@@ -855,7 +863,7 @@ def bicgstab(
         return jnp.logical_and(s.k < maxiter, s.rnorm > target)
 
     def body(s: _BiCGState):
-        rho_new = _dot(s.rhat, s.r)
+        rho_new = dot(s.rhat, s.r)
         # rho breakdown -> re-seed shadow residual (reference restart,
         # main.cpp:14452-14479)
         broke = jnp.abs(rho_new) < eps * jnp.maximum(s.rnorm * s.rnorm, 1.0)
@@ -869,16 +877,16 @@ def bicgstab(
         p = s.r + beta * (p_prev - s.omega * v_prev)
         y = M(p)
         v = apply_A(y)
-        rhat_v = _dot(rhat, v)
+        rhat_v = dot(rhat, v)
         alpha = rho_new / _safe(rhat_v)
         svec = s.r - alpha * v
         z = M(svec)
         t = apply_A(z)
-        tt = _dot(t, t)
-        omega = _dot(t, svec) / _safe(tt)
+        tt = dot(t, t)
+        omega = dot(t, svec) / _safe(tt)
         x = s.x + alpha * y + omega * z
         r = svec - omega * t
-        rnorm = jnp.sqrt(_dot(r, r))
+        rnorm = jnp.sqrt(dot(r, r))
 
         better = rnorm < s.rnorm_best
         return _BiCGState(
@@ -998,6 +1006,7 @@ def build_iterative_solver(
 
         store = _precision.krylov_dtype()
 
+        @jax.named_scope("PoissonSolve")
         def solve(rhs: jnp.ndarray, x0: Optional[jnp.ndarray] = None,
                   with_stats: bool = False):
             b = rhs - jnp.mean(rhs)
@@ -1019,6 +1028,7 @@ def build_iterative_solver(
         solve.maxiter = maxiter
         return solve
 
+    @jax.named_scope("PoissonSolve")
     def solve(rhs: jnp.ndarray, x0: Optional[jnp.ndarray] = None,
               with_stats: bool = False):
         if mean_constraint == 2:
@@ -1079,6 +1089,7 @@ def _build_iterative_solver_dense(
     else:
         A = A0
 
+    @jax.named_scope("PoissonSolve")
     def solve(rhs: jnp.ndarray, x0: Optional[jnp.ndarray] = None,
               with_stats: bool = False):
         b = rhs - jnp.mean(rhs) if mean_constraint == 2 else rhs

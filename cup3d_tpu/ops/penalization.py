@@ -9,9 +9,11 @@ single fused elementwise kernel over the whole domain.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 
+@jax.named_scope("Penalization")
 def penalize(vel: jnp.ndarray, chi: jnp.ndarray, ubody: jnp.ndarray,
              lam, dt) -> jnp.ndarray:
     """vel, ubody: (...,3); chi in [0,1]; lam, dt scalars."""
@@ -27,6 +29,7 @@ def penalization_force(vel_new: jnp.ndarray, vel_old: jnp.ndarray, dt,
     return (vel_new - vel_old) * (h ** 3 / dt)
 
 
+@jax.named_scope("Penalization")
 def per_obstacle_penalization_force(
     vel_new: jnp.ndarray,
     vel_old: jnp.ndarray,

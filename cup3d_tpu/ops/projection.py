@@ -13,12 +13,14 @@ from __future__ import annotations
 
 from typing import Callable, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 
 from cup3d_tpu.grid.uniform import UniformGrid
 from cup3d_tpu.ops import stencils as st
 
 
+@jax.named_scope("PoissonRHS")
 def pressure_rhs(grid: UniformGrid, u: jnp.ndarray, dt,
                  chi: Optional[jnp.ndarray] = None,
                  udef: Optional[jnp.ndarray] = None) -> jnp.ndarray:
@@ -30,6 +32,7 @@ def pressure_rhs(grid: UniformGrid, u: jnp.ndarray, dt,
     return div_u / dt
 
 
+@jax.named_scope("PressureProjection")
 def project(grid: UniformGrid, u: jnp.ndarray, dt, solver: Callable,
             chi: Optional[jnp.ndarray] = None,
             udef: Optional[jnp.ndarray] = None,
@@ -49,7 +52,8 @@ def project(grid: UniformGrid, u: jnp.ndarray, dt, solver: Callable,
     else:
         p = solver(rhs, p_init)
         stats = None
-    gradp = st.grad(grid.pad_scalar(p, 1), 1, grid.h)
+    with jax.named_scope("Gradient"):
+        gradp = st.grad(grid.pad_scalar(p, 1), 1, grid.h)
     if with_stats:
         return u - dt * gradp, p, stats
     return u - dt * gradp, p

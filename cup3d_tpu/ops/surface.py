@@ -102,6 +102,7 @@ def _surface_band(sdf, chi, valid, h):
 _PROBE_CHUNK = 1024
 
 
+@jax.named_scope("ComputeForces")
 def surface_force_window(
     vel: jnp.ndarray,  # (Wx, Wy, Wz, 3) window velocity
     p: jnp.ndarray,  # (Wx, Wy, Wz)
@@ -464,6 +465,7 @@ def obstacle_probe_budget(ob, h) -> int:
 
 
 @partial(jax.jit, static_argnames=("wcells", "per_point", "max_points"))
+@jax.named_scope("ComputeForces")
 def _uniform_window_probe(vel, p, chi, sdf, udef, idx0, h, origin0, nu,
                           cm, u_trans, omega, wcells, per_point=False,
                           max_points=None):
@@ -548,6 +550,7 @@ def _gather_block_window(field, slots):
     return wi.reshape((nbx * bs, nby * bs, nbz * bs) + trail)
 
 
+@jax.named_scope("ComputeForces")
 def probe_blocks_core(vel, p, ob_chi, ob_sdf, ob_udef, slots, b0, h, nu,
                       cm, u_trans, omega, per_point: bool = False,
                       max_points: int | None = None):

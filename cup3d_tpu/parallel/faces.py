@@ -200,6 +200,7 @@ class ShardedFaceTables:
     def assemble_component(self, field, bs: int, comp: int) -> jnp.ndarray:
         return self._assemble(field[..., None], (comp,))[..., 0]
 
+    @jax.named_scope("Halo")
     def _assemble(self, fields: jnp.ndarray,
                   sign_comps: Optional[Tuple[int, ...]]) -> jnp.ndarray:
         f = self.forest

@@ -297,6 +297,7 @@ class ShardedLabTables:
     any_coarse: bool
     send_idx: jnp.ndarray  # (D, D, M)
 
+    @jax.named_scope("Halo")
     def _assemble(self, field: jnp.ndarray, bs: int, signed: bool):
         """field: (nb_pad, bs,bs,bs, C) sharded on axis 0 -> labs
         (nb_pad, L,L,L, C)."""
@@ -358,6 +359,7 @@ class ShardedLabTables:
         lab = self._assemble_signed_comp(field[..., None], bs, comp)
         return lab[..., 0]
 
+    @jax.named_scope("Halo")
     def _assemble_signed_comp(self, field, bs: int, comp: int):
         # per-component sign labs: reuse the vector path with the component's
         # sign column broadcast over the single channel

@@ -42,7 +42,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from cup3d_tpu.analysis.runtime import device_scalar, sanctioned_transfer
+from cup3d_tpu.analysis.runtime import (
+    blocking_read,
+    device_scalar,
+    sanctioned_transfer,
+)
+from cup3d_tpu.obs import trace as obs_trace
 from cup3d_tpu.config import SimulationConfig, parse_factory
 from cup3d_tpu.grid import adapt as ad
 from cup3d_tpu.grid import bucket as bk
@@ -118,6 +123,7 @@ class _ArgGeom:
 
 
 @jax.jit
+@jax.named_scope("DtPolicy")
 def _maxu_j(vel, uinf):
     return jnp.max(jnp.abs(vel + uinf))
 
@@ -129,6 +135,7 @@ from cup3d_tpu.sim.dtpolicy import (  # noqa: E402 (placed with jit helpers)
 
 
 @partial(jax.jit, static_argnames=("rasters", "cuts", "combine", "bs"))
+@jax.named_scope("CreateObstacles")
 def _create_blocks(packs, slots, frames, given, xc, real, h_raw, tab,
                    rasters, cuts, combine, bs):
     """CreateObstacles on the single-device forest as ONE program: from
@@ -172,6 +179,15 @@ def _create_blocks(packs, slots, frames, given, xc, real, h_raw, tab,
         if combine else (None, None)
     )
     return (tuple(sdfs), tuple(chis), tuple(udefs)) + combined
+
+
+def _regrid_part(name: str):
+    """One part of an adaptation pass that changes the mesh, in the
+    profiler's trace (``cup3d:AdaptMesh.<name>``).  An annotation and no
+    profiler section: the AdaptMesh section keeps the whole pass as its
+    self time, which is what ``amr.adapt_host_ms_per_step`` reads."""
+    return obs_trace.annotate(
+        f"{obs_trace.ANNOTATION_PREFIX}AdaptMesh.{name}")
 
 
 class AMRSimulation:
@@ -252,7 +268,6 @@ class AMRSimulation:
         # on; step traces under CUP3D_TRACE=1.  Solver stats ride the
         # packed QoI reads of the host path (the megastep pack layout is
         # unchanged — pipelined traces carry mesh/stream fields only).
-        from cup3d_tpu.obs import trace as obs_trace
         from cup3d_tpu.obs.flight import FlightRecorder
 
         obs_trace.TRACE.default_directory(cfg.path4serialization)
@@ -568,51 +583,52 @@ class AMRSimulation:
             else "bucket.table_memo_misses"
         ).inc()
         if memo is None:
-            cap = bk.capacity(g.nb)
-            coarse = (krylov.use_coarse_correction()
-                      if self._poisson_two_level is None
-                      else bool(self._poisson_two_level))
-            coarse = coarse and cfg.bMeanConstraint not in (1, 3)
-            h = np.ones(cap, np.float64)
-            h[: g.nb] = g.h
-            vol = np.zeros((cap, 1, 1, 1), np.float64)
-            vol[: g.nb, 0, 0, 0] = g.h**3
-            mask = np.zeros((cap, 1, 1, 1), np.float32)
-            mask[: g.nb] = 1.0
-            xc = np.zeros((cap, g.bs, g.bs, g.bs, 3), np.float32)
-            xc[: g.nb] = g.cell_centers(np.float32)
-            # corner pin slot (mean_constraint 1/3) rides as a DYNAMIC
-            # index so pin relocation across regrids never retraces
-            slot0 = 0
-            if cfg.bMeanConstraint in (1, 3):
-                slot0 = int(np.lexsort(
-                    (g.ijk[:, 2], g.ijk[:, 1], g.ijk[:, 0])
-                )[0])
-            # per-slot octree level for the on-device regrid decision
-            # (padding slots carry level 0 -> device_tags emits 'L')
-            level = np.zeros(cap, np.int32)
-            level[: g.nb] = [k[0] for k in g.keys]
-            tab1 = g.face_tables(1)
-            memo = dict(
-                cap=cap,
-                # faces of leaves whose neighbour is coarser: the rows of
-                # the coarse-face halo tables, before the bucket's padding
-                cf_faces=sum(int(r.shape[0]) for r in tab1.cf_rows),
-                tab1=pad_face_tables(tab1, g, cap),
-                tab3=pad_face_tables(g.face_tables(3), g, cap),
-                ftab=pad_flux_tables(build_flux_tables(g), g.bs, cap),
-                graph=(krylov.block_graph_tables(g, cap=cap)
-                       if coarse else None),
-                h=jnp.asarray(h, self.dtype),
-                vol=jnp.asarray(vol, self.dtype),
-                xc=jnp.asarray(xc, self.dtype),
-                mask=jnp.asarray(mask, self.dtype),
-                slot0=jnp.asarray(slot0, jnp.int32),
-                level=jnp.asarray(level),
-            )
-            self._table_memo[sig] = memo
-            while len(self._table_memo) > 4:
-                self._table_memo.pop(next(iter(self._table_memo)))
+            with _regrid_part("Tables"):
+                cap = bk.capacity(g.nb)
+                coarse = (krylov.use_coarse_correction()
+                          if self._poisson_two_level is None
+                          else bool(self._poisson_two_level))
+                coarse = coarse and cfg.bMeanConstraint not in (1, 3)
+                h = np.ones(cap, np.float64)
+                h[: g.nb] = g.h
+                vol = np.zeros((cap, 1, 1, 1), np.float64)
+                vol[: g.nb, 0, 0, 0] = g.h**3
+                mask = np.zeros((cap, 1, 1, 1), np.float32)
+                mask[: g.nb] = 1.0
+                xc = np.zeros((cap, g.bs, g.bs, g.bs, 3), np.float32)
+                xc[: g.nb] = g.cell_centers(np.float32)
+                # corner pin slot (mean_constraint 1/3) rides as a DYNAMIC
+                # index so pin relocation across regrids never retraces
+                slot0 = 0
+                if cfg.bMeanConstraint in (1, 3):
+                    slot0 = int(np.lexsort(
+                        (g.ijk[:, 2], g.ijk[:, 1], g.ijk[:, 0])
+                    )[0])
+                # per-slot octree level for the on-device regrid decision
+                # (padding slots carry level 0 -> device_tags emits 'L')
+                level = np.zeros(cap, np.int32)
+                level[: g.nb] = [k[0] for k in g.keys]
+                tab1 = g.face_tables(1)
+                memo = dict(
+                    cap=cap,
+                    # faces of leaves whose neighbour is coarser: the rows of
+                    # the coarse-face halo tables, before the bucket's padding
+                    cf_faces=sum(int(r.shape[0]) for r in tab1.cf_rows),
+                    tab1=pad_face_tables(tab1, g, cap),
+                    tab3=pad_face_tables(g.face_tables(3), g, cap),
+                    ftab=pad_flux_tables(build_flux_tables(g), g.bs, cap),
+                    graph=(krylov.block_graph_tables(g, cap=cap)
+                           if coarse else None),
+                    h=jnp.asarray(h, self.dtype),
+                    vol=jnp.asarray(vol, self.dtype),
+                    xc=jnp.asarray(xc, self.dtype),
+                    mask=jnp.asarray(mask, self.dtype),
+                    slot0=jnp.asarray(slot0, jnp.int32),
+                    level=jnp.asarray(level),
+                )
+                self._table_memo[sig] = memo
+                while len(self._table_memo) > 4:
+                    self._table_memo.pop(next(iter(self._table_memo)))
         self._cap = memo["cap"]
         self._tab1, self._tab3 = memo["tab1"], memo["tab3"]
         self._ftab = memo["ftab"]
@@ -664,12 +680,13 @@ class AMRSimulation:
         # (a forest above krylov.DENSE_COARSE_MAX, or no coarse level)
         obs_metrics.gauge("poisson.coarse_dense").set(int(
             self._graph is not None and self._graph.pinv is not None))
-        if ex is None:
-            ex = self._build_bucket_executables()
-            self._exec_cache[key] = ex
-        self._bind_bucket_executables(ex)
-        if cfg.pipelined:
-            self._build_megastep()
+        with _regrid_part("Rebind"):
+            if ex is None:
+                ex = self._build_bucket_executables()
+                self._exec_cache[key] = ex
+            self._bind_bucket_executables(ex)
+            if cfg.pipelined:
+                self._build_megastep()
 
     def _geo_args(self):
         """The canonical traced-geometry bundle every bucketed
@@ -1001,7 +1018,9 @@ class AMRSimulation:
             if pf is not None and pf[2] == "tags":
                 tags = np.rint(np.asarray(pf[0], np.float64))
             else:
-                tags = np.asarray(self._device_tags(
+                # the pass's one blocking read: it waits for the step's
+                # device work in front of the tags
+                tags = blocking_read("tags-read", self._device_tags(
                     self.state["vel"], self.state["chi"]
                 ))
             states = ad.states_from_tags(g, tags[: g.nb])
@@ -1012,9 +1031,9 @@ class AMRSimulation:
                 vals[vals.shape[0] // 2:] > 0.5
             )
         else:
-            vort, near_body = self._scores(
+            vort, near_body = blocking_read("tags-read", self._scores(
                 self.state["vel"], self.state["chi"]
-            )
+            ))
         score = np.asarray(vort, np.float64)[: g.nb]
         near = np.asarray(near_body)[: g.nb]
         if cfg.bAdaptChiGradient and near.any():
@@ -1032,19 +1051,26 @@ class AMRSimulation:
         from cup3d_tpu.obs import metrics as obs_metrics
 
         g = self.grid
-        plan = ad.adapt(g, states)
+        if all(s == "L" for s in states.values()):
+            # the steady-state pass: nothing asked for, no part opened
+            plan = None
+        else:
+            with _regrid_part("Plan"):
+                plan = ad.adapt(g, states)
         if plan is None:
             obs_metrics.counter("amr.regrid_noops").inc()
             return False
         obs_metrics.counter("amr.regrids").inc()
-        for k in ("vel", "udef", "chi", "p"):
-            self.state[k] = ad.transfer_field(
-                g, plan, self._unpad(self.state[k])
-            )
+        with _regrid_part("Transfer"):
+            for k in ("vel", "udef", "chi", "p"):
+                self.state[k] = ad.transfer_field(
+                    g, plan, self._unpad(self.state[k])
+                )
         self.grid = plan.new_grid
-        self._rebuild()
-        for k in self.state:
-            self.state[k] = self._pad(self.state[k])
+        self._rebuild()  # its Tables and Rebind parts
+        with _regrid_part("Transfer"):
+            for k in self.state:
+                self.state[k] = self._pad(self.state[k])
         return True
 
     # -- initialization ----------------------------------------------------
@@ -1195,17 +1221,13 @@ class AMRSimulation:
                 )
         else:
             # the designed once-per-step dt sync of the non-pipelined path
-            with sanctioned_transfer("umax-read"):
-                umax = float(
-                    self._maxu(self.state["vel"], self.uinf_device())
-                )
-                if self.obstacles:
-                    # body kinematics bound the CFL immediately (see
-                    # sim/simulation.py calc_max_timestep)
-                    umax = max(
-                        umax,
-                        float(jnp.max(jnp.abs(self.state["udef"]))),
-                    )
+            # (dispatched in front of the read, which then only waits)
+            maxima = (self._maxu(self.state["vel"], self.uinf_device()),)
+            if self.obstacles:
+                # body kinematics bound the CFL immediately (see
+                # sim/simulation.py calc_max_timestep)
+                maxima += (jnp.max(jnp.abs(self.state["udef"])),)
+            umax = max(float(m) for m in blocking_read("umax-read", maxima))
         self._last_umax = umax  # host float already (both branches)
         if not np.isfinite(umax) or umax > cfg.uMax_allowed:
             # NaN must trip the abort too: `NaN > x` is False, and a NaN
@@ -1316,7 +1338,6 @@ class AMRSimulation:
         """Join all off-critical-path output (pending dumps/checkpoints,
         trace writer) — run end, and anything that must observe the files
         on disk."""
-        from cup3d_tpu.obs import trace as obs_trace
 
         self._dumper.wait()
         try:
@@ -1449,10 +1470,10 @@ class AMRSimulation:
                 # the designed once-per-step moments sync of the
                 # non-pipelined obstacle path (the pipelined megastep
                 # streams these rows through the QoI pack instead)
-                with sanctioned_transfer("moments-read"):
-                    vals = np.asarray(
-                        self._moments_read(chis, s["vel"], cms), np.float64
-                    )
+                vals = blocking_read(
+                    "moments-read", self._moments_read(chis, s["vel"], cms),
+                    np.float64,
+                )
                 precheck = dict(zip(pairs, vals[n_obs * 19:].tolist()))
                 self._overlap_now = any(v > 0 for v in precheck.values())
                 M = vals[: n_obs * 19].reshape(n_obs, 19)
@@ -1667,8 +1688,7 @@ class AMRSimulation:
     def _consume_entry(self, entry: dict):
         vals = entry.get("vals")
         if vals is None:
-            with sanctioned_transfer("qoi-read"):
-                vals = np.asarray(entry["pack"], np.float64)
+            vals = blocking_read("qoi-read", entry["pack"], np.float64)
         off = 0
         for name, size in entry["layout"]:
             seg = vals[off:off + size]
@@ -1753,8 +1773,7 @@ class AMRSimulation:
         pack = jnp.concatenate([p[1].astype(self.dtype) for p in parts])
         # THE designed end-of-step packed QoI read of the host path: one
         # blocking transfer serves every consumer
-        with sanctioned_transfer("qoi-read"):
-            vals = np.asarray(pack, np.float64)
+        vals = blocking_read("qoi-read", pack, np.float64)
         off = 0
         for name, arr in parts:
             seg = vals[off:off + arr.shape[0]]

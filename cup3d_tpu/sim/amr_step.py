@@ -128,6 +128,7 @@ def make_step_bodies(
             vn, vo, chis, dt, view.vol, view.xc, cms
         )
 
+    @jax.named_scope("Penalization")
     def ubody(udef, cm, ut, om, view):
         # per-obstacle rigid+deformation velocity field from the cached
         # device cell centers (avoids Obstacle.body_velocity_field's host
@@ -157,10 +158,12 @@ def make_step_bodies(
             axis=-1,
         ))
 
+    @jax.named_scope("AdaptMesh")
     def scores(vel, chi, view):
         return (amr_ops.vorticity_score(view.geom, vel, view.tab1),
                 amr_ops.gradchi_mask(view.geom, chi, view.tab1))
 
+    @jax.named_scope("AdaptMesh")
     def tags(vel, chi, level, view):
         # on-device regrid DECISION: scores -> per-slot int8 tag in
         # one dispatch, so adapt_mesh downloads (cap,) bytes instead
@@ -170,6 +173,7 @@ def make_step_bodies(
         return ad.device_tags(vort, near, level, rtol, ctol, level_max,
                               level_max_vort, adapt_chi)
 
+    @jax.named_scope("UpdateObstacles")
     def moments(chis, vel, cms, view):
         # ``chis``: a tuple of fields (host path) or their stack (megastep).
         return jnp.stack([
@@ -190,6 +194,7 @@ def make_step_bodies(
         return jnp.stack([overlap_count(chis[i], chis[j]).astype(dtype)
                           for i, j in pairs])
 
+    @jax.named_scope("UpdateObstacles")
     def moments_read(chis, vel, cms, view):
         """UpdateObstacles' device half on the per-step path: the vector
         its one blocking read fetches, every body's moments and then the
@@ -198,6 +203,7 @@ def make_step_bodies(
             [moments(chis, vel, cms, view).reshape(-1).astype(dtype),
              overlaps(chis)])
 
+    @jax.named_scope("Penalization")
     def penalize_bodies(vel, chis, udefs, rigid, dt, lam, view):
         """Penalization with every body's velocity field built in the
         trace: ``rigid`` holds a row (transVel, angVel, centerOfMass) per
@@ -222,6 +228,7 @@ def make_step_bodies(
         PF = -penal_force(vel_new, vel, tuple(chis), dt, rigid[:, 6:9], view)
         return vel_new, PF.reshape(-1).astype(dtype)
 
+    @jax.named_scope("ComputeForces")
     def forces_bodies(vel, p, chis, sdfs, udefs, win, rigid, view):
         """ComputeForces: the surface-point probe of every body
         (ops/surface.py: the production force measure, on the body's dense
@@ -304,15 +311,16 @@ def make_step_bodies(
 
         # next step's frame velocity from the NEW rigid state, so the
         # device chain matches non-pipelined uinf semantics exactly
-        nfix = jnp.sum(fixmask)
-        mean_tv = jnp.sum(
-            out[:, 0:3] * fixmask[:, None], axis=0
-        ) / jnp.maximum(nfix, 1.0)
-        uinf_next = jnp.where(nfix > 0, -mean_tv, uinf)
-        umax = jnp.maximum(
-            jnp.max(jnp.abs(vel + uinf_next)),
-            jnp.max(jnp.abs(udef)),
-        ).reshape(1)
+        with jax.named_scope("DtPolicy"):
+            nfix = jnp.sum(fixmask)
+            mean_tv = jnp.sum(
+                out[:, 0:3] * fixmask[:, None], axis=0
+            ) / jnp.maximum(nfix, 1.0)
+            uinf_next = jnp.where(nfix > 0, -mean_tv, uinf)
+            umax = jnp.maximum(
+                jnp.max(jnp.abs(vel + uinf_next)),
+                jnp.max(jnp.abs(udef)),
+            ).reshape(1)
         pack = jnp.concatenate(
             [out.reshape(-1), PF, F, overlaps(chis), flux_msr, umax]
         )
@@ -328,7 +336,8 @@ def make_step_bodies(
             view.geom, vel, dt, view.sol, view.tab1, view.ftab,
             p_init=p, second_order=second_order,
         )
-        umax = jnp.max(jnp.abs(vel + uinf)).reshape(1)
+        with jax.named_scope("DtPolicy"):
+            umax = jnp.max(jnp.abs(vel + uinf)).reshape(1)
         pack = jnp.concatenate([flux_msr, umax])
         return vel, p, pack
 

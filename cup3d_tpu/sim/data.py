@@ -108,11 +108,11 @@ class SimulationData:
             import jax
 
             xc = self.xc
-            self._ubody_cache_fn = jax.jit(
+            self._ubody_cache_fn = jax.jit(jax.named_scope("Penalization")(
                 lambda udef, cm, ut, om: ut
                 + jnp.cross(jnp.broadcast_to(om, xc.shape), xc - cm)
                 + udef
-            )
+            ))
         return self._ubody_cache_fn
 
     @property

@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 
 __all__ = ["ramped_cfl", "diffusion_cap", "dt_host", "dt_device",
-           "dt_device_implicit"]
+           "dt_device_implicit", "dt_scan"]
 
 
 def ramped_cfl(cfl: float, step: int, rampup: int) -> float:
@@ -55,7 +55,19 @@ def dt_host(h: float, nu: float, umax: float, cfl: float, step: int,
                      cfl_eff * dt_adv))
 
 
+@jax.named_scope("DtPolicy")
+def dt_scan(cfl_eff, h: float, nu: float, umax, dtprev):
+    """The scan bodies' dt, traced: from the CARRIED umax (one step
+    stale, like the host chain's freshly consumed pack), capped by the
+    combined bound above and by the 1.03x growth limiter against the
+    previous step's dt."""
+    cap = (h * h / 6.0) / (nu + (h / 6.0) * umax)
+    dt = jnp.minimum(cfl_eff * h / (umax + 1e-8), cap)
+    return jnp.where(dtprev > 0, jnp.minimum(dt, 1.03 * dtprev), dt)
+
+
 @jax.jit
+@jax.named_scope("DtPolicy")
 def dt_device(umax, cfl_eff, hmin, nu):
     """Device-resident dt (explicit diffusion): same formula, umax stays
     on device so the pipelined driver never blocks on it."""
@@ -64,6 +76,7 @@ def dt_device(umax, cfl_eff, hmin, nu):
 
 
 @jax.jit
+@jax.named_scope("DtPolicy")
 def dt_device_implicit(umax, cfl_eff, hmin, nu, past_warmup):
     """Device-resident dt, implicit diffusion: absolute 0.1 cap once
     step > 10 (main.cpp:15270-15271), combined cap before that."""

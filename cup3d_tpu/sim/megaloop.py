@@ -56,6 +56,7 @@ from cup3d_tpu.ops.penalization import (
     per_obstacle_penalization_force,
 )
 from cup3d_tpu.ops.projection import project
+from cup3d_tpu.sim import dtpolicy
 
 # QoI row layouts.  Fish: rigid pack 0:29 | penal force/torque 29:35 |
 # force probe pack 35:52 | [residual, iterations] 52:54 | internal
@@ -132,6 +133,99 @@ def init_fish_carry(s, ob):
     }
 
 
+def _fish_stages(s, ob):
+    """What both single-fish scan bodies (solo and x-slab) do between
+    their field operators, written once: everything geometric frozen
+    static at build time (the rasterization window, the probe window and
+    its slot budget, the forced/blocked masks), and the three stages
+    that are lines of the body rather than calls into ``ops/`` —
+    each under its operator's name in a device trace."""
+    from types import SimpleNamespace
+
+    from cup3d_tpu.models.fish.device_midline import midline_state_device
+    from cup3d_tpu.models.fish.rasterize import rasterize_midline
+    from cup3d_tpu.ops.surface import (
+        _uniform_window_probe,
+        obstacle_probe_budget,
+        window_size_cells,
+    )
+
+    grid, nu, dtype = s.grid, s.nu, s.dtype
+    cfg = s.cfg
+    h = float(grid.h)
+
+    n = np.asarray(grid.shape)
+    grid_shape = tuple(int(v) for v in n)
+    window_shape = tuple(ob._window_shape)
+    half_win = jnp.asarray(0.5 * np.asarray(window_shape) * h, dtype)
+    lim_win = jnp.asarray(n - np.asarray(window_shape), jnp.int32)
+    wp = int(min(window_size_cells(ob.length, h), n.min()))
+    half_probe = jnp.asarray(0.5 * wp * h, dtype)
+    lim_probe = jnp.asarray(n - wp, jnp.int32)
+    budget = obstacle_probe_budget(ob, h)
+    xc = s.xc
+    h3 = h ** 3
+    hd = jnp.asarray(h, dtype)
+    zero3 = jnp.zeros(3, dtype)
+    dlm = float(cfg.DLM)
+    lam_static = jnp.asarray(cfg.lambda_penalization, dtype)
+
+    @jax.named_scope("CreateObstacles")
+    def create(gait, time, dt, qint, rigid):
+        """Shape kinematics + rasterization from the PRE-update rigid
+        state (host order: CreateObstacles runs before UpdateObstacles):
+        (sdf, chi, udef, the new internal quaternion)."""
+        mid, qint_new = midline_state_device(gait, time, dt, qint)
+        pos = rigid[6:9]
+        rot = quat_to_rot_dev(rigid[15:19])
+        idx0 = jnp.clip(jnp.floor((pos - half_win) / hd).astype(jnp.int32),
+                        0, lim_win)
+        origin = idx0.astype(dtype) * hd
+        sdf_w, udef_w = rasterize_midline(origin, hd, window_shape, mid,
+                                          pos, rot)
+        sdf = jnp.full(grid_shape, -1.0, dtype)
+        sdf = jax.lax.dynamic_update_slice(
+            sdf, sdf_w, (idx0[0], idx0[1], idx0[2]))
+        udef = jnp.zeros(grid_shape + (3,), dtype)
+        udef = jax.lax.dynamic_update_slice(
+            udef, udef_w, (idx0[0], idx0[1], idx0[2], 0))
+        chi = towers_chi(grid.pad_scalar(sdf, 1), grid.h)
+        udef = udef * (chi > 0)[..., None]
+        return sdf, chi, udef, qint_new
+
+    @jax.named_scope("Penalization")
+    def penalization(vel, chi, udef, ut, om, cm, dt):
+        """Penalization toward the updated body velocity field:
+        (velocity, minus the momentum it injected)."""
+        ubody = ut + jnp.cross(jnp.broadcast_to(om, xc.shape), xc - cm) \
+            + udef
+        lam = dlm / dt if dlm > 0 else lam_static
+        vel_new = penalize(vel, chi, ubody, lam, dt)
+        PF = -per_obstacle_penalization_force(
+            vel_new, vel, (chi,), dt, h3, xc, cm[None])[0]
+        return vel_new, PF
+
+    @jax.named_scope("ComputeForces")
+    def forces(vel, p, chi, sdf, udef, pos_new, cm, ut, om):
+        """Surface-probe force QoI around the updated position."""
+        idx0f = jnp.clip(
+            jnp.floor((pos_new - half_probe) / hd).astype(jnp.int32),
+            0, lim_probe)
+        return pack_forces(_uniform_window_probe(
+            vel, p, chi, sdf, udef, idx0f, hd, zero3, nu, cm, ut, om,
+            wcells=wp, max_points=budget))
+
+    return SimpleNamespace(create=create, penalization=penalization,
+                           forces=forces)
+
+
+@jax.named_scope("DtPolicy")
+def _umax_with_body(vel, uinf, udef):
+    """The CFL scale the next step's dt is formed from: the fluid's and
+    the body's own (see Simulation.calc_max_timestep)."""
+    return jnp.maximum(max_velocity(vel, uinf), jnp.max(jnp.abs(udef)))
+
+
 def make_tgv_step(s):
     """The obstacle-free scan body as a pure function
     ``one_step(carry, cfl_eff) -> (carry', row (TGV_ROW,))``.  All grid /
@@ -148,9 +242,7 @@ def make_tgv_step(s):
     def one_step(carry, cfl_eff):
         vel, p = carry["vel"], carry["p"]
         umax, time, dtprev = carry["umax"], carry["time"], carry["dt"]
-        cap = (h * h / 6.0) / (nu + (h / 6.0) * umax)
-        dt = jnp.minimum(cfl_eff * h / (umax + 1e-8), cap)
-        dt = jnp.where(dtprev > 0, jnp.minimum(dt, 1.03 * dtprev), dt)
+        dt = dtpolicy.dt_scan(cfl_eff, h, nu, umax, dtprev)
         vel = rk3_step(grid, vel, dt, nu, uinf)
         if with_stats:
             vel, p, stats = project(grid, vel, dt, solver, p_init=p,
@@ -185,74 +277,30 @@ def make_fish_step(s, ob):
     """The single-StefanFish scan body as a pure function
     ``one_step(gait, carry, cfl_eff) -> (carry', row (FISH_ROW,))``.
 
-    Everything geometric is frozen static at build time: the rasterization
-    window, the probe window + slot budget (obstacle_probe_budget), and
-    the forced/blocked masks.  The
+    Everything geometric is frozen static at build time
+    (:func:`_fish_stages`).  The
     frozen-gait parameters are an ARGUMENT pytree rather than a closure,
     so the solo megaloop can bake one gait in as trace-time constants
     while fleet/batch.py stacks per-lane gaits and vmaps over them."""
-    from cup3d_tpu.models.fish.rasterize import rasterize_midline
-    from cup3d_tpu.ops.surface import (
-        _uniform_window_probe,
-        obstacle_probe_budget,
-        window_size_cells,
-    )
-
     grid, nu, dtype = s.grid, s.nu, s.dtype
-    cfg = s.cfg
     h = float(grid.h)
     solver = s.poisson_solver
     with_stats = bool(getattr(solver, "supports_stats", False))
-
-    n = np.asarray(grid.shape)
-    grid_shape = tuple(int(v) for v in n)
-    window_shape = tuple(ob._window_shape)
-    half_win = jnp.asarray(0.5 * np.asarray(window_shape) * h, dtype)
-    lim_win = jnp.asarray(n - np.asarray(window_shape), jnp.int32)
-    wp = int(min(window_size_cells(ob.length, h), n.min()))
-    half_probe = jnp.asarray(0.5 * wp * h, dtype)
-    lim_probe = jnp.asarray(n - wp, jnp.int32)
-    budget = obstacle_probe_budget(ob, h)
+    stage = _fish_stages(s, ob)
     forced_mask = ob.forced_mask_dev()
     block_mask = ob.block_mask_dev()
     fix_frame = bool(ob.bFixFrameOfRef)
     uinf_const = None if fix_frame else s.uinf_device()
     xc = s.xc
     h3 = h ** 3
-    hd = jnp.asarray(h, dtype)
-    zero3 = jnp.zeros(3, dtype)
-    dlm = float(cfg.DLM)
-    lam_static = jnp.asarray(cfg.lambda_penalization, dtype)
-
-    from cup3d_tpu.models.fish.device_midline import midline_state_device
 
     def one_step(gait, carry, cfl_eff):
         vel, p = carry["vel"], carry["p"]
         rigid, qint = carry["rigid"], carry["qint"]
         umax, time, dtprev = carry["umax"], carry["time"], carry["dt"]
-        # dt from the carried umax (one step stale, like the host chain)
-        cap = (h * h / 6.0) / (nu + (h / 6.0) * umax)
-        dt = jnp.minimum(cfl_eff * h / (umax + 1e-8), cap)
-        dt = jnp.where(dtprev > 0, jnp.minimum(dt, 1.03 * dtprev), dt)
+        dt = dtpolicy.dt_scan(cfl_eff, h, nu, umax, dtprev)
         uinf = -rigid[0:3] if fix_frame else uinf_const
-        # shape kinematics + rasterization from the PRE-update rigid state
-        # (host order: CreateObstacles runs before UpdateObstacles)
-        mid, qint_new = midline_state_device(gait, time, dt, qint)
-        pos = rigid[6:9]
-        rot = quat_to_rot_dev(rigid[15:19])
-        idx0 = jnp.clip(jnp.floor((pos - half_win) / hd).astype(jnp.int32),
-                        0, lim_win)
-        origin = idx0.astype(dtype) * hd
-        sdf_w, udef_w = rasterize_midline(origin, hd, window_shape, mid,
-                                          pos, rot)
-        sdf = jnp.full(grid_shape, -1.0, dtype)
-        sdf = jax.lax.dynamic_update_slice(
-            sdf, sdf_w, (idx0[0], idx0[1], idx0[2]))
-        udef = jnp.zeros(grid_shape + (3,), dtype)
-        udef = jax.lax.dynamic_update_slice(
-            udef, udef_w, (idx0[0], idx0[1], idx0[2], 0))
-        chi = towers_chi(grid.pad_scalar(sdf, 1), grid.h)
-        udef = udef * (chi > 0)[..., None]
+        sdf, chi, udef, qint_new = stage.create(gait, time, dt, qint, rigid)
         # advection-diffusion
         vel = rk3_step(grid, vel, dt, nu, uinf)
         # chi-weighted fluid momenta -> 6-DOF rigid update, on device
@@ -262,14 +310,7 @@ def make_fish_step(s, ob):
                                   uinf, dt)
         rigid_new = out[:RIGID_STATE]
         ut, om, cm = out[0:3], out[3:6], out[12:15]
-        # penalization toward the updated body velocity field
-        ubody = ut + jnp.cross(jnp.broadcast_to(om, xc.shape), xc - cm) \
-            + udef
-        lam = dlm / dt if dlm > 0 else lam_static
-        vel_old = vel
-        vel = penalize(vel, chi, ubody, lam, dt)
-        PF = -per_obstacle_penalization_force(
-            vel, vel_old, (chi,), dt, h3, xc, cm[None])[0]
+        vel, PF = stage.penalization(vel, chi, udef, ut, om, cm, dt)
         # projection, warm-started from the carried pressure
         if with_stats:
             vel, p, stats = project(grid, vel, dt, solver, chi, udef,
@@ -278,17 +319,10 @@ def make_fish_step(s, ob):
         else:
             vel, p = project(grid, vel, dt, solver, chi, udef, p_init=p)
             stats = _solver_stats(dtype)
-        # surface-probe force QoI around the updated position
-        idx0f = jnp.clip(
-            jnp.floor((out[6:9] - half_probe) / hd).astype(jnp.int32),
-            0, lim_probe)
-        F = pack_forces(_uniform_window_probe(
-            vel, p, chi, sdf, udef, idx0f, hd, zero3, nu, cm, ut, om,
-            wcells=wp, max_points=budget))
+        F = stage.forces(vel, p, chi, sdf, udef, out[6:9], cm, ut, om)
         # umax with the PRE-update uinf: the host emit point reads the
         # previous step's frame velocity (Simulation._emit_step_pack)
-        umax_new = jnp.maximum(max_velocity(vel, uinf),
-                               jnp.max(jnp.abs(udef)))
+        umax_new = _umax_with_body(vel, uinf, udef)
         time_new = time + dt
         carry_new = {
             "vel": vel, "p": p, "chi": chi, "udef": udef,
@@ -392,9 +426,7 @@ def make_tgv_step_sharded(s, axis="x"):
     def one_step(carry, cfl_eff):
         vel, p = carry["vel"], carry["p"]
         umax, time, dtprev = carry["umax"], carry["time"], carry["dt"]
-        cap = (h * h / 6.0) / (nu + (h / 6.0) * umax)
-        dt = jnp.minimum(cfl_eff * h / (umax + 1e-8), cap)
-        dt = jnp.where(dtprev > 0, jnp.minimum(dt, 1.03 * dtprev), dt)
+        dt = dtpolicy.dt_scan(cfl_eff, h, nu, umax, dtprev)
         vel = rk3_step(grid, vel, dt, nu, uinf, pad=pad_vec)
         # projection: slab divergence, replicated global solve
         # (ops/projection.pressure_rhs semantics on the slab)
@@ -449,41 +481,19 @@ def make_fish_step_sharded(s, ob, axis="x"):
     identical everywhere, and the rest works on the gathered velocity,
     so every reduction keeps the solo order and the step stays bitwise
     against make_fish_step."""
-    from cup3d_tpu.models.fish.rasterize import rasterize_midline
-    from cup3d_tpu.ops.surface import (
-        _uniform_window_probe,
-        obstacle_probe_budget,
-        window_size_cells,
-    )
     from cup3d_tpu.parallel import collectives as coll
     from cup3d_tpu.parallel import ring as _ring
 
     grid, nu, dtype = s.grid, s.nu, s.dtype
-    cfg = s.cfg
     h = float(grid.h)
     solver = s.poisson_solver
-
-    n = np.asarray(grid.shape)
-    grid_shape = tuple(int(v) for v in n)
-    window_shape = tuple(ob._window_shape)
-    half_win = jnp.asarray(0.5 * np.asarray(window_shape) * h, dtype)
-    lim_win = jnp.asarray(n - np.asarray(window_shape), jnp.int32)
-    wp = int(min(window_size_cells(ob.length, h), n.min()))
-    half_probe = jnp.asarray(0.5 * wp * h, dtype)
-    lim_probe = jnp.asarray(n - wp, jnp.int32)
-    budget = obstacle_probe_budget(ob, h)
+    stage = _fish_stages(s, ob)
     forced_mask = ob.forced_mask_dev()
     block_mask = ob.block_mask_dev()
     fix_frame = bool(ob.bFixFrameOfRef)
     uinf_const = None if fix_frame else s.uinf_device()
     xc = s.xc
     h3 = h ** 3
-    hd = jnp.asarray(h, dtype)
-    zero3 = jnp.zeros(3, dtype)
-    dlm = float(cfg.DLM)
-    lam_static = jnp.asarray(cfg.lambda_penalization, dtype)
-
-    from cup3d_tpu.models.fish.device_midline import midline_state_device
 
     def pad_vec(u, w):
         return _ring.pad_slab_vector(grid, u, w, axis)
@@ -492,28 +502,11 @@ def make_fish_step_sharded(s, ob, axis="x"):
         vel, p = carry["vel"], carry["p"]
         rigid, qint = carry["rigid"], carry["qint"]
         umax, time, dtprev = carry["umax"], carry["time"], carry["dt"]
-        cap = (h * h / 6.0) / (nu + (h / 6.0) * umax)
-        dt = jnp.minimum(cfl_eff * h / (umax + 1e-8), cap)
-        dt = jnp.where(dtprev > 0, jnp.minimum(dt, 1.03 * dtprev), dt)
+        dt = dtpolicy.dt_scan(cfl_eff, h, nu, umax, dtprev)
         uinf = -rigid[0:3] if fix_frame else uinf_const
         # shape kinematics + rasterization: replicated (pure functions
         # of the replicated rigid/gait scalars)
-        mid, qint_new = midline_state_device(gait, time, dt, qint)
-        pos = rigid[6:9]
-        rot = quat_to_rot_dev(rigid[15:19])
-        idx0 = jnp.clip(jnp.floor((pos - half_win) / hd).astype(jnp.int32),
-                        0, lim_win)
-        origin = idx0.astype(dtype) * hd
-        sdf_w, udef_w = rasterize_midline(origin, hd, window_shape, mid,
-                                          pos, rot)
-        sdf = jnp.full(grid_shape, -1.0, dtype)
-        sdf = jax.lax.dynamic_update_slice(
-            sdf, sdf_w, (idx0[0], idx0[1], idx0[2]))
-        udef = jnp.zeros(grid_shape + (3,), dtype)
-        udef = jax.lax.dynamic_update_slice(
-            udef, udef_w, (idx0[0], idx0[1], idx0[2], 0))
-        chi = towers_chi(grid.pad_scalar(sdf, 1), grid.h)
-        udef = udef * (chi > 0)[..., None]
+        sdf, chi, udef, qint_new = stage.create(gait, time, dt, qint, rigid)
         # advection-diffusion on the slab, halos by ring permute
         vel = rk3_step(grid, vel, dt, nu, uinf, pad=pad_vec)
         vel_full = coll.all_gather_tiled(vel, axis)
@@ -523,24 +516,15 @@ def make_fish_step_sharded(s, ob, axis="x"):
                                   uinf, dt)
         rigid_new = out[:RIGID_STATE]
         ut, om, cm = out[0:3], out[3:6], out[12:15]
-        ubody = ut + jnp.cross(jnp.broadcast_to(om, xc.shape), xc - cm) \
-            + udef
-        lam = dlm / dt if dlm > 0 else lam_static
-        vel_pen = penalize(vel_full, chi, ubody, lam, dt)
-        PF = -per_obstacle_penalization_force(
-            vel_pen, vel_full, (chi,), dt, h3, xc, cm[None])[0]
+        vel_pen, PF = stage.penalization(vel_full, chi, udef, ut, om, cm,
+                                         dt)
         p_prev = coll.all_gather_tiled(p, axis)
         vel_proj, p_full = project(grid, vel_pen, dt, solver, chi, udef,
                                    p_init=p_prev)
         stats = _solver_stats(dtype)
-        idx0f = jnp.clip(
-            jnp.floor((out[6:9] - half_probe) / hd).astype(jnp.int32),
-            0, lim_probe)
-        F = pack_forces(_uniform_window_probe(
-            vel_proj, p_full, chi, sdf, udef, idx0f, hd, zero3, nu, cm,
-            ut, om, wcells=wp, max_points=budget))
-        umax_new = jnp.maximum(max_velocity(vel_proj, uinf),
-                               jnp.max(jnp.abs(udef)))
+        F = stage.forces(vel_proj, p_full, chi, sdf, udef, out[6:9], cm,
+                         ut, om)
+        umax_new = _umax_with_body(vel_proj, uinf, udef)
         time_new = time + dt
         sx = vel.shape[0]
         me = jax.lax.axis_index(axis)

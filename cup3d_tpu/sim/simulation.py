@@ -14,7 +14,11 @@ from typing import List, Optional
 import jax
 import numpy as np
 
-from cup3d_tpu.analysis.runtime import device_scalar, sanctioned_transfer
+from cup3d_tpu.analysis.runtime import (
+    blocking_read,
+    device_scalar,
+    sanctioned_transfer,
+)
 from cup3d_tpu.config import SimulationConfig, parse_factory
 from cup3d_tpu.obs import trace as obs_trace
 from cup3d_tpu.obs.flight import FlightRecorder
@@ -275,21 +279,18 @@ class Simulation:
         else:
             # the designed once-per-step dt sync of the non-pipelined
             # path (the ONLY device->host read its steady-state step pays)
-            with sanctioned_transfer("umax-read"):
-                umax = float(
-                    self._max_u(s.state["vel"], s.uinf_device())
-                )
-                if s.obstacles:
-                    # the CFL scale must see the BODY kinematics
-                    # immediately: at full gait amplitude the tail's
-                    # deformation velocity reaches the advective limit one
-                    # step before it imprints on the measured fluid field
-                    # (blow-up observed at the diffusive-cap dt otherwise)
-                    import jax.numpy as _jnp
+            # (dispatched in front of the read, which then only waits)
+            maxima = (self._max_u(s.state["vel"], s.uinf_device()),)
+            if s.obstacles:
+                # the CFL scale must see the BODY kinematics
+                # immediately: at full gait amplitude the tail's
+                # deformation velocity reaches the advective limit one
+                # step before it imprints on the measured fluid field
+                # (blow-up observed at the diffusive-cap dt otherwise)
+                import jax.numpy as _jnp
 
-                    umax = max(
-                        umax, float(_jnp.max(_jnp.abs(s.state["udef"])))
-                    )
+                maxima += (_jnp.max(_jnp.abs(s.state["udef"])),)
+            umax = max(float(m) for m in blocking_read("umax-read", maxima))
         self._last_umax = umax  # host float already (both branches)
         if not np.isfinite(umax) or umax > cfg.uMax_allowed:
             # NaN must trip the abort too (`NaN > x` is False; code-review r4)
@@ -582,8 +583,7 @@ class Simulation:
         vals = entry.get("vals")
         if vals is None:
             # the designed end-of-step QoI sync of the non-pipelined path
-            with sanctioned_transfer("qoi-read"):
-                vals = np.asarray(entry["pack"], np.float64)
+            vals = blocking_read("qoi-read", entry["pack"], np.float64)
         ob = s.obstacles[0] if s.obstacles else None
         off = 0
         for name, size in entry["layout"]:

@@ -52,6 +52,7 @@ from typing import Callable, Iterable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from cup3d_tpu.analysis.runtime import blocking_read
 from cup3d_tpu.obs import trace as _trace
 
 
@@ -293,7 +294,7 @@ class QoIStream:
         # are exactly the obs attribution the rule asks for)
         t0 = _trace.now()
         with ctx:
-            vals = np.asarray(holder["batch"], np.float64)
+            vals = blocking_read("stream-read", holder["batch"], np.float64)
         elapsed = _trace.now() - t0
         self.stats["stall_s" if not was_ready else "read_s"] += elapsed
         self.stats["groups_read"] += 1

@@ -3,10 +3,12 @@ file uses (the fault plan's fixture, ``clean_faults``, is in conftest)."""
 
 import os
 
+import jax
 import numpy as np
 
 from cup3d_tpu.config import SimulationConfig
 from cup3d_tpu.obs import metrics as M
+from cup3d_tpu.obs import profile as P
 from cup3d_tpu.sim.simulation import Simulation
 
 
@@ -72,3 +74,24 @@ def tgv_spec(**kw):
 def delta(before, key):
     """Growth of one metric since the ``M.snapshot()`` in ``before``."""
     return M.snapshot().get(key, 0) - before.get(key, 0)
+
+
+def scope_paths(thunk):
+    """The scope paths (``obs/profile.scope_path``) of every equation
+    ``thunk`` traces to, those of nested programs and loop bodies under
+    the path of the equation that holds them, as the ``op_name`` of the
+    lowered operation has them; nothing is compiled or run."""
+    out = set()
+
+    def walk(jaxpr, prefix):
+        for eqn in jaxpr.eqns:
+            stack = f"{prefix}/{eqn.source_info.name_stack}"
+            out.add("/".join(P.scope_path(stack)))
+            for v in eqn.params.values():
+                for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        walk(sub, stack)
+
+    walk(jax.make_jaxpr(thunk)().jaxpr, "")
+    return out - {""}

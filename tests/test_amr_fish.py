@@ -182,6 +182,82 @@ def test_forest_moments_run_at_highest(fish_sim):
     assert_dots_highest(jaxpr, at_least=5 * len(sim.obstacles))
 
 
+FOREST_SOLVE = {
+    "PressureProjection/PoissonRHS", "PressureProjection/PoissonRHS/Halo",
+    "PressureProjection/PoissonRHS/FluxCorrection",
+    "PressureProjection/PoissonSolve",
+    "PressureProjection/PoissonSolve/Laplacian",
+    "PressureProjection/PoissonSolve/Laplacian/Halo",
+    "PressureProjection/PoissonSolve/Laplacian/FluxCorrection",
+    "PressureProjection/PoissonSolve/Preconditioner",
+    "PressureProjection/PoissonSolve/Preconditioner/TileSolve",
+    "PressureProjection/PoissonSolve/Preconditioner/CoarseSolve",
+    "PressureProjection/PoissonSolve/Dots",
+    "PressureProjection/Gradient", "PressureProjection/Gradient/Halo"}
+FOREST_ADVDIFF = {"AdvectionDiffusion", "AdvectionDiffusion/Halo",
+                  "AdvectionDiffusion/FluxCorrection"}
+FOREST_SCOPES = {
+    "advdiff": FOREST_ADVDIFF,
+    "project_2nd": FOREST_SOLVE,
+    "moments_read": {"UpdateObstacles"},
+    "penalize_bodies": {"Penalization"},
+    "forces_bodies": {"ComputeForces"},
+    "tags": {"AdaptMesh", "AdaptMesh/Halo"},
+    "mega": FOREST_ADVDIFF | FOREST_SOLVE | {
+        "CreateObstacles", "UpdateObstacles", "Penalization",
+        "ComputeForces", "DtPolicy"},
+}
+
+
+@pytest.mark.parametrize("name", sorted(FOREST_SCOPES))
+def test_each_forest_program_carries_the_operators_it_runs(fish_sim, name):
+    """The bodies of ``sim/amr_step.py`` name nothing themselves but the
+    lines that are theirs alone: the scopes come with the functions they
+    share with the uniform driver (``tests/test_scopes.py``), the halo
+    assembly and the flux correction as children wherever they are
+    called, the solve's children under PressureProjection."""
+    from cup3d_tpu.ops.surface import obstacle_probe_budget
+    from tests._cases import scope_paths
+
+    sim, s, obs = fish_sim, fish_sim.state, fish_sim.obstacles
+    h_fine = float(sim.grid.h.min())
+    windows, win = sim._probe_windows()
+    bodies = sim._step_bodies(
+        tuple(obstacle_probe_budget(ob, h_fine) for ob in obs), windows)
+    geo, view_of = sim._geo_args(), sim._view_of()
+    dt = jnp.asarray(sim.dt, sim.dtype)
+    uinf, win = sim.uinf_device(), jnp.asarray(win)
+    chis, udefs, sdfs = (tuple(getattr(ob, k) for ob in obs)
+                         for k in ("chi", "udef", "sdf"))
+    rows = sim._rigid_rows()
+    cms = rows[:, 6:9]
+    calls = {
+        "advdiff": lambda v: bodies.advdiff(s["vel"], dt, uinf, v),
+        "project_2nd": lambda v: bodies.project_2nd(
+            s["vel"], dt, s["chi"], s["udef"], s["p"], v),
+        "moments_read": lambda v: bodies.moments_read(
+            chis, s["vel"], cms, v),
+        "penalize_bodies": lambda v: bodies.penalize_bodies(
+            s["vel"], chis, udefs, rows, dt, sim._lambda_device(), v),
+        "forces_bodies": lambda v: bodies.forces_bodies(
+            s["vel"], s["p"], chis, sdfs, udefs, win, rows, v),
+        "tags": lambda v: bodies.tags(
+            s["vel"], s["chi"], sim._level_arr, v),
+        "mega": lambda v: bodies.mega(
+            s["vel"], s["p"], jnp.stack(chis), jnp.stack(udefs),
+            jnp.stack(sdfs),
+            jnp.stack([ob.rigid_state_dev(sim.dtype) for ob in obs]),
+            jnp.zeros((len(obs), 3), bool), jnp.zeros((len(obs), 3), bool),
+            jnp.asarray([1.0, 0.0], sim.dtype), win, uinf, dt,
+            sim._lambda_device(), v),
+    }
+    got = scope_paths(lambda: calls[name](view_of(geo)))
+    want = FOREST_SCOPES[name]
+    assert want <= got, sorted(want - got)
+    assert ({p.split("/")[0] for p in got}
+            == {p.split("/")[0] for p in want}), sorted(got)
+
+
 def test_fish_kinematics_stay_host_numpy(fish_sim):
     """The forest hands update_shape/update the step as a Python float,
     like the uniform driver (tests/test_create_obstacles_dispatch.py):
@@ -294,6 +370,13 @@ def test_forest_step_dispatch_counts(fish_sim, tmp_path):
         assert programs <= most[0] and uploads <= most[1], (section, counts)
     programs, uploads = counts["advance"]
     assert programs <= 25 and uploads <= 10, counts  # 97 / 38 before
+    # the program's own step annotation is the whole call; its two
+    # blocking reads are annotated where they wait (the moments read
+    # dispatches its program in front of the seam, the pack nothing)
+    assert counts["step"] == counts["advance"], counts
+    assert counts["read:moments-read"] == (0, 0), counts
+    assert counts["read:qoi-read"] == (0, 0), counts
+    print("forest advance dispatches", counts)
 
 
 def parent_chain(sim, ob):

@@ -269,8 +269,8 @@ def test_the_dense_coarse_solve_takes_the_iterations_of_the_loop(rows):
 
 
 def test_a_step_with_no_body_dispatches_what_it_always_did(rows, tmp_path):
-    """One more ``advance()`` with every profiler section a counted span
-    (``tests/_dispatch.py``): the body operators of the forest's per-step
+    """One more ``advance()``, counted between the program's own ``cup3d:``
+    annotations (``tests/_dispatch.py``): the body operators of the forest's per-step
     path are programs of their own since PR 35, and a flow with no body
     runs none of them.  The counts are the parent's."""
     sim = rows["driver"].sim
@@ -279,4 +279,7 @@ def test_a_step_with_no_body_dispatches_what_it_always_did(rows, tmp_path):
     assert counts == {
         "advance": (6, 1), "CreateObstacles": (0, 0),
         "AdvectionDiffusion": (1, 0), "PressureProjection": (1, 0),
-        "SyncQoI": (3, 0)}, counts
+        "SyncQoI": (3, 0),
+        # the program's step annotation is the whole call, and the one
+        # blocking read of the step dispatches nothing: it only waits
+        "step": (6, 1), "read:qoi-read": (0, 0)}, counts

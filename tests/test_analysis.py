@@ -517,13 +517,13 @@ def test_jx012_profiler_outside_obs_fires_suppresses_and_scopes():
 
 def test_jx012_obs_channel_use_is_clean():
     """Going through the obs channel never fires: CONTROLLER windows
-    and sink annotations are the sanctioned path."""
+    and obs.trace.annotate are the sanctioned path."""
     src = (
         "from cup3d_tpu.obs import profile as obs_profile\n"
         "from cup3d_tpu.obs import trace as obs_trace\n"
         "def capture(fn):\n"
         "    with obs_profile.CONTROLLER.capture('bench'):\n"
-        "        ann = obs_trace.TRACE.annotation('Megastep')\n"
+        "        ann = obs_trace.annotate('cup3d:Megastep')\n"
         "        fn()\n"
     )
     assert not any(v.rule == "JX012" for v in _failing(src))
@@ -1331,6 +1331,10 @@ def test_uniform_step_compiles_once_and_runs_transfer_clean(tmp_path):
         # megaloop carry seeding: once per entry into scan mode, never
         # per step (sim/simulation.py advance_megaloop; round 11)
         "scan-carry-upload",
+        # the stream's grouped reads and the adaptation pass's tags go
+        # through the blocking-read seam since PR 38 (pipelined runs,
+        # the forest): sites of other drivers' loops, never this one's
+        "stream-read", "tags-read",
     }
 
 

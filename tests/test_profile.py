@@ -35,24 +35,42 @@ FIXTURE = os.path.join(os.path.dirname(__file__), "data",
 
 
 def test_fixture_attribution_sums_and_sections():
-    """Section attribution over the fixture: every expected logical
-    section lands nonzero device time, the unknown op buckets to
-    ``other``, and the invariant sum(sections)+other == total holds."""
+    """Attribution by scope over the fixture (two steps of a TPU-shaped
+    capture): device self time by outermost operator scope, one level
+    below for the split operators, the op with no scope under ``other``,
+    and the invariant sum(sections)+other == total == busy time."""
     attr = P.attribute(P.load_chrome_trace(FIXTURE), source=FIXTURE)
-    # the round-13 acceptance sections: three BiCGSTAB stages, ring
-    # halo, megaloop body — plus the two annotation-derived sections
-    want = {"bicgstab.update", "bicgstab.getz_lap", "bicgstab.finish",
-            "halo.ring", "megaloop.body", "PoissonSolve",
-            "AdvectionDiffusion"}
-    assert set(attr.sections) == want
-    assert all(v > 0 for v in attr.sections.values())
-    assert attr.other_ms > 0  # unknown_op_xyz
+    assert set(attr.sections) == {"AdvectionDiffusion",
+                                  "PressureProjection"}
+    assert attr.sections["AdvectionDiffusion"] == pytest.approx(2.6)
+    # the while's own 0.3 ms a step + its nested body, counted once
+    assert attr.sections["PressureProjection"] == pytest.approx(7.8)
+    assert attr.other_ms == pytest.approx(1.2)  # copy.9, twice
     assert abs(sum(attr.sections.values()) + attr.other_ms
                - attr.total_ms) < 1e-9
+    assert attr.children("PressureProjection") == pytest.approx(
+        {"PoissonRHS": 0.6, "PoissonSolve": 6.0, "Gradient": 1.2})
+    assert attr.children("AdvectionDiffusion") == pytest.approx(
+        {"self": 1.8, "Halo": 0.8})
+    assert attr.paths[
+        "PressureProjection/PoissonSolve/Preconditioner/TileSolve"
+    ] == pytest.approx(2.4)
     # every device op is bucketed exactly once
-    assert len(attr.events) == 10
-    by_section = [e for e in attr.events if e["section"] is None]
-    assert len(by_section) == 1  # only the unknown op
+    assert len(attr.events) == 18
+    assert [e["name"] for e in attr.events
+            if e["section"] is None] == ["copy.9", "copy.9"]
+    assert attr.programs == {"jit_step(1)": pytest.approx([11.6, 1.2])}
+    # busy + idle = the window, and each idle instant has one owner
+    assert attr.total_ms + sum(attr.gaps.values()) == pytest.approx(
+        attr.window_ms)
+    assert attr.gaps["cup3d:read:qoi-read"] == pytest.approx(1.8)
+    assert attr.gaps["cup3d:CreateObstacles"] == pytest.approx(3.8)
+    assert attr.gaps[P.NO_SPAN] == pytest.approx(1.0)
+    # where the host thread was, device busy or not: each span's self time
+    assert attr.host == pytest.approx({
+        "cup3d:step": 1.0, "cup3d:CreateObstacles": 3.8,
+        "cup3d:AdvectionDiffusion": 0.4, "cup3d:SyncQoI": 0.4,
+        "cup3d:read:qoi-read": 12.4})
 
 
 def test_fixture_matches_generator():
@@ -68,55 +86,115 @@ def test_fixture_matches_generator():
             assert f.read() == checked_in
 
 
-def test_attribute_name_match_beats_temporal_and_unknown_to_other():
-    trace = {"traceEvents": [
-        {"name": "process_name", "ph": "M", "pid": 9, "ts": 0,
-         "args": {"name": "/device:TPU:1"}},
-        {"name": "Sect", "ph": "X", "pid": 1, "tid": 1,
-         "ts": 0.0, "dur": 100.0},
-        # name carries the section even though it sits OUTSIDE the span
-        {"name": "Sect.fusion.3", "ph": "X", "pid": 9, "tid": 0,
-         "ts": 500.0, "dur": 10.0},
-        # no name match, midpoint inside the span -> temporal
-        {"name": "fusion.9", "ph": "X", "pid": 9, "tid": 0,
-         "ts": 40.0, "dur": 10.0},
-        # neither -> other
-        {"name": "mystery", "ph": "X", "pid": 9, "tid": 0,
-         "ts": 900.0, "dur": 5.0},
-    ]}
-    attr = P.attribute(trace)
-    assert attr.sections == {"Sect": 0.02}
-    assert attr.other_ms == pytest.approx(0.005)
-    assert attr.total_ms == pytest.approx(0.025)
-
-
-def test_attribute_cpu_backend_executor_threads_and_frame_spans():
-    """A CPU-backend capture: XLA ops run on tf_XLA* threads of the one
-    /host:CPU process — those count as device streams, while the python
-    thread's $-prefixed profiler frames are neither device ops nor
-    section candidates (a frame span must not swallow ops temporally)."""
+def test_attribute_reads_the_scope_from_the_event_or_joins_the_hlo():
+    """Where the trace carries the op_name (a TPU's) it is read from the
+    event; where it names only module and instruction (the CPU's
+    executor threads) it is joined from the program's optimised HLO
+    text; an op that neither names goes to ``other``."""
+    hlo = (
+        "HloModule jit_step, entry_computation_layout={()->f32[]}\n\n"
+        "ENTRY %main () -> f32[] {\n"
+        '  %fusion.3 = f32[8]{0} fusion(), kind=kLoop, metadata={op_name='
+        '"jit(step)/Penalization/Penalization/mul" source_file="x.py"}\n'
+        '  ROOT %dot.7 = f32[] dot(), metadata={op_name='
+        '"jit(step)/PressureProjection/PoissonSolve/while/body/Dots/'
+        'reduce_sum"}\n}\n')
+    names = P.hlo_op_names(hlo)
+    assert names[("jit_step", "fusion.3")].endswith("Penalization/mul")
     trace = {"traceEvents": [
         {"name": "process_name", "ph": "M", "pid": 3, "ts": 0,
          "args": {"name": "/host:CPU"}},
-        {"name": "thread_name", "ph": "M", "pid": 3, "tid": 10,
-         "ts": 0, "args": {"name": "python"}},
         {"name": "thread_name", "ph": "M", "pid": 3, "tid": 20,
-         "ts": 0, "args": {"name": "tf_XLATfrtCpuClient/12345"}},
-        # python frames: not device time, not section candidates
+         "ts": 0, "args": {"name": "tf_XLAPjRtCpuClient/12345"}},
+        {"name": "fusion.9", "ph": "X", "pid": 3, "tid": 20, "ts": 0.0,
+         "dur": 10.0, "args": {"hlo_op": "fusion.9", "hlo_module": "jit_x",
+                               "tf_op": "jit(x)/ComputeForces/gather"}},
+        {"name": "fusion.3", "ph": "X", "pid": 3, "tid": 20, "ts": 20.0,
+         "dur": 30.0, "args": {"hlo_op": "fusion.3",
+                               "hlo_module": "jit_step"}},
+        {"name": "dot.7", "ph": "X", "pid": 3, "tid": 20, "ts": 60.0,
+         "dur": 5.0, "args": {"hlo_op": "dot.7", "hlo_module": "jit_step"}},
+        {"name": "mystery", "ph": "X", "pid": 3, "tid": 20, "ts": 70.0,
+         "dur": 5.0, "args": {"hlo_op": "mystery", "hlo_module": "jit_y"}},
+        # a python frame is neither a device op nor a span of the program
         {"name": "$contextlib.py", "ph": "X", "pid": 3, "tid": 10,
          "ts": 0.0, "dur": 1000.0},
-        {"name": "PoissonSolve", "ph": "X", "pid": 3, "tid": 10,
-         "ts": 100.0, "dur": 500.0},
-        # executor-thread ops ARE device time
-        {"name": "multiply_reduce_fusion", "ph": "X", "pid": 3,
-         "tid": 20, "ts": 200.0, "dur": 50.0},   # temporal -> span
-        {"name": "dot.7", "ph": "X", "pid": 3, "tid": 20,
-         "ts": 700.0, "dur": 30.0},              # outside span -> other
+    ]}
+    attr = P.attribute(trace, op_names=names)
+    assert attr.sections == {
+        "ComputeForces": pytest.approx(0.010),
+        "Penalization": pytest.approx(0.030),
+        "PressureProjection": pytest.approx(0.005)}
+    assert attr.paths["PressureProjection/PoissonSolve/Dots"] == (
+        pytest.approx(0.005))
+    assert attr.other_ms == pytest.approx(0.005)
+    assert attr.total_ms == pytest.approx(0.050)
+    assert attr.programs["jit_y"] == pytest.approx([0.005, 0.005])
+    # without the join the two ops of jit_step have no owner
+    assert P.attribute(trace).other_ms == pytest.approx(0.040)
+
+
+@pytest.mark.parametrize("op_name, path", [
+    ("jit(megaloop)/while/body/closed_call/PressureProjection/PoissonSolve/"
+     "while/body/Dots/reduce_sum", "PressureProjection/PoissonSolve/Dots"),
+    # a scope entered again right inside itself counts once
+    ("jit(megaloop)/while/body/closed_call/CreateObstacles/"
+     "jit(rasterize_midline)/CreateObstacles/jit(rasterize_points)/"
+     "CreateObstacles/while/body/closed_call/lt", "CreateObstacles"),
+    # the v5e's trace ends an op_name with a colon and may write a nested
+    # computation's whole path again behind its caller's
+    ("jit(project_2nd)/PressureProjection/PoissonSolve/while/body/"
+     "Preconditioner/jit(project_2nd)/PressureProjection/PoissonSolve/"
+     "Preconditioner/Halo/concatenate:",
+     "PressureProjection/PoissonSolve/Preconditioner/Halo"),
+    # a child alone, in a program that is no step (a solve probe)
+    ("jit(solve)/PoissonSolve/while/body/Laplacian/add",
+     "PoissonSolve/Laplacian"),
+    ("jit(multiply)/mul", ""), ("", ""),
+])
+def test_scope_path_reads_the_vocabulary_out_of_an_op_name(op_name, path):
+    assert "/".join(P.scope_path(op_name)) == path
+
+
+def test_attribute_gives_each_idle_instant_to_the_innermost_span_open():
+    """The device idles from 100 to 900 us: the part under the blocking
+    read is the read's, the rest of the section's wall the section's,
+    what no section covers the step's, and what follows the step
+    nobody's."""
+    trace = {"traceEvents": [
+        {"name": "process_name", "ph": "M", "pid": 9, "ts": 0,
+         "args": {"name": "/device:TPU:1"}},
+        {"name": "thread_name", "ph": "M", "pid": 9, "tid": 0, "ts": 0,
+         "args": {"name": "XLA Ops"}},
+        {"name": "thread_name", "ph": "M", "pid": 9, "tid": 1, "ts": 0,
+         "args": {"name": "Steps"}},
+        {"name": "cup3d:step", "ph": "X", "pid": 1, "tid": 1,
+         "ts": 0.0, "dur": 700.0},
+        {"name": "cup3d:SyncQoI", "ph": "X", "pid": 1, "tid": 1,
+         "ts": 200.0, "dur": 400.0},
+        {"name": "cup3d:read:qoi-read", "ph": "X", "pid": 1, "tid": 1,
+         "ts": 250.0, "dur": 300.0},
+        {"name": "bench:advance", "ph": "X", "pid": 1, "tid": 1,
+         "ts": 0.0, "dur": 1000.0},  # not the program's: no owner
+        {"name": "fusion.1", "ph": "X", "pid": 9, "tid": 0,
+         "ts": 0.0, "dur": 100.0,
+         "args": {"tf_op": "jit(a)/AdvectionDiffusion/add"}},
+        {"name": "fusion.2", "ph": "X", "pid": 9, "tid": 0,
+         "ts": 900.0, "dur": 100.0,
+         "args": {"tf_op": "jit(b)/DtPolicy/reduce_max"}},
+        # another line of the device plane is no operation
+        {"name": "7", "ph": "X", "pid": 9, "tid": 1,
+         "ts": 0.0, "dur": 1000.0},
     ]}
     attr = P.attribute(trace)
-    assert attr.total_ms == pytest.approx(0.08)
-    assert attr.sections == {"PoissonSolve": pytest.approx(0.05)}
-    assert attr.other_ms == pytest.approx(0.03)
+    assert attr.total_ms == pytest.approx(0.2)
+    assert attr.window_ms == pytest.approx(1.0)
+    assert attr.gaps == {
+        "cup3d:step": pytest.approx(0.2),            # 100-200, 600-700
+        "cup3d:SyncQoI": pytest.approx(0.1),         # 200-250, 550-600
+        "cup3d:read:qoi-read": pytest.approx(0.3),   # 250-550
+        P.NO_SPAN: pytest.approx(0.2),               # 700-900
+    }
 
 
 def test_parse_plan_specs_and_bad_plan_counted():
@@ -211,7 +289,10 @@ def test_harvest_merges_fixture_into_sink(tmp_path):
     assert len(dev) == 1 and dev[0]["step"] == 10
     assert dev[0]["window"] == [8, 10]
     assert T.validate_step_record(dev[0]) == []
-    assert dev[0]["device_sections"]["halo.ring"] > 0
+    assert dev[0]["device_sections"]["PressureProjection"] > 0
+    assert dev[0]["idle_gaps_ms"]["cup3d:read:qoi-read"] > 0
+    assert snap["profile.idle_ms{span=cup3d:read:qoi-read}"] == (
+        pytest.approx(attr.gaps["cup3d:read:qoi-read"]))
     pf = json.load(open(tmp_path / "trace.pfto.json"))
     dev_ops = [e for e in pf["traceEvents"]
                if e.get("pid") == P.DEVICE_PID and e["ph"] == "X"]
