@@ -405,6 +405,7 @@ def _static_signature(drv, kind: str) -> tuple:
             float(ob.length),
             bool(ob.bFixFrameOfRef),
             tuple(int(v) for v in ob._window_shape),
+            ob._raster_box,
             tuple(np.asarray(ob.forced_mask_dev()).astype(float).tolist()),
             tuple(np.asarray(ob.block_mask_dev()).astype(float).tolist()),
             float(drv.cfg.DLM),

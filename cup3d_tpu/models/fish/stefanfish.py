@@ -31,8 +31,14 @@ from cup3d_tpu.models.base import (
     quat_to_rot,
 )
 from cup3d_tpu.models.fish.curvature import CurvatureDefinedFishData
-from cup3d_tpu.models.fish.rasterize import rasterize_midline, rasterize_points
+from cup3d_tpu.models.fish.rasterize import (
+    raster_box,
+    raster_work,
+    rasterize_midline,
+    rasterize_points,
+)
 from cup3d_tpu.models.fish.shapes import compute_widths_heights
+from cup3d_tpu.obs import metrics as obs_metrics
 from cup3d_tpu.ops.chi import heaviside
 
 
@@ -77,11 +83,11 @@ _raster_blocks = jax.jit(raster_blocks)
 
 
 @jax.named_scope("CreateObstacles")
-def _raster_window(pack, frame, grid, window_shape):
+def _raster_window(pack, frame, grid, window_shape, box):
     """Window snap + midline rasterization + dense placement, traced.
     ``frame`` None: the pack's last row carries the host mirrors' frame
-    (``StefanFish._dense_inputs``).  The window half-width and ``h`` are
-    trace-time constants."""
+    (``StefanFish._dense_inputs``).  The window half-width, the body's
+    raster box and ``h`` are trace-time constants."""
     dtype = pack.dtype
     if frame is None:
         pack, frame = pack[:-1], pack[-1, :FRAME]
@@ -97,7 +103,7 @@ def _raster_window(pack, frame, grid, window_shape):
     origin = idx0.astype(dtype) * h
     starts = (idx0[0], idx0[1], idx0[2])
     sdf_w, udef_w = rasterize_midline(
-        origin, h, window_shape, _split_midline(pack), pos, rot,
+        origin, h, window_shape, box, _split_midline(pack), pos, rot,
     )
     sdf = jnp.full(grid.shape, -1.0, dtype)
     sdf = jax.lax.dynamic_update_slice(sdf, sdf_w, starts)
@@ -106,17 +112,17 @@ def _raster_window(pack, frame, grid, window_shape):
     return sdf, udef
 
 
-_raster_window_dense = jax.jit(_raster_window,
-                               static_argnames=("grid", "window_shape"))
+_raster_window_dense = jax.jit(
+    _raster_window, static_argnames=("grid", "window_shape", "box"))
 
 
-@partial(jax.jit, static_argnames=("grid", "window_shape", "combine"))
-def _create_dense(pack, frame, grid, window_shape, combine):
+@partial(jax.jit, static_argnames=("grid", "window_shape", "box", "combine"))
+def _create_dense(pack, frame, grid, window_shape, box, combine):
     """CreateObstacles for one fish on the dense grid as ONE program: from
     the step's single upload to (sdf, chi, udef, combined) — rasterizer,
     ghost padding, Towers chi, band mask and, for a fish alone on the
     grid, the combine (``models/base.fields_from_sdf``)."""
-    sdf, udef = _raster_window(pack, frame, grid, window_shape)
+    sdf, udef = _raster_window(pack, frame, grid, window_shape, box)
     return (sdf,) + fields_from_sdf(grid, sdf, udef, combine)
 
 
@@ -167,10 +173,14 @@ class StefanFish(Obstacle):
 
         # dense uniform layout: a static rasterization window (the deformed
         # fish stays within ~0.6 L of its center; margin for the mollified
-        # band).  Block layout: candidate blocks are found per call.
+        # band) and the box each group of segments is evaluated in.  Block
+        # layout: candidate blocks are found per call.
         if not self._is_blocks:
             nw = int(np.ceil(1.25 * self.length / h)) + 8
             self._window_shape = tuple(min(nw, n) for n in sim.grid.shape)
+            self._raster_box = raster_box(
+                self.myFish.width, self.myFish.height, self.myFish.rS, h,
+                self._window_shape)
 
     # -- geometry pipeline (Fish::create, main.cpp:10952-10958) ------------
 
@@ -323,16 +333,28 @@ class StefanFish(Obstacle):
         if self._is_blocks:
             return self._rasterize_blocks(t)
         return _raster_window_dense(
-            *self._dense_inputs(), self.sim.grid, self._window_shape
+            *self._dense_inputs(), self.sim.grid, self._window_shape,
+            self._raster_box,
         )
 
     def create(self, t: float, combine: bool = False):
         """``Obstacle.create`` with the rasterizer inside the program."""
         self.sdf, self.chi, self.udef, combined = _create_dense(
             *self._dense_inputs(), self.sim.grid, self._window_shape,
-            combine,
+            self._raster_box, combine,
         )
+        self.note_raster_work(1)
         return combined
+
+    def note_raster_work(self, calls: int) -> None:
+        """Raise ``operators.raster_cells`` (cells the boxed rasterizer
+        evaluated) and ``operators.raster_sweep_cells`` (what the full
+        sweep of the window would have) for ``calls`` dense-window
+        rasterizations of this body."""
+        cells, sweep = raster_work(self.myFish.Nm, self._window_shape,
+                                   self._raster_box)
+        obs_metrics.counter("operators.raster_cells").inc(calls * cells)
+        obs_metrics.counter("operators.raster_sweep_cells").inc(calls * sweep)
 
     # -- rigid-body override: roll correction ------------------------------
 

@@ -157,6 +157,7 @@ def _fish_stages(s, ob):
     n = np.asarray(grid.shape)
     grid_shape = tuple(int(v) for v in n)
     window_shape = tuple(ob._window_shape)
+    box = ob._raster_box
     half_win = jnp.asarray(0.5 * np.asarray(window_shape) * h, dtype)
     lim_win = jnp.asarray(n - np.asarray(window_shape), jnp.int32)
     wp = int(min(window_size_cells(ob.length, h), n.min()))
@@ -181,8 +182,8 @@ def _fish_stages(s, ob):
         idx0 = jnp.clip(jnp.floor((pos - half_win) / hd).astype(jnp.int32),
                         0, lim_win)
         origin = idx0.astype(dtype) * hd
-        sdf_w, udef_w = rasterize_midline(origin, hd, window_shape, mid,
-                                          pos, rot)
+        sdf_w, udef_w = rasterize_midline(origin, hd, window_shape, box,
+                                          mid, pos, rot)
         sdf = jnp.full(grid_shape, -1.0, dtype)
         sdf = jax.lax.dynamic_update_slice(
             sdf, sdf_w, (idx0[0], idx0[1], idx0[2]))

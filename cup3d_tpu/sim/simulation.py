@@ -520,6 +520,8 @@ class Simulation:
             with s.profiler("Megaloop"):
                 carry, rows = fn(self._scan_carry, cfl_dev)
             obs_metrics.counter("megaloop.dispatches").inc()
+            if s.obstacles:  # the single-fish body rasterizes every step
+                s.obstacles[0].note_raster_work(K)
             self._scan_carry = carry
             # the megaloop donates its carry: rebind the field state to
             # the carried arrays so dumps/snapshots/fallback see live

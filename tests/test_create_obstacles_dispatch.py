@@ -132,7 +132,7 @@ def parent_chain(s, ob):
         jnp.asarray(np.asarray(grid.shape) - np.asarray(window), jnp.int32))
     starts = (idx0[0], idx0[1], idx0[2])
     sdf_w, udef_w = rasterize_midline(idx0.astype(dtype) * h, h, window,
-                                      mid, pos, rot)
+                                      ob._raster_box, mid, pos, rot)
     sdf = jax.lax.dynamic_update_slice(
         jnp.full(grid.shape, -1.0, dtype), sdf_w, starts)
     udef = jax.lax.dynamic_update_slice(

@@ -203,6 +203,22 @@ def test_each_force_row_is_counted_against_its_probes_budget(cell, paths):
     assert read({"obs": {"megaloop.dispatches": 2}}) is None
 
 
+def test_the_rasterizer_counts_its_cells_against_the_sweep(cell, paths):
+    """``operators.raster_cells`` / ``operators.raster_sweep_cells`` rise
+    once per per-step CreateObstacles (3 warm-up steps + the checked one)
+    and K times per scan dispatch (the warm-up's and the checked unit's):
+    the reader makes the box's share of the window's sweep of that, the
+    same on both paths, and nothing of a program without the counters."""
+    step, scan = paths("step")["obs"], paths("scan")["unit"]
+    for name in ("operators.raster_cells", "operators.raster_sweep_cells"):
+        assert scan[name] == 2 * K * step[name] / 4, name
+    read = spec.load_reader(cell["bench"], "operators.raster_work_share").read
+    share = read({"obs": step})
+    assert 0 < share < 100 and read({"obs": scan}) == share
+    assert read({"obs": {}}) is None
+    assert read({"obs": {"megaloop.dispatches": 2}}) is None
+
+
 def test_an_overflowing_row_is_counted_as_truncated(cell):
     """The sink alone: a row whose n_surf is over the slot budget of the
     body's probe counts as truncated, one at most the budget as

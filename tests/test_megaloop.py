@@ -118,7 +118,8 @@ def test_device_midline_chi_udef_matches_host(tmp_path):
             .astype(np.int64), 0, lim_win)
         origin = jnp.asarray(idx0 * h, s.dtype)
         sdf_w, udef_w = rasterize_midline(
-            origin, jnp.asarray(h, s.dtype), window_shape, mid, pos, rot)
+            origin, jnp.asarray(h, s.dtype), window_shape, ob._raster_box,
+            mid, pos, rot)
         sdf = jnp.full(grid_shape, -1.0, s.dtype)
         sdf = jax.lax.dynamic_update_slice(
             sdf, sdf_w, tuple(int(v) for v in idx0))

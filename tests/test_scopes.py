@@ -92,7 +92,8 @@ def _uniform_program(driver, name):
             {"UpdateObstacles"})
     if name == "CreateObstacles":
         return (lambda: stefanfish._create_dense(
-            *ob._dense_inputs(), s.grid, ob._window_shape, True),
+            *ob._dense_inputs(), s.grid, ob._window_shape, ob._raster_box,
+            True),
             {"CreateObstacles", "CreateObstacles/Halo"})
     if name == "ComputeForces":
         return (lambda: force_integrals_probe_uniform(
