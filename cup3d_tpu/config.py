@@ -95,7 +95,9 @@ class SimulationConfig:
     # -- forcing (main.cpp:15372-15377) --
     uMax_forced: float = 0.0
     bFixMassFlux: bool = False
-    initCond: str = "zero"  # zero | taylorGreen | channel
+    # zero | taylorGreen | vorticity | channel | turbulentChannel
+    initCond: str = "zero"
+    initSeed: int = 0  # the seed of turbulentChannel's perturbation
 
     # -- obstacles --
     factory_content: str = ""

@@ -57,13 +57,23 @@ from cup3d_tpu.ops.penalization import (
 )
 from cup3d_tpu.ops.projection import project
 from cup3d_tpu.sim import dtpolicy
+from cup3d_tpu.sim.operators import forced, forcing_stage
 
 # QoI row layouts.  Fish: rigid pack 0:29 | penal force/torque 29:35 |
 # force probe pack 35:52 | [residual, iterations] 52:54 | internal
 # quaternion 54:58 | umax 58 | dt 59 | time 60.
 FISH_ROW = 61
-# TGV (obstacle-free): [residual, iterations] 0:2 | umax 2 | dt 3 | time 4.
+# TGV (obstacle-free): [residual, iterations] 0:2 | umax 2 | dt 3 | time 4;
+# a forced flow's row holds the bulk velocity its forcing measured at
+# TGV_BULK, before (umax, dt, time), which stay the row's last three
+# columns in every layout (tgv_row_width).
 TGV_ROW = 5
+TGV_BULK = 2
+
+
+def tgv_row_width(cfg) -> int:
+    """Width of the obstacle-free scan body's row for this flow."""
+    return TGV_ROW + int(forced(cfg))
 
 DEFAULT_SCAN_K = 8
 
@@ -229,22 +239,31 @@ def _umax_with_body(vel, uinf, udef):
 
 def make_tgv_step(s):
     """The obstacle-free scan body as a pure function
-    ``one_step(carry, cfl_eff) -> (carry', row (TGV_ROW,))``.  All grid /
-    solver / uinf statics are frozen in the closure; the function has no
-    leading batch axis, so fleet/batch.py can ``vmap`` it over a scenario
-    axis unchanged (the lane independence the fleet isolation contract
-    relies on: no cross-lane reduction anywhere in the body)."""
+    ``one_step(carry, cfl_eff) -> (carry', row (tgv_row_width,))``.  All
+    grid / solver / uinf statics are frozen in the closure; the function
+    has no leading batch axis, so fleet/batch.py can ``vmap`` it over a
+    scenario axis unchanged (the lane independence the fleet isolation
+    contract relies on: no cross-lane reduction anywhere in the body; a
+    fleet scenario cannot be forced, so its rows keep TGV_ROW).  A forced
+    flow runs the per-step path's own forcing stage
+    (``sim/operators.forcing_stage``) between advection-diffusion and
+    the projection, and its row carries the measured bulk velocity."""
     grid, nu, dtype = s.grid, s.nu, s.dtype
     h = float(grid.h)
     solver = s.poisson_solver
     with_stats = bool(getattr(solver, "supports_stats", False))
     uinf = s.uinf_device()
+    forcing = forcing_stage(s)
 
     def one_step(carry, cfl_eff):
         vel, p = carry["vel"], carry["p"]
         umax, time, dtprev = carry["umax"], carry["time"], carry["dt"]
         dt = dtpolicy.dt_scan(cfl_eff, h, nu, umax, dtprev)
         vel = rk3_step(grid, vel, dt, nu, uinf)
+        bulk = []
+        if forcing is not None:
+            vel, u_bulk = forcing(vel, uinf, dt)
+            bulk = [jnp.asarray(u_bulk, dtype)[None]]
         if with_stats:
             vel, p, stats = project(grid, vel, dt, solver, p_init=p,
                                     with_stats=True)
@@ -256,7 +275,7 @@ def make_tgv_step(s):
         time_new = time + dt
         out = {"vel": vel, "p": p, "umax": umax_new, "time": time_new,
                "dt": dt}
-        row = jnp.concatenate([stats, umax_new[None], dt[None],
+        row = jnp.concatenate([stats, *bulk, umax_new[None], dt[None],
                                time_new[None]])
         return out, row
 
@@ -264,8 +283,8 @@ def make_tgv_step(s):
 
 
 def build_tgv_megaloop(s):
-    """jitted (carry, cfl_eff (K,)) -> (carry', rows (K, TGV_ROW)) for the
-    obstacle-free uniform pipeline.  The carry is DONATED."""
+    """jitted (carry, cfl_eff (K,)) -> (carry', rows (K, tgv_row_width))
+    for the obstacle-free uniform pipeline.  The carry is DONATED."""
     one_step = make_tgv_step(s)
 
     def megaloop(carry, cfl_eff):
@@ -411,10 +430,16 @@ def make_tgv_step_sharded(s, axis="x"):
     as make_tgv_step; vel/p arrive as the local (nx/D, ny, nz[, 3])
     slabs.  RK3 and the divergence read ring-padded slabs; the Poisson
     solve runs replicated on the gathered rhs and each shard slices its
-    own pressure slab (and its sx+2 gradient window) back out."""
+    own pressure slab (and its sx+2 gradient window) back out.  It has
+    no forcing stage: a forced flow raises rather than run unforced."""
     from cup3d_tpu.ops import stencils as st
     from cup3d_tpu.parallel import collectives as coll
     from cup3d_tpu.parallel import ring as _ring
+
+    if forced(s.cfg):
+        raise NotImplementedError(
+            "CUP3D_MESH_X: the x-slab megaloop has no forcing stage "
+            "(-bFixMassFlux / -uMax_forced); run the solo scan")
 
     grid, nu, dtype = s.grid, s.nu, s.dtype
     h = float(grid.h)

@@ -138,10 +138,12 @@ class Simulation:
             # done-by-time needs a fresh s.time every step; inside the
             # scan the host time mirror lags by up to the stream window
             return False
-        if cfg.uMax_forced > 0 or cfg.bFixMassFlux or cfg.freqDiagnostics:
-            return False  # forcing/diagnostics operators are per-step
+        if cfg.freqDiagnostics:
+            return False  # the diagnostics operators are per-step
         if not s.obstacles:
-            return True
+            return True  # forced or not: make_tgv_step forces in the scan
+        if ops.forced(cfg):
+            return False  # the fish scan body has no forcing stage
         if len(s.obstacles) != 1:
             return False
         from cup3d_tpu.models.fish.device_midline import (
@@ -184,7 +186,7 @@ class Simulation:
             else:
                 fn = (ml.build_tgv_megaloop(s) if mesh is None
                       else ml.build_tgv_megaloop_sharded(s, mesh))
-                row_w = ml.TGV_ROW
+                row_w = ml.tgv_row_width(self.cfg)
             if fn is None:
                 # gait not freezable after all: scan off for the run
                 self._scan_k = 0
@@ -522,6 +524,8 @@ class Simulation:
             obs_metrics.counter("megaloop.dispatches").inc()
             if s.obstacles:  # the single-fish body rasterizes every step
                 s.obstacles[0].note_raster_work(K)
+            elif ops.forced(cfg):  # make_tgv_step forces every step
+                obs_metrics.counter("operators.flux_scan_steps").inc(K)
             self._scan_carry = carry
             # the megaloop donates its carry: rebind the field state to
             # the carried arrays so dumps/snapshots/fallback see live
@@ -621,7 +625,9 @@ class Simulation:
         forces, surface forces, solver stats, umax/dt/t — so the host
         mirrors, force logs, flight ring and failure detection see the
         SAME per-step sequence the per-step path produces, K steps
-        late (row layouts: sim/megaloop.py FISH_ROW / TGV_ROW)."""
+        late (row layouts: sim/megaloop.py FISH_ROW / TGV_ROW; a forced
+        flow's bulk velocity at TGV_BULK feeds flux.txt as FixMassFlux
+        writes it per step)."""
         from cup3d_tpu.models.base import (
             log_forces, store_force_qoi, unpack_forces,
         )
@@ -629,7 +635,7 @@ class Simulation:
 
         s, cfg = self.sim, self.cfg
         ob = s.obstacles[0] if s.obstacles else None
-        row_w = ml.FISH_ROW if ob is not None else ml.TGV_ROW
+        row_w = ml.FISH_ROW if ob is not None else ml.tgv_row_width(cfg)
         rows = seg.reshape(-1, row_w)
         base_step = int(entry.get("step", s.step))
         for k in range(rows.shape[0]):
@@ -641,8 +647,12 @@ class Simulation:
                                    float(row[60]))
             else:
                 resid, iters = float(row[0]), float(row[1])
-                umax, dt_k, t_k = (float(row[2]), float(row[3]),
-                                   float(row[4]))
+                umax, dt_k, t_k = (float(row[-3]), float(row[-2]),
+                                   float(row[-1]))
+                if cfg.bFixMassFlux:
+                    s.logger.write("flux.txt", ops.flux_line(
+                        step_k, s.time, float(row[ml.TGV_BULK]),
+                        ops.bulk_target(cfg)))
             # fault seams replay PER STEP at consumption: the injected
             # poisons land on the host copies, so the whole detection
             # -> trigger -> rollback chain runs exactly as it does on a
