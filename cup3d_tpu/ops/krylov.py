@@ -808,6 +808,8 @@ def bicgstab(
     tol_rel: float = 1e-4,
     maxiter: int = 1000,
     rnorm_ref=None,
+    r0=None,
+    rnorm0=None,
 ):
     """Preconditioned BiCGSTAB with breakdown re-seeding and best-x tracking
     (the reference's solve loop, main.cpp:14449-14604).  Returns
@@ -823,6 +825,11 @@ def bicgstab(
     VERDICT r2 item 4).  Callers with a warm start pass the cold system's
     RHS norm so the solve targets the same absolute quality as a cold
     solve and a good start can only reduce iterations.
+
+    ``r0`` is the initial residual b - A x0 where the caller has formed
+    it already, and ``rnorm0`` its norm: A is then applied, and the norm
+    taken, only inside the loop (build_iterative_solver forms both on the
+    natural grid and iterates on the increment from x0 = 0).
     """
     if M is None:
         M = lambda r: r
@@ -838,8 +845,10 @@ def bicgstab(
     # the rho re-seed below (round-12 mixed-precision audit)
     eps = jnp.asarray(1e-30, jnp.promote_types(b.dtype, jnp.float32))
 
-    r0 = b - apply_A(x0)
-    rnorm0 = jnp.sqrt(dot(r0, r0))
+    if r0 is None:
+        r0 = b - apply_A(x0)
+    if rnorm0 is None:
+        rnorm0 = jnp.sqrt(dot(r0, r0))
     ref = rnorm0 if rnorm_ref is None else rnorm_ref
     target = jnp.maximum(tol_abs, tol_rel * ref)
     one = jnp.asarray(1.0, b.dtype)
@@ -940,8 +949,21 @@ def build_iterative_solver(
     cell (0,0,0).  The pinned-row RHS is zeroed like the reference's
     solve loop (main.cpp:14404-14407).
 
-    The solve runs in the lane-resident tile layout (to_lanes /
+    The iteration runs in the lane-resident tile layout (to_lanes /
     make_laplacian_lanes): one transpose in, one out, none per iteration.
+    Without a pinned row (``mean_constraint`` 0 and 2) the entry is the
+    increment form: b, its norm and r0 = b - A x0 are formed on the
+    natural grid (make_laplacian, the same operator), r0 goes into the
+    lanes layout, BiCGSTAB iterates on the increment d from d = 0, and
+    x = x0 + d comes back on the natural grid.  In the lanes layout the
+    compiler fused the set-up's norm into the transposes of b and x0 and
+    gave its stencil the transposed tile layout, whose minor dimension of
+    8 pads to the 128-wide lanes: on one v5e at 256^3 that norm alone
+    took 14.2 ms a step, ~170x its bytes (PERF.md section 6).
+    The pinned rows of 1 and 3 exist only on the lanes operator: those
+    modes keep the composed entry (b and x0 transposed, r0 formed in
+    the loop's layout).  ``solve.entry`` names which ("increment",
+    "composed"); the drivers count solves by it (sim/operators.py).
 
     ``two_level`` overrides the CUP3D_COARSE env default for the
     preconditioner choice (None = :func:`use_coarse_correction`): the
@@ -1026,33 +1048,58 @@ def build_iterative_solver(
 
         solve.supports_stats = True
         solve.maxiter = maxiter
+        solve.entry = "composed"
         return solve
 
-    @jax.named_scope("PoissonSolve")
-    def solve(rhs: jnp.ndarray, x0: Optional[jnp.ndarray] = None,
-              with_stats: bool = False):
-        if mean_constraint == 2:
-            b = rhs - jnp.mean(rhs)
-        else:
-            b = rhs
-        bt = to_lanes(b, precond_bs)
-        if mean_constraint in (1, 3):
-            bt = bt.at[0, 0, 0, 0].set(0.0)
-        x0t = None if x0 is None else to_lanes(x0, precond_bs)
-        # rel tolerance always references the cold system's RHS norm so a
-        # warm start can only reduce iterations (see bicgstab docstring)
-        xt, rnorm, k = bicgstab(
-            A, bt, M=M, x0=x0t, tol_abs=tol_abs, tol_rel=tol_rel,
-            maxiter=maxiter, rnorm_ref=jnp.sqrt(_dot(bt, bt)),
-        )
-        x = from_lanes(xt, rhs.shape)
-        x = x - jnp.mean(x) if mean_constraint == 2 else x
-        if with_stats:
-            # (final residual norm, iterations) as one device vector —
-            # drivers pack it onto the async QoI read so per-step solver
-            # telemetry costs ZERO extra syncs (obs/trace.py)
-            return x, solver_stats(rnorm, k)
-        return x
+    if mean_constraint in (1, 3):
+        @jax.named_scope("PoissonSolve")
+        def solve(rhs: jnp.ndarray, x0: Optional[jnp.ndarray] = None,
+                  with_stats: bool = False):
+            bt = to_lanes(rhs, precond_bs).at[0, 0, 0, 0].set(0.0)
+            x0t = None if x0 is None else to_lanes(x0, precond_bs)
+            # rel tolerance always references the cold system's RHS norm
+            # so a warm start can only reduce iterations (see bicgstab)
+            xt, rnorm, k = bicgstab(
+                A, bt, M=M, x0=x0t, tol_abs=tol_abs, tol_rel=tol_rel,
+                maxiter=maxiter, rnorm_ref=jnp.sqrt(_dot(bt, bt)),
+            )
+            x = from_lanes(xt, rhs.shape)
+            if with_stats:
+                return x, solver_stats(rnorm, k)
+            return x
+
+        solve.entry = "composed"
+    else:
+        lap = make_laplacian(grid)
+
+        @jax.named_scope("PoissonSolve")
+        def solve(rhs: jnp.ndarray, x0: Optional[jnp.ndarray] = None,
+                  with_stats: bool = False):
+            b = rhs - jnp.mean(rhs) if mean_constraint == 2 else rhs
+            # rel tolerance always references the cold system's RHS norm
+            # so a warm start can only reduce iterations (see bicgstab)
+            rnorm_ref = jnp.sqrt(_dot(b, b))
+            if x0 is None:
+                r0, rnorm0 = b, rnorm_ref
+            else:
+                r0 = b - lap(x0)
+                rnorm0 = jnp.sqrt(_dot(r0, r0))
+            rt = to_lanes(r0, precond_bs)
+            d, rnorm, k = bicgstab(
+                A, rt, M=M, tol_abs=tol_abs, tol_rel=tol_rel,
+                maxiter=maxiter, rnorm_ref=rnorm_ref, r0=rt, rnorm0=rnorm0,
+            )
+            x = from_lanes(d, rhs.shape)
+            x = x if x0 is None else x0 + x
+            x = x - jnp.mean(x) if mean_constraint == 2 else x
+            if with_stats:
+                # (final residual norm, iterations) as one device vector —
+                # drivers pack it onto the async QoI read so per-step
+                # solver telemetry costs ZERO extra syncs (obs/trace.py)
+                return x, solver_stats(rnorm, k)
+            return x
+
+        solve.entry = "increment"
 
     solve.supports_stats = True
     solve.maxiter = maxiter
@@ -1106,4 +1153,5 @@ def _build_iterative_solver_dense(
 
     solve.supports_stats = True
     solve.maxiter = maxiter
+    solve.entry = "composed"
     return solve

@@ -183,6 +183,17 @@ def flux_line(step: int, time: float, u_msr: float, target: float) -> str:
     return f"{step} {time:.8e} {u_msr:.8e} {target:.8e}\n"
 
 
+def note_poisson_solves(solver, n: int) -> None:
+    """Raise ``poisson.<entry>_solves`` by ``n`` dispatched solves of a
+    solver that names its entry (krylov.build_iterative_solver:
+    ``increment`` or ``composed``); nothing for one that names none (the
+    spectral solve).  The per-step projection raises it once a call, the
+    scan ``scan_k`` times a dispatch."""
+    entry = getattr(solver, "entry", None)
+    if entry:
+        obs_metrics.counter(f"poisson.{entry}_solves").inc(n)
+
+
 class PressureProjection(Operator):
     """RHS -> Poisson solve -> velocity correction (main.cpp:15061-15160).
 
@@ -220,6 +231,7 @@ class PressureProjection(Operator):
         out = self._project(
             s.state["vel"], s.state["chi"], s.state["udef"], dt, s.state["p"]
         )
+        note_poisson_solves(s.poisson_solver, 1)
         if self._with_stats:
             vel, p, stats = out
             s.pending_parts.append(("psolve", stats))

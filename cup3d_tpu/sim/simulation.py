@@ -522,6 +522,7 @@ class Simulation:
             with s.profiler("Megaloop"):
                 carry, rows = fn(self._scan_carry, cfl_dev)
             obs_metrics.counter("megaloop.dispatches").inc()
+            ops.note_poisson_solves(s.poisson_solver, K)
             if s.obstacles:  # the single-fish body rasterizes every step
                 s.obstacles[0].note_raster_work(K)
             elif ops.forced(cfg):  # make_tgv_step forces every step

@@ -161,6 +161,20 @@ def test_one_per_step_call_counts_one_host_flux_step_and_one_read(paths):
     assert unit["transfers.sanctioned{site=flux-read}"] == 1
 
 
+@pytest.mark.parametrize("path", ["step", "scan"])
+def test_each_walled_solve_is_counted_as_an_increment_solve(cell, paths,
+                                                             path):
+    """The walled solve takes the increment entry on both paths: once for
+    the per-step call, ``check_unit_steps`` times for the dispatch."""
+    got = paths(path)
+    moved = got["obs" if path == "step" else "unit"]
+    want = 1 if path == "step" else cell["traffic"]["check_unit_steps"]
+    assert moved["poisson.increment_solves"] == want
+    assert not moved.get("poisson.composed_solves")
+    read = spec.load_reader(cell["bench"], "poisson.increment_share").read
+    assert read({"obs": moved}) == 100.0
+
+
 def _small(tmp_path, **over):
     flags = {"bpdx": 2, "bpdy": 2, "bpdz": 2, "extent": 1, "BC_y": "wall",
              "nu": 1e-2, "uMax_forced": 1.5, "bFixMassFlux": 1,
@@ -212,6 +226,7 @@ def test_a_k2_dispatch_counts_its_forced_steps(tmp_path):
     assert unit["megaloop.dispatches"] == 1
     assert unit["operators.flux_scan_steps"] == 2
     assert unit.get("operators.flux_host_steps", 0) == 0
+    assert unit["poisson.increment_solves"] == 2
     lines = open(tmp_path / "flux.txt").read().splitlines()
     assert [int(line.split()[0]) for line in lines] == [0, 1]
 

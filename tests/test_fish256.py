@@ -219,6 +219,25 @@ def test_the_rasterizer_counts_its_cells_against_the_sweep(cell, paths):
     assert read({"obs": {"megaloop.dispatches": 2}}) is None
 
 
+def test_each_solve_is_counted_by_its_entry(cell, paths):
+    """``poisson.increment_solves`` rises once per per-step projection (3
+    warm-up steps + the checked one) and K times per scan dispatch (the
+    warm-up's and the checked unit's); no solve takes the composed
+    entry.  The reader makes 100 of either path, and nothing of a
+    program without the counters."""
+    step, scan = paths("step")["obs"], paths("scan")["unit"]
+    assert step["poisson.increment_solves"] == 4
+    assert scan["poisson.increment_solves"] == 2 * K
+    read = spec.load_reader(cell["bench"], "poisson.increment_share").read
+    for moved in (step, scan):
+        assert not moved.get("poisson.composed_solves")
+        assert read({"obs": moved}) == 100.0
+    assert read({"obs": {}}) is None
+    assert read({"obs": {"megaloop.dispatches": 2}}) is None
+    assert read({"obs": {"poisson.increment_solves": 1,
+                         "poisson.composed_solves": 3}}) == 25.0
+
+
 def test_an_overflowing_row_is_counted_as_truncated(cell):
     """The sink alone: a row whose n_surf is over the slot budget of the
     body's probe counts as truncated, one at most the budget as

@@ -288,3 +288,119 @@ def test_mean_constraint_pinned_paths(mc, monkeypatch):
         assert abs(float(p[0, 0, 0])) < 1e-4
     else:
         assert abs(float(p.mean())) < 1e-4
+
+
+# -- the solve's entry: increment form on the natural grid --------------------
+
+ENTRY_GRIDS = {
+    "periodic32": UniformGrid((32, 32, 32), (1.0, 1.0, 1.0)),
+    # walls in y, as the channel has them
+    "wall_y48x16x24": UniformGrid((48, 16, 24), (3.0, 1.0, 1.5),
+                                  (BC.periodic, BC.wall, BC.periodic)),
+}
+
+
+def _composed_solve(g, rhs, x0):
+    """The lanes entry as it was composed before the increment form: b
+    and x0 transposed, the norm of b and r0 = b - A x0 taken in the
+    lanes layout, the iterate transposed back."""
+    A = krylov.make_laplacian_lanes(g)
+    M = krylov.make_twolevel_preconditioner_lanes(g, g.h * g.h)
+    bt = krylov.to_lanes(rhs - jnp.mean(rhs))
+    x0t = None if x0 is None else krylov.to_lanes(x0)
+    xt, rnorm, k = krylov.bicgstab(A, bt, M=M, x0=x0t,
+                                   rnorm_ref=jnp.sqrt(krylov._dot(bt, bt)))
+    x = krylov.from_lanes(xt, rhs.shape)
+    return x - jnp.mean(x), krylov.solver_stats(rnorm, k)
+
+
+def _entry_problem(g):
+    """A mean-free pressure, its right-hand side, and a warm start off it
+    by a twentieth of its size."""
+    rng = np.random.default_rng(42)
+    p = jnp.asarray(rng.standard_normal(g.shape), jnp.float32)
+    p = p - jnp.mean(p)
+    warm = p + 0.05 * jnp.asarray(rng.standard_normal(g.shape), jnp.float32)
+    return p, krylov.make_laplacian(g)(p), warm
+
+
+@pytest.mark.parametrize("start", ["cold", "warm", "exact"])
+@pytest.mark.parametrize("name", sorted(ENTRY_GRIDS))
+def test_the_increment_entry_is_the_composed_solve(name, start):
+    """The same Krylov iteration in exact arithmetic: the solution to
+    float32 rounding, the iteration count within one, the final residual
+    within the target; from the exact solution, no iteration and x0
+    back."""
+    g = ENTRY_GRIDS[name]
+    p, rhs, warm = _entry_problem(g)
+    x0 = {"cold": None, "warm": warm, "exact": p}[start]
+    solve = krylov.build_iterative_solver(g, two_level=True)
+    assert solve.entry == "increment"
+    x, stats = jax.jit(lambda r, s: solve(r, s, with_stats=True))(rhs, x0)
+    want, ref = jax.jit(lambda r, s: _composed_solve(g, r, s))(rhs, x0)
+    x, want = np.asarray(x), np.asarray(want)
+    (rn, k), (rn_ref, k_ref) = np.asarray(stats), np.asarray(ref)
+    scale = np.abs(want).max()
+    target = max(1e-6, 1e-4 * float(jnp.linalg.norm(rhs - jnp.mean(rhs))))
+    assert abs(k - k_ref) <= 1 and rn <= target and rn_ref <= target
+    if start == "exact":
+        assert k == k_ref == 0
+        np.testing.assert_allclose(x, np.asarray(p), atol=1e-6 * scale)
+    else:
+        assert k > 0
+        np.testing.assert_allclose(x, want, atol=1e-5 * scale)
+        assert abs(rn - rn_ref) <= 1e-3 * rn_ref
+    # both reach the pressure itself to the tolerance's accuracy
+    assert np.abs(x - np.asarray(p)).max() < 1e-2 * scale
+
+
+def _outside_the_loop(jaxpr):
+    """Every equation of ``jaxpr`` and of its sub-programs, except what
+    runs inside a ``while``."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "while":
+            continue
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else (v,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _outside_the_loop(sub)
+
+
+@pytest.mark.parametrize("name", sorted(ENTRY_GRIDS))
+def test_the_set_up_transposes_once_each_way(name):
+    """Outside the BiCGSTAB loop the solve transposes r0 in and the
+    increment out (the composed entry also transposed x0), and no
+    reduction there reads an array in the lanes layout (the composed
+    entry took the norms of b and r0 there)."""
+    g = ENTRY_GRIDS[name]
+    p, rhs, _ = _entry_problem(g)
+    lanes = (8, 8, 8, int(np.prod(g.shape)) // 512)
+    solve = krylov.build_iterative_solver(g, two_level=True)
+
+    def counts(fn):
+        eqns = list(_outside_the_loop(jax.make_jaxpr(fn)(rhs, p).jaxpr))
+        return (sum(e.primitive.name == "transpose" for e in eqns),
+                sum(e.primitive.name == "reduce_sum"
+                    and tuple(e.invars[0].aval.shape) == lanes
+                    for e in eqns))
+
+    assert counts(lambda r, s: solve(r, s, with_stats=True)) == (2, 0)
+    assert counts(lambda r, s: _composed_solve(g, r, s)) == (3, 2)
+
+
+def test_the_entry_follows_the_constraint(monkeypatch):
+    """The increment form where the operator is the natural stencil
+    (constraints 0 and 2), the composed one where a row is pinned (1, 3),
+    for the dense fallback and for the fused front end; the spectral
+    solve names none."""
+    g = unit_cube(BC.periodic, n=16)
+    entries = [krylov.build_iterative_solver(g, mean_constraint=mc).entry
+               for mc in range(4)]
+    assert entries == ["increment", "composed", "increment", "composed"]
+    odd = UniformGrid((12, 12, 12), (1.0, 1.0, 1.0))
+    assert krylov.build_iterative_solver(odd).entry == "composed"
+    assert not hasattr(build_spectral_solver(g), "entry")
+    monkeypatch.setenv("CUP3D_FUSED", "1")
+    assert krylov.build_iterative_solver(g).entry == "composed"
