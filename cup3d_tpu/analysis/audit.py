@@ -131,7 +131,7 @@ def _build_uniform_fish() -> Built:
     import jax.numpy as jnp
 
     from cup3d_tpu.config import SimulationConfig
-    from cup3d_tpu.sim.megaloop import build_fish_megaloop, init_fish_carry
+    from cup3d_tpu.sim.megaloop import build_body_megaloop, init_body_carry
     from cup3d_tpu.sim.simulation import Simulation
 
     cfg = SimulationConfig(
@@ -144,8 +144,8 @@ def _build_uniform_fish() -> Built:
     sim = Simulation(cfg)
     sim.init()
     ob = sim.sim.obstacles[0]
-    fn = build_fish_megaloop(sim.sim, ob)
-    carry = init_fish_carry(sim.sim, ob)
+    fn = build_body_megaloop(sim.sim, ob)
+    carry = init_body_carry(sim.sim, ob)
     cfl = jnp.full((2,), 0.3, sim.sim.dtype)
     return Built(fn, (carry, cfl), donate_argnums=(0,))
 

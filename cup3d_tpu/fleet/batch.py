@@ -4,7 +4,7 @@ BENCH_r04/r05 put every config's floor at ~0.03 s/step of host overhead.
 The megaloop (PR 6) amortizes that over K steps of ONE simulation; this
 module amortizes it over *scenarios* by laying a leading ``lane`` axis
 over the megaloop scan body (sim/megaloop.make_tgv_step /
-make_fish_step) with ``jax.vmap``:
+make_body_step) with ``jax.vmap``:
 
 - the batched carry stacks vel/p/chi/udef + the 6-DOF rigid vector and
   internal quaternion per lane, so every lane owns its own state;
@@ -43,9 +43,9 @@ import numpy as np
 from cup3d_tpu.sim.megaloop import (  # noqa: F401  (rows re-exported)
     FISH_ROW,
     TGV_ROW,
-    init_fish_carry,
+    init_body_carry,
     init_tgv_carry,
-    make_fish_step,
+    make_body_step,
     make_tgv_step,
 )
 
@@ -96,7 +96,7 @@ def stack_gaits(gaits, dtype):
 
 
 def stack_carries(carries, targets):
-    """Stack per-lane solo carries (init_tgv_carry / init_fish_carry
+    """Stack per-lane solo carries (init_tgv_carry / init_body_carry
     outputs) into one batched carry, attaching the per-lane ``left``
     budget.  ``targets[b] <= 0`` marks lane b as padding: its state is a
     clone that the gated body freezes from step 0."""
@@ -399,7 +399,7 @@ def build_fleet_advance(s, ob=None, mesh=None, kind=None):
         kind = "fish" if ob is not None else "tgv"
     has_gait = kind == "fish"
     if kind == "fish":
-        core = make_fish_step(s, ob)
+        core = make_body_step(s, ob)
     elif kind == "amr_tgv":
         from cup3d_tpu.sim.amr import make_amr_tgv_step
 

@@ -425,7 +425,7 @@ def _lane_payload(kind: str, drv, label: str):
         gait = freeze_gait(ob, drv.sim.time, drv.sim.dtype)
         if gait is None:
             raise ValueError(f"{label}: gait not freezable for fleet")
-        return FB.init_fish_carry(drv.sim, ob), gait
+        return FB.init_body_carry(drv.sim, ob), gait
     if kind == "amr_tgv":
         return FB.init_amr_carry(drv.sim), None
     return FB.init_tgv_carry(drv.sim), None

@@ -354,7 +354,46 @@ class Obstacle:
         self.chi, self.udef, combined = _fields_from_sdf(
             self.sim.grid, sdf, udef, combine
         )
+        self.note_raster_work(1)
         return combined
+
+    def note_raster_work(self, calls: int, scan: bool = False) -> None:
+        """Count ``calls`` rasterizations of this rigid body on the
+        uniform grid: per-step ones under ``operators.rigid_host_steps``,
+        those of a scan dispatch under ``operators.rigid_scan_steps``
+        (the fish counts its rasterizer's cells instead)."""
+        name = ("operators.rigid_scan_steps" if scan
+                else "operators.rigid_host_steps")
+        obs_metrics.counter(name).inc(calls)
+
+    # -- the scan megaloop's body stage (sim/megaloop.py) -------------------
+
+    def offers_scan_stage(self) -> bool:
+        """True when this body's shape can be made inside the scan: it
+        gives ``scan_window``, ``scan_gait``, ``scan_state`` and
+        ``window_shape_device``.  A body without the stage runs per
+        step."""
+        return False
+
+    def scan_gait(self, t: float, dtype):
+        """The shape's parameters frozen at ``t`` as the scan takes them
+        (an argument pytree, so a fleet can stack one per lane), or None
+        where they cannot be frozen; a rigid body has none to freeze."""
+        return {}
+
+    def scan_state(self, dtype):
+        """The shape's own state that rides the scan's carry, or None."""
+        return None
+
+    def apply_scan_state(self, row: np.ndarray) -> None:
+        """Host mirror of ``scan_state`` from a scan row's four columns."""
+
+    def window_shape_device(self, gait, origin, h, pos, rigid, time, dt,
+                            state):
+        """The body on its static window ``scan_window`` placed at
+        ``origin``, from the PRE-update rigid state (``pos`` is
+        ``rigid[6:9]``): ``(sdf_w, udef_w or None, state')``."""
+        raise NotImplementedError
 
     # -- device fast path --------------------------------------------------
 

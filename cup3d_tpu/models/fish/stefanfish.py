@@ -29,8 +29,14 @@ from cup3d_tpu.models.base import (
     fields_from_sdf,
     pos_rot_traced,
     quat_to_rot,
+    quat_to_rot_dev,
 )
 from cup3d_tpu.models.fish.curvature import CurvatureDefinedFishData
+from cup3d_tpu.models.fish.device_midline import (
+    device_midline_eligible,
+    freeze_gait,
+    midline_state_device,
+)
 from cup3d_tpu.models.fish.rasterize import (
     raster_box,
     raster_work,
@@ -346,15 +352,44 @@ class StefanFish(Obstacle):
         self.note_raster_work(1)
         return combined
 
-    def note_raster_work(self, calls: int) -> None:
+    def note_raster_work(self, calls: int, scan: bool = False) -> None:
         """Raise ``operators.raster_cells`` (cells the boxed rasterizer
         evaluated) and ``operators.raster_sweep_cells`` (what the full
         sweep of the window would have) for ``calls`` dense-window
-        rasterizations of this body."""
+        rasterizations of this body, per step or in a scan alike."""
         cells, sweep = raster_work(self.myFish.Nm, self._window_shape,
                                    self._raster_box)
         obs_metrics.counter("operators.raster_cells").inc(calls * cells)
         obs_metrics.counter("operators.raster_sweep_cells").inc(calls * sweep)
+
+    # -- the scan megaloop's body stage: the frozen-gait device midline -----
+
+    def offers_scan_stage(self) -> bool:
+        return device_midline_eligible(self)
+
+    @property
+    def scan_window(self):
+        return self._window_shape
+
+    def scan_gait(self, t: float, dtype):
+        return freeze_gait(self, t, dtype)
+
+    def scan_state(self, dtype):
+        """The internal quaternion the midline's frame integrates."""
+        return jnp.asarray(self.myFish.quaternion_internal, dtype)
+
+    def apply_scan_state(self, row: np.ndarray) -> None:
+        self.myFish.quaternion_internal = np.asarray(row, np.float64)
+
+    def window_shape_device(self, gait, origin, h, pos, rigid, time, dt,
+                            state):
+        """Shape kinematics at the carried time, then the boxed window
+        rasterizer: (sdf, udef, the new internal quaternion)."""
+        mid, qint_new = midline_state_device(gait, time, dt, state)
+        rot = quat_to_rot_dev(rigid[15:19])
+        sdf_w, udef_w = rasterize_midline(origin, h, self._window_shape,
+                                          self._raster_box, mid, pos, rot)
+        return sdf_w, udef_w, qint_new
 
     # -- rigid-body override: roll correction ------------------------------
 

@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from cup3d_tpu.models.base import Obstacle, pos_rot_traced
+from cup3d_tpu.ops.surface import window_size_cells
 
 
 @partial(jax.jit, static_argnames=("radius",))
@@ -38,3 +39,22 @@ class Sphere(Obstacle):
             self._cell_centers(), self.frame_device(self.sim.dtype),
             self.radius,
         ), None
+
+    # -- the scan megaloop's body stage --------------------------------------
+
+    def offers_scan_stage(self) -> bool:
+        return not self._is_blocks and self.supports_device_update()
+
+    @property
+    def scan_window(self):
+        """The force probe's window (ops/surface.window_size_cells):
+        the sphere and 8h of band on every side."""
+        w = window_size_cells(self.length, self.sim.grid.h)
+        return tuple(min(w, n) for n in self.sim.grid.shape)
+
+    def window_shape_device(self, gait, origin, h, pos, rigid, time, dt,
+                            state):
+        axes = [origin[a] + (jnp.arange(n, dtype=origin.dtype) + 0.5) * h
+                for a, n in enumerate(self.scan_window)]
+        x = jnp.stack(jnp.meshgrid(*axes, indexing="ij"), axis=-1)
+        return self.radius - jnp.linalg.norm(x - pos, axis=-1), None, state

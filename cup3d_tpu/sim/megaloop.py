@@ -15,18 +15,20 @@ Step semantics reproduce the host pipelined chain exactly:
   the host chain's freshly-consumed pack, so no 1.5x staleness margin),
   capped by the combined advection-diffusion bound and the 1.03x growth
   limiter (sim/dtpolicy.py).
-- The midline is evaluated by the frozen-gait device port
-  (models/fish/device_midline.py) at the carried time; rasterization snaps
-  the same static window as StefanFish.rasterize from the PRE-update rigid
-  state (the host rasterizes before UpdateObstacles runs).
+- The body's shape is made on its static window, placed from the
+  PRE-update rigid state (the host rasterizes before UpdateObstacles
+  runs): the fish's midline by the frozen-gait device port
+  (models/fish/device_midline.py) at the carried time and the same
+  window as StefanFish.rasterize; a rigid body (Sphere) analytically.
 - umax is measured with the PRE-update uinf, matching the host emit point
   (Simulation._emit_step_pack reads s._uinf_dev set from the previous
   rigid state).
 - The QoI row layout (FISH_ROW) carries everything _consume_pack needs to
   refresh the host mirrors per step k: the rigid pack, penalization
   force/torque (already negated, models.base.update_penalization_forces
-  convention), the force probe pack, solver stats, the internal
-  quaternion, and the (umax, dt, time) chain for failure detection.
+  convention), the force probe pack, solver stats, the shape's state (the
+  fish's internal quaternion; zeros for a rigid body), and the (umax,
+  dt, time) chain for failure detection.
 
 The carry is donated: callers must rebind every field from the returned
 carry and never touch the passed-in arrays again (JX002 discipline).
@@ -45,7 +47,6 @@ from cup3d_tpu.models.base import (
     momentum_integrals_core,
     pack_forces,
     pack_moments,
-    quat_to_rot_dev,
     rigid_update_device,
 )
 from cup3d_tpu.ops.advection import GHOSTS, rk3_step
@@ -116,9 +117,10 @@ def init_tgv_carry(s):
     }
 
 
-def init_fish_carry(s, ob):
-    """Single-fish carry: field state + 6-DOF rigid vector + internal
-    quaternion, all device-resident.  chi/udef ride the carry so dumps and
+def init_body_carry(s, ob):
+    """Single-body carry: field state + 6-DOF rigid vector + the shape's
+    own state where the body has one (the fish's internal quaternion,
+    ``qint``), all device-resident.  chi/udef ride the carry so dumps and
     resilience restores see a consistent set (the scan body overwrites
     them every step).  The umax seed is floored by the host's fresh
     max_body_speed bound — the cold-start case where the fields are still
@@ -130,30 +132,33 @@ def init_fish_carry(s, ob):
     uinf = -rigid[0:3] if ob.bFixFrameOfRef else s.uinf_device()
     umax = jnp.maximum(max_velocity(vel, uinf), jnp.max(jnp.abs(udef)))
     umax = jnp.maximum(umax, jnp.asarray(ob.max_body_speed(s.uinf), dtype))
-    return {
+    carry = {
         "vel": vel,
         "p": s.state["p"],
         "chi": s.state["chi"],
         "udef": udef,
         "rigid": rigid,
-        "qint": jnp.asarray(ob.myFish.quaternion_internal, dtype),
         "umax": umax,
         "time": jnp.asarray(s.time, dtype),
         "dt": jnp.asarray(s.dt, dtype),
     }
+    state = ob.scan_state(dtype)
+    if state is not None:
+        carry["qint"] = state
+    return carry
 
 
-def _fish_stages(s, ob):
-    """What both single-fish scan bodies (solo and x-slab) do between
-    their field operators, written once: everything geometric frozen
-    static at build time (the rasterization window, the probe window and
+def _body_stages(s, ob):
+    """What both single-body scan bodies (solo and x-slab) do between
+    their field operators, written once for every body that offers a
+    scan stage (``Obstacle.offers_scan_stage``): everything geometric
+    frozen static at build time (the body's window, the probe window and
     its slot budget, the forced/blocked masks), and the three stages
     that are lines of the body rather than calls into ``ops/`` —
-    each under its operator's name in a device trace."""
+    each under its operator's name in a device trace.  Only the shape
+    on the window comes from the body (``window_shape_device``)."""
     from types import SimpleNamespace
 
-    from cup3d_tpu.models.fish.device_midline import midline_state_device
-    from cup3d_tpu.models.fish.rasterize import rasterize_midline
     from cup3d_tpu.ops.surface import (
         _uniform_window_probe,
         obstacle_probe_budget,
@@ -166,8 +171,7 @@ def _fish_stages(s, ob):
 
     n = np.asarray(grid.shape)
     grid_shape = tuple(int(v) for v in n)
-    window_shape = tuple(ob._window_shape)
-    box = ob._raster_box
+    window_shape = tuple(ob.scan_window)
     half_win = jnp.asarray(0.5 * np.asarray(window_shape) * h, dtype)
     lim_win = jnp.asarray(n - np.asarray(window_shape), jnp.int32)
     wp = int(min(window_size_cells(ob.length, h), n.min()))
@@ -182,27 +186,27 @@ def _fish_stages(s, ob):
     lam_static = jnp.asarray(cfg.lambda_penalization, dtype)
 
     @jax.named_scope("CreateObstacles")
-    def create(gait, time, dt, qint, rigid):
-        """Shape kinematics + rasterization from the PRE-update rigid
-        state (host order: CreateObstacles runs before UpdateObstacles):
-        (sdf, chi, udef, the new internal quaternion)."""
-        mid, qint_new = midline_state_device(gait, time, dt, qint)
+    def create(gait, time, dt, state, rigid):
+        """The body's shape on its window, placed from the PRE-update
+        rigid state (host order: CreateObstacles runs before
+        UpdateObstacles): (sdf, chi, udef, the shape's new state)."""
         pos = rigid[6:9]
-        rot = quat_to_rot_dev(rigid[15:19])
         idx0 = jnp.clip(jnp.floor((pos - half_win) / hd).astype(jnp.int32),
                         0, lim_win)
         origin = idx0.astype(dtype) * hd
-        sdf_w, udef_w = rasterize_midline(origin, hd, window_shape, box,
-                                          mid, pos, rot)
+        sdf_w, udef_w, state_new = ob.window_shape_device(
+            gait, origin, hd, pos, rigid, time, dt, state)
         sdf = jnp.full(grid_shape, -1.0, dtype)
         sdf = jax.lax.dynamic_update_slice(
             sdf, sdf_w, (idx0[0], idx0[1], idx0[2]))
-        udef = jnp.zeros(grid_shape + (3,), dtype)
-        udef = jax.lax.dynamic_update_slice(
-            udef, udef_w, (idx0[0], idx0[1], idx0[2], 0))
         chi = towers_chi(grid.pad_scalar(sdf, 1), grid.h)
-        udef = udef * (chi > 0)[..., None]
-        return sdf, chi, udef, qint_new
+        udef = None  # a rigid body deforms nowhere: its zeros are carried
+        if udef_w is not None:
+            udef = jnp.zeros(grid_shape + (3,), dtype)
+            udef = jax.lax.dynamic_update_slice(
+                udef, udef_w, (idx0[0], idx0[1], idx0[2], 0))
+            udef = udef * (chi > 0)[..., None]
+        return sdf, chi, udef, state_new
 
     @jax.named_scope("Penalization")
     def penalization(vel, chi, udef, ut, om, cm, dt):
@@ -293,20 +297,23 @@ def build_tgv_megaloop(s):
     return jax.jit(megaloop, donate_argnums=(0,))
 
 
-def make_fish_step(s, ob):
-    """The single-StefanFish scan body as a pure function
+def make_body_step(s, ob):
+    """The single-body scan body (a StefanFish or a rigid body such as a
+    Sphere) as a pure function
     ``one_step(gait, carry, cfl_eff) -> (carry', row (FISH_ROW,))``.
 
     Everything geometric is frozen static at build time
-    (:func:`_fish_stages`).  The
+    (:func:`_body_stages`).  The
     frozen-gait parameters are an ARGUMENT pytree rather than a closure,
     so the solo megaloop can bake one gait in as trace-time constants
-    while fleet/batch.py stacks per-lane gaits and vmaps over them."""
+    while fleet/batch.py stacks per-lane gaits and vmaps over them (a
+    rigid body's gait is empty).  The row's columns 54:58 hold the
+    shape's state, zeros for a body without one."""
     grid, nu, dtype = s.grid, s.nu, s.dtype
     h = float(grid.h)
     solver = s.poisson_solver
     with_stats = bool(getattr(solver, "supports_stats", False))
-    stage = _fish_stages(s, ob)
+    stage = _body_stages(s, ob)
     forced_mask = ob.forced_mask_dev()
     block_mask = ob.block_mask_dev()
     fix_frame = bool(ob.bFixFrameOfRef)
@@ -316,11 +323,13 @@ def make_fish_step(s, ob):
 
     def one_step(gait, carry, cfl_eff):
         vel, p = carry["vel"], carry["p"]
-        rigid, qint = carry["rigid"], carry["qint"]
+        rigid, qint = carry["rigid"], carry.get("qint")
         umax, time, dtprev = carry["umax"], carry["time"], carry["dt"]
         dt = dtpolicy.dt_scan(cfl_eff, h, nu, umax, dtprev)
         uinf = -rigid[0:3] if fix_frame else uinf_const
         sdf, chi, udef, qint_new = stage.create(gait, time, dt, qint, rigid)
+        if udef is None:
+            udef = carry["udef"]
         # advection-diffusion
         vel = rk3_step(grid, vel, dt, nu, uinf)
         # chi-weighted fluid momenta -> 6-DOF rigid update, on device
@@ -346,9 +355,13 @@ def make_fish_step(s, ob):
         time_new = time + dt
         carry_new = {
             "vel": vel, "p": p, "chi": chi, "udef": udef,
-            "rigid": rigid_new, "qint": qint_new,
+            "rigid": rigid_new,
             "umax": umax_new, "time": time_new, "dt": dt,
         }
+        if qint_new is not None:
+            carry_new["qint"] = qint_new
+        else:  # the row keeps its layout: zeros for a shape with no state
+            qint_new = jnp.zeros(4, dtype)
         row = jnp.concatenate([out, PF, F, stats, qint_new,
                                umax_new[None], dt[None], time_new[None]])
         return carry_new, row
@@ -356,19 +369,18 @@ def make_fish_step(s, ob):
     return one_step
 
 
-def build_fish_megaloop(s, ob):
+def build_body_megaloop(s, ob):
     """jitted (carry, cfl_eff (K,)) -> (carry', rows (K, FISH_ROW)) for the
-    single-StefanFish uniform pipeline.  Returns None when the gait is not
-    freezable (models/fish/device_midline.freeze_gait).  The carry is
-    DONATED.  The frozen gait is bound here as trace-time constants (the
-    same leaves the closure used to capture), so the compiled artifact is
-    unchanged by the make_fish_step refactor."""
-    from cup3d_tpu.models.fish.device_midline import freeze_gait
-
-    gait = freeze_gait(ob, s.time, s.dtype)
+    single-body uniform pipeline.  Returns None when the body's gait is
+    not freezable (``Obstacle.scan_gait``; for the fish
+    models/fish/device_midline.freeze_gait).  The carry is DONATED.  The
+    frozen gait is bound here as trace-time constants (the same leaves
+    the closure used to capture), so the compiled artifact is unchanged
+    by the make_body_step refactor."""
+    gait = ob.scan_gait(s.time, s.dtype)
     if gait is None:
         return None
-    one_step = make_fish_step(s, ob)
+    one_step = make_body_step(s, ob)
 
     def megaloop(carry, cfl_eff):
         return jax.lax.scan(
@@ -506,14 +518,20 @@ def make_fish_step_sharded(s, ob, axis="x"):
     replicated — rasterization from replicated rigid scalars is already
     identical everywhere, and the rest works on the gathered velocity,
     so every reduction keeps the solo order and the step stays bitwise
-    against make_fish_step."""
+    against make_body_step.  A body that is not a fish raises: the x-slab
+    body is the fish's alone."""
     from cup3d_tpu.parallel import collectives as coll
     from cup3d_tpu.parallel import ring as _ring
+
+    if not hasattr(ob, "myFish"):
+        raise NotImplementedError(
+            f"CUP3D_MESH_X: the x-slab megaloop runs a StefanFish only, "
+            f"not a {type(ob).__name__}; run the solo scan")
 
     grid, nu, dtype = s.grid, s.nu, s.dtype
     h = float(grid.h)
     solver = s.poisson_solver
-    stage = _fish_stages(s, ob)
+    stage = _body_stages(s, ob)
     forced_mask = ob.forced_mask_dev()
     block_mask = ob.block_mask_dev()
     fix_frame = bool(ob.bFixFrameOfRef)
@@ -574,14 +592,14 @@ def build_fish_megaloop_sharded(s, ob, mesh, axis="x"):
     """jitted (carry, cfl_eff (K,)) -> (carry', rows (K, FISH_ROW)) with
     the fish scan body shard_mapped over ``axis`` slabs.  Returns None
     when the gait is not freezable (no megaloop at all, exactly like
-    :func:`build_fish_megaloop`); a run that cannot slab raises
-    (:func:`_require_slabbable`)."""
+    :func:`build_body_megaloop`); a run that cannot slab raises
+    (:func:`_require_slabbable`), and so does a body that is not a fish.
+    """
     from jax.sharding import PartitionSpec as P
 
-    from cup3d_tpu.models.fish.device_midline import freeze_gait
     from cup3d_tpu.parallel.compat import shard_map
 
-    gait = freeze_gait(ob, s.time, s.dtype)
+    gait = ob.scan_gait(s.time, s.dtype)
     if gait is None:
         return None
     _require_slabbable(s, mesh, axis)

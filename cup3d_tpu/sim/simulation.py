@@ -143,19 +143,18 @@ class Simulation:
         if not s.obstacles:
             return True  # forced or not: make_tgv_step forces in the scan
         if ops.forced(cfg):
-            return False  # the fish scan body has no forcing stage
+            return False  # the single-body scan has no forcing stage
         if len(s.obstacles) != 1:
             return False
-        from cup3d_tpu.models.fish.device_midline import (
-            device_midline_eligible,
-        )
-
-        return device_midline_eligible(s.obstacles[0])
+        # the body makes its shape inside the scan: a steady StefanFish
+        # (the frozen-gait device midline) or a Sphere; a Naca runs per
+        # step
+        return s.obstacles[0].offers_scan_stage()
 
     def _scan_ready(self) -> bool:
         """True when the next simulate iteration should run as one
         K-step megaloop: scan enabled, the compiled loop buildable
-        (fish gait freezable), a full K inside the step budget, and no
+        (the body's gait freezable), a full K inside the step budget, and no
         recovery retreat in progress (the per-step path owns the
         halved-dt re-advance; the scan resumes once the engine retires
         the attempt)."""
@@ -180,7 +179,7 @@ class Simulation:
             mesh = topo.megaloop_mesh()
             if s.obstacles:
                 ob = s.obstacles[0]
-                fn = (ml.build_fish_megaloop(s, ob) if mesh is None
+                fn = (ml.build_body_megaloop(s, ob) if mesh is None
                       else ml.build_fish_megaloop_sharded(s, ob, mesh))
                 row_w = ml.FISH_ROW
             else:
@@ -475,7 +474,7 @@ class Simulation:
 
     def advance_megaloop(self) -> None:
         """One K-step scan dispatch (sim/megaloop.py): the whole
-        per-step pipeline — dt policy, fish midline, rasterization,
+        per-step pipeline — dt policy, the body's shape, rasterization,
         rigid update, penalization, projection, force probe — runs
         inside one jitted ``lax.scan``; the host only precomputes the
         CFL ramp, dispatches, and emits the (K, ROW) QoI block into the
@@ -502,7 +501,7 @@ class Simulation:
                 # post-fallback), never per step
                 with sanctioned_transfer("scan-carry-upload"):
                     self._scan_carry = (
-                        ml.init_fish_carry(s, s.obstacles[0])
+                        ml.init_body_carry(s, s.obstacles[0])
                         if s.obstacles else ml.init_tgv_carry(s))
                     if self._scan_mesh is not None:
                         from cup3d_tpu.parallel import topology as topo
@@ -523,8 +522,8 @@ class Simulation:
                 carry, rows = fn(self._scan_carry, cfl_dev)
             obs_metrics.counter("megaloop.dispatches").inc()
             ops.note_poisson_solves(s.poisson_solver, K)
-            if s.obstacles:  # the single-fish body rasterizes every step
-                s.obstacles[0].note_raster_work(K)
+            if s.obstacles:  # the single body rasterizes every step
+                s.obstacles[0].note_raster_work(K, scan=True)
             elif ops.forced(cfg):  # make_tgv_step forces every step
                 obs_metrics.counter("operators.flux_scan_steps").inc(K)
             self._scan_carry = carry
@@ -682,8 +681,7 @@ class Simulation:
                     f"dt policy collapse: dt={dt_k:.3g}", extra)
             if ob is not None:
                 ob.apply_rigid_pack(row[0:29])
-                ob.myFish.quaternion_internal = np.asarray(
-                    row[54:58], np.float64)
+                ob.apply_scan_state(row[54:58])
                 ob.penal_force = row[29:32]
                 ob.penal_torque = row[32:35]
                 store_force_qoi(ob, unpack_forces(row[35:52]))

@@ -115,7 +115,7 @@ def test_each_program_of_the_per_step_path_carries_its_operator(fish, name):
 
 
 def test_the_scan_body_carries_every_operator_it_runs(tmp_path):
-    """``make_fish_step`` has no scope of its own: the names come from
+    """``make_body_step`` has no scope of its own: the names come from
     the functions it shares with the per-step path and the forest, and
     the solve's children sit under PressureProjection."""
     driver = Simulation(fish_cfg(tmp_path, nsteps=10 ** 6, **ITERATIVE))
@@ -125,8 +125,8 @@ def test_the_scan_body_carries_every_operator_it_runs(tmp_path):
     from cup3d_tpu.models.fish.device_midline import freeze_gait
 
     gait = freeze_gait(ob, s.time, s.dtype)
-    one_step = ml.make_fish_step(s, ob)
-    carry = ml.init_fish_carry(s, ob)
+    one_step = ml.make_body_step(s, ob)
+    carry = ml.init_body_carry(s, ob)
     got = scope_paths(lambda: one_step(gait, carry, jnp.asarray(0.3, s.dtype)))
     want = SOLVE | {
         "DtPolicy", "CreateObstacles", "CreateObstacles/Halo",
